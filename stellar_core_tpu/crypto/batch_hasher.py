@@ -241,7 +241,13 @@ class HasherStats:
         tracer_instant(self.tracer, "hasher.staging.stall", cat="crypto")
 
     # -- compile cache + warmup ---------------------------------------------
-    def compile_cache_enabled(self, path: str) -> None:
+    def set_compile_cache_dir(self, path: Optional[str]) -> None:
+        """Where JAX keeps its persistent compile cache, as warmup found
+        it (parallel.device.compile_cache_dir); None = JAX has none."""
+        if not path:
+            self.compile_cache_error(
+                "JAX has no persistent compile cache directory")
+            return
         self.compile_cache.update(
             {"enabled": True, "dir": path, "error": None})
         self._g_cc.set(1)
@@ -436,13 +442,11 @@ class TpuBatchHasher(BatchHasher):
     # shapes the AOT warmup compiles: the small-drain shape the live
     # close path uses plus the bulk entry-leaf shapes
     WARM_SHAPES = ((256, 2), (4096, 2), (4096, 4))
-    CACHE_PERSIST_MIN_S = 0.5
 
-    def __init__(self, compile_cache_dir: Optional[str] = None) -> None:
-        self._compile_cache_dir = compile_cache_dir
-        self._cache_path: Optional[str] = None
+    def __init__(self) -> None:
         self._warmed = False
         self._warmup_thread: Optional[threading.Thread] = None
+        self._warmup_error: Optional[BaseException] = None
         self._platform: Optional[str] = None
 
     # -- buckets -------------------------------------------------------------
@@ -458,46 +462,12 @@ class TpuBatchHasher(BatchHasher):
                 return b
         return self.BLOCK_BUCKETS[-1]
 
-    # -- persistent compile cache (mirrors TpuSigVerifier) -------------------
-    def _resolve_cache_dir(self) -> str:
-        import os
-        return self._compile_cache_dir or os.environ.get(
-            "JAX_COMPILATION_CACHE_DIR") or os.path.expanduser(
-            "~/.cache/stellar_core_tpu/jax_cache")
-
-    def _enable_compile_cache(self) -> None:
-        import os
-        path = self._resolve_cache_dir()
-        try:
-            import jax
-            os.makedirs(path, exist_ok=True)
-            jax.config.update("jax_compilation_cache_dir", path)
-            jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                              self.CACHE_PERSIST_MIN_S)
-            self._cache_path = path
-            if self.stats is not None:
-                self.stats.compile_cache_enabled(path)
-        except Exception as e:   # cache is an optimization, never fatal
-            log.warning("hash compile cache unavailable: %s", e)
-            if self.stats is not None:
-                self.stats.compile_cache_error(repr(e))
-
-    def _cache_entry_count(self) -> int:
-        import os
-        if self._cache_path is None:
-            return -1
-        try:
-            n = 0
-            for _dir, _sub, files in os.walk(self._cache_path):
-                n += len(files)
-            return n
-        except OSError:
-            return -1
-
     # -- warmup --------------------------------------------------------------
     def warmup(self, wait: bool = False) -> None:
         """AOT-compile every warm shape off the consensus path (startup
-        background thread); idempotent."""
+        background thread); idempotent. A failure is recorded in the
+        cockpit and the node keeps running; a caller that waits gets it
+        raised."""
         if self._warmed:
             return
         if self._warmup_thread is None:
@@ -505,6 +475,8 @@ class TpuBatchHasher(BatchHasher):
                 "crypto.hash-warmup", self._hash_warmup_impl)
         if wait:
             self._warmup_thread.join()
+            if self._warmup_error is not None:
+                raise self._warmup_error
 
     def _compile_shape(self, lanes: int, blocks: int) -> None:
         import numpy as np
@@ -514,28 +486,21 @@ class TpuBatchHasher(BatchHasher):
             np.ones((lanes,), np.int32)))
 
     def _hash_warmup_impl(self) -> None:
+        from ..parallel.device import (
+            cache_hit, compile_cache_dir, compile_cache_events,
+        )
         st = self.stats
         try:
-            self._enable_compile_cache()
             if st is not None:
+                st.set_compile_cache_dir(compile_cache_dir())
                 st.warmup_begin(self.WARM_SHAPES)
             for shape in self.WARM_SHAPES:
-                before = self._cache_entry_count()
                 t0 = real_monotonic()
-                self._compile_shape(*shape)
+                with compile_cache_events() as events:
+                    self._compile_shape(*shape)
                 dt = real_monotonic() - t0
-                after = self._cache_entry_count()
-                if before < 0 or after < 0:
-                    hit = None
-                elif after > before:
-                    hit = False
-                elif dt >= self.CACHE_PERSIST_MIN_S:
-                    hit = True
-                else:
-                    hit = None     # fast compile below the persistence
-                    # threshold writes no entry either way
                 if st is not None:
-                    st.warmup_shape_done(shape, dt, hit)
+                    st.warmup_shape_done(shape, dt, cache_hit(events))
             self._warmed = True
             if st is not None:
                 st.warmup_done()
@@ -543,6 +508,7 @@ class TpuBatchHasher(BatchHasher):
                      len(self.WARM_SHAPES))
         except Exception as e:
             log.warning("hash kernel warmup failed: %s", e)
+            self._warmup_error = e
             if st is not None:
                 st.warmup_failed(repr(e))
 
@@ -815,7 +781,6 @@ class ResilientBatchHasher(BatchHasher):
 
 
 def make_hasher(backend: str = "cpu", clock=None,
-                compile_cache_dir: Optional[str] = None,
                 metrics=None, tracer=None, faults=None,
                 flight_recorder=None,
                 breaker_threshold: int = 3,
@@ -855,7 +820,7 @@ def make_hasher(backend: str = "cpu", clock=None,
     elif backend == "cpu-resilient":
         h = resilient(CpuBatchHasher())
     elif backend == "tpu":
-        h = resilient(TpuBatchHasher(compile_cache_dir=compile_cache_dir))
+        h = resilient(TpuBatchHasher())
     else:
         raise ValueError("unknown hash backend %r" % backend)
     h.tracer = tracer

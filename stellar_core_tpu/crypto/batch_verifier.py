@@ -336,16 +336,22 @@ class VerifierStats:
         self._t_wait.update(mean_s)
 
     # -- compile cache + warmup ---------------------------------------------
-    def compile_cache_enabled(self, path: str) -> None:
+    def set_compile_cache_dir(self, path: Optional[str]) -> None:
+        """Where JAX keeps its persistent compile cache, as warmup found
+        it (parallel.device.compile_cache_dir); None = JAX has none."""
+        if not path:
+            self.compile_cache_error(
+                "JAX has no persistent compile cache directory")
+            return
         self.compile_cache.update(
             {"enabled": True, "dir": path, "error": None})
         self._g_cc.set(1)
 
     def compile_cache_error(self, err: str) -> None:
-        """The persistent-XLA-cache enable failed: previously a swallowed
-        log.warning — now a meter, a tracer instant and a flight dump,
-        because a node silently paying cold compiles on every restart is
-        exactly the regression the cockpit exists to catch."""
+        """The node runs without a persistent compile cache: a meter, a
+        tracer instant and a flight dump, because a node silently paying
+        cold compiles on every restart is exactly the regression the
+        cockpit exists to catch."""
         self.compile_cache.update({"enabled": False, "error": err})
         self._g_cc.set(0)
         self.metrics.new_meter("verifier.compile-cache.unavailable").mark()
@@ -357,7 +363,7 @@ class VerifierStats:
 
     WARMUP_STATE_CODE = {"idle": 0, "running": 1, "done": 2, "failed": 3}
     # where the warm-start bucket set came from: the hardcoded default
-    # ladder, or the cockpit-derived plan persisted beside the XLA cache
+    # ladder, or the cockpit-derived plan persisted with the node's state
     WARMUP_SOURCE_CODE = {"default": 0, "cockpit": 1}
 
     def warmup_begin(self, buckets, source: str = "default") -> None:
@@ -374,8 +380,8 @@ class VerifierStats:
     def warmup_bucket_done(self, bucket: int, seconds: float,
                            cache_hit) -> None:
         """One bucket shape compiled (or loaded). `cache_hit` is
-        True/False from the compile-cache-entry diff, None when the
-        cache dir is unreadable."""
+        parallel.device.cache_hit's reading of JAX's own cache events:
+        True loaded, False compiled and written, None neither."""
         cache = ("hit" if cache_hit is True else
                  "miss" if cache_hit is False else "unknown")
         with self._lock:
@@ -474,8 +480,8 @@ def warmup_plan(stats, candidates):
 
     Returns (buckets, info) where info carries `source`
     ("cockpit"/"default") and the evidence the choice was made from —
-    persisted beside the XLA cache by save_warmup_plan() so a warm
-    restart compiles only the shapes real traffic uses."""
+    persisted by save_warmup_plan() so a warm restart compiles only the
+    shapes real traffic uses."""
     cands = sorted(candidates)
     if stats is None:
         return list(cands), {"source": "default",
@@ -708,12 +714,6 @@ class TpuSigVerifier(BatchSigVerifier):
     name = "tpu"
     wants_prewarm = True
     BUCKETS = (128, 512, 2048, 8192)
-    # minimum compile duration the persistent cache stores (mirrors the
-    # jax_persistent_cache_min_compile_time_secs value set below): a
-    # compile faster than this writes no entry, so "no new cache file"
-    # proves nothing about it — warmup classifies those "unknown",
-    # never "hit"
-    CACHE_PERSIST_MIN_S = 0.5
 
     # batches below this size stay on one device: sharding a handful of
     # sigs over a pod slice buys nothing and costs a sharded compile
@@ -721,12 +721,12 @@ class TpuSigVerifier(BatchSigVerifier):
 
     # device drains between cockpit-plan autosaves (save_warmup_plan)
     PLAN_AUTOSAVE_DRAINS = 32
+    PLAN_BASENAME = "warmup_buckets.json"
 
     # the kernel's device argument order (prepare_batch dict keys)
     ARG_KEYS = ("ay", "a_sign", "ry", "r_sign", "s_nibs", "k_nibs")
 
     def __init__(self, max_pending: int = 8192,
-                 compile_cache_dir: Optional[str] = None,
                  shard_threshold: Optional[int] = None,
                  devices: Optional[Sequence] = None,
                  now_fn: Optional[Callable[[], float]] = None,
@@ -736,10 +736,14 @@ class TpuSigVerifier(BatchSigVerifier):
         self._max_pending = max_pending
         self.batches_dispatched = 0
         self.sigs_verified = 0
-        self._compile_cache_dir = compile_cache_dir
-        self._cache_path: Optional[str] = None  # resolved on enable
+        # where the cockpit-derived warmup plan persists; None (direct
+        # constructions, nodes without a bucket directory) keeps the
+        # default ladder and saves nothing. Application.enable_buckets
+        # points it beside the node's bucket directory.
+        self.warmup_plan_path: Optional[str] = None
         self._warmed = False
         self._warmup_thread: Optional[threading.Thread] = None
+        self._warmup_error: Optional[BaseException] = None
         self._sharded_fn = None  # full-mesh dp fn (set on first build)
         self._platform: Optional[str] = None  # actual jax platform, lazy
         self._devices_override = devices
@@ -860,74 +864,16 @@ class TpuSigVerifier(BatchSigVerifier):
         return tuple(jax.device_put(padded[k], target)
                      for k in self.ARG_KEYS)
 
-    def _enable_compile_cache(self) -> None:
-        """Persistent XLA compilation cache: a node restart never re-pays
-        kernel compilation (VERDICT r1: lazy compile on the consensus path
-        stalls a validator for the compile duration)."""
-        import os
-        path = self._resolve_cache_dir()
-        try:
-            import jax
-            os.makedirs(path, exist_ok=True)
-            jax.config.update("jax_compilation_cache_dir", path)
-            jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                              self.CACHE_PERSIST_MIN_S)
-            self._cache_path = path
-            if self.stats is not None:
-                self.stats.compile_cache_enabled(path)
-        except Exception as e:  # cache is an optimization, never fatal
-            log.warning("compile cache unavailable: %s", e)
-            if self.stats is not None:
-                # ...but an operator must be able to SEE it (tracer
-                # instant + meter + flight dump), or every restart
-                # silently pays cold compiles
-                self.stats.compile_cache_error(repr(e))
-
-    def _cache_entry_count(self) -> int:
-        """Files under the persistent XLA cache dir (-1 = unknown).
-        Warmup diffs this around each bucket compile: no new entry means
-        the executable came from the cache (a warm restart), a new entry
-        means a cold compile just got paid. The persisted warmup plan
-        lives beside the executables and is excluded from the diff."""
-        import os
-        if self._cache_path is None:
-            return -1
-        try:
-            n = 0
-            for _dir, _sub, files in os.walk(self._cache_path):
-                # PLAN_BASENAME and its .tmp write-staging sibling: a
-                # concurrent plan autosave must not make a cache-hit
-                # bucket classify as a cold compile
-                n += sum(1 for f in files
-                         if not f.startswith(self.PLAN_BASENAME))
-            return n
-        except OSError:
-            return -1
-
     # -- cockpit-driven warm start (ISSUE 11 tentpole) -----------------------
-    PLAN_BASENAME = "warmup_buckets.json"
-
-    def _resolve_cache_dir(self) -> str:
-        import os
-        return self._compile_cache_dir or os.environ.get(
-            "JAX_COMPILATION_CACHE_DIR") or os.path.expanduser(
-            "~/.cache/stellar_core_tpu/jax_cache")
-
-    def warmup_plan_path(self) -> str:
-        """The cockpit-derived bucket plan persists beside the XLA
-        compile cache: the same restart that finds warm executables
-        finds the bucket set real traffic uses."""
-        import os
-        return os.path.join(self._cache_path or self._resolve_cache_dir(),
-                            self.PLAN_BASENAME)
-
     def _load_warmup_plan(self):
         """(buckets, source): the persisted cockpit plan when present
         and still valid against the candidate ladder, else the full
         default BUCKETS."""
         import json
+        if self.warmup_plan_path is None:
+            return list(self.BUCKETS), "default"
         try:
-            with open(self.warmup_plan_path()) as fh:
+            with open(self.warmup_plan_path) as fh:
                 blob = json.load(fh)
             buckets = [int(b) for b in blob["buckets"]]
             if buckets and all(b in self.BUCKETS for b in buckets):
@@ -941,19 +887,20 @@ class TpuSigVerifier(BatchSigVerifier):
 
     def save_warmup_plan(self) -> Optional[str]:
         """Persist the cockpit-derived bucket plan (warmup_plan over the
-        shared VerifierStats) beside the XLA cache. No-op until the
-        cockpit has seen traffic — a default plan is not evidence worth
-        persisting. Returns the path written, or None."""
-        if self.stats is None:
+        shared VerifierStats) at `warmup_plan_path` — node state, kept
+        out of the compile cache so a cache shared between runs carries
+        executables only and one run cannot choose another's warm set.
+        No-op until the cockpit has seen traffic — a default plan is not
+        evidence worth persisting. Returns the path written, or None."""
+        if self.stats is None or self.warmup_plan_path is None:
             return None
         buckets, info = warmup_plan(self.stats, self.BUCKETS)
         if info.get("source") != "cockpit":
             return None
         import json
         import os
-        path = self.warmup_plan_path()
+        path = self.warmup_plan_path
         try:
-            os.makedirs(os.path.dirname(path), exist_ok=True)
             tmp = path + ".tmp"
             with open(tmp, "w") as fh:
                 json.dump({"version": 1, "buckets": buckets,
@@ -971,7 +918,8 @@ class TpuSigVerifier(BatchSigVerifier):
     def warmup(self, wait: bool = False) -> None:
         """AOT-compile every bucket shape off the consensus path (startup
         background thread; reference analog: no lazy work on first
-        envelope). Idempotent."""
+        envelope). Idempotent. A failure is recorded in the cockpit and
+        the node keeps running; a caller that waits gets it raised."""
         if self._warmed:
             return
         if self._warmup_thread is None:
@@ -979,6 +927,8 @@ class TpuSigVerifier(BatchSigVerifier):
                 "crypto.verify-warmup", self._warmup_impl)
         if wait:
             self._warmup_thread.join()
+            if self._warmup_error is not None:
+                raise self._warmup_error
 
     def _compile_bucket(self, b: int) -> None:
         """AOT-compile (or cache-load) one bucket shape, routed exactly
@@ -997,32 +947,23 @@ class TpuSigVerifier(BatchSigVerifier):
         np.asarray(fn(*self._device_args(zeros, idxs)))
 
     def _warmup_impl(self) -> None:
+        from ..parallel.device import (
+            cache_hit, compile_cache_dir, compile_cache_events,
+        )
         st = self.stats
         try:
-            self._enable_compile_cache()
+            if st is not None:
+                st.set_compile_cache_dir(compile_cache_dir())
             planned, source = self._load_warmup_plan()
             if st is not None:
                 st.warmup_begin(planned, source=source)
             for b in planned:
-                before = self._cache_entry_count()
                 t0 = real_monotonic()
-                self._compile_bucket(b)
+                with compile_cache_events() as events:
+                    self._compile_bucket(b)
                 dt = real_monotonic() - t0
-                after = self._cache_entry_count()
-                if before < 0 or after < 0:
-                    hit = None            # cache dir unreadable
-                elif after > before:
-                    hit = False           # a cold compile just persisted
-                elif dt >= self.CACHE_PERSIST_MIN_S:
-                    hit = True            # long compile, no new entry:
-                    # the executable came from the cache
-                else:
-                    # fast compile below the persistence threshold
-                    # writes no entry either way — unclassifiable, and
-                    # nothing worth caching was at stake
-                    hit = None
                 if st is not None:
-                    st.warmup_bucket_done(b, dt, hit)
+                    st.warmup_bucket_done(b, dt, cache_hit(events))
             self._warmed = True
             if st is not None:
                 st.warmup_done()
@@ -1030,6 +971,7 @@ class TpuSigVerifier(BatchSigVerifier):
                      "%s plan)", len(planned), source)
         except Exception as e:
             log.warning("verify kernel warmup failed: %s", e)
+            self._warmup_error = e
             if st is not None:
                 st.warmup_failed(repr(e))
 
@@ -1638,7 +1580,6 @@ class ThreadedBatchVerifier(BatchSigVerifier):
 
 def make_verifier(backend: str = "cpu", clock=None,
                   max_pending: int = 8192,
-                  compile_cache_dir: Optional[str] = None,
                   metrics=None, tracer=None, faults=None,
                   flight_recorder=None,
                   breaker_threshold: int = 3,
@@ -1685,7 +1626,6 @@ def make_verifier(backend: str = "cpu", clock=None,
         # chip's trip/reprobe schedule is as deterministic under a
         # virtual clock as the whole-backend breaker's
         return TpuSigVerifier(max_pending=max_pending,
-                              compile_cache_dir=compile_cache_dir,
                               now_fn=now_fn,
                               device_breaker_threshold=breaker_threshold,
                               device_breaker_cooldown=breaker_cooldown)

@@ -107,12 +107,28 @@ class Application:
         self.persistent_state = (PersistentState(self.database)
                                  if self.database else None)
 
+        # the device as JAX reports it ({platform, device_kind, count});
+        # None on a node with no device backend, which never imports JAX.
+        # A device backend places the compile cache here — main thread,
+        # before anything can compile — and refuses to start without the
+        # chip: the breaker + CPU fallback below survive a device that
+        # fails, they do not stand in for one that was never there.
+        self.device: Optional[dict] = None
+        if config.SIG_VERIFY_BACKEND in ("tpu", "tpu-async") or \
+                config.HASH_BACKEND == "tpu":
+            from ..parallel.device import (
+                configure_compile_cache, require_accelerator,
+            )
+            configure_compile_cache()
+            self.device = require_accelerator(
+                "SIG_VERIFY_BACKEND=%r / HASH_BACKEND=%r" % (
+                    config.SIG_VERIFY_BACKEND, config.HASH_BACKEND))
+
         # crypto backend (config-gated; the TPU boundary); device
         # backends sit behind a circuit breaker with a CPU fallback
         self.sig_verifier = make_verifier(
             config.SIG_VERIFY_BACKEND, clock,
             config.SIG_VERIFY_MAX_BATCH,
-            config.SIG_VERIFY_COMPILE_CACHE_DIR,
             metrics=self.metrics, tracer=self.tracer,
             faults=self.faults, flight_recorder=self.flight_recorder,
             breaker_threshold=config.SIG_VERIFY_BREAKER_THRESHOLD,
@@ -125,7 +141,6 @@ class Application:
         from ..crypto.batch_hasher import make_hasher
         self.batch_hasher = make_hasher(
             config.HASH_BACKEND, clock=clock,
-            compile_cache_dir=config.SIG_VERIFY_COMPILE_CACHE_DIR,
             metrics=self.metrics, tracer=self.tracer,
             faults=self.faults, flight_recorder=self.flight_recorder,
             breaker_threshold=config.SIG_VERIFY_BREAKER_THRESHOLD,
@@ -336,7 +351,7 @@ class Application:
                 getattr(self.sig_verifier, "wants_prewarm", False):
             self.sig_verifier.warmup(wait=False)
         # the hash kernel warms beside the verify kernel: same
-        # persistent XLA cache, same no-lazy-compile-on-consensus rule
+        # no-lazy-compile-on-consensus rule
         if self.config.SIG_VERIFY_WARMUP and \
                 getattr(self.batch_hasher, "wants_warmup", False):
             self.batch_hasher.warmup(wait=False)
@@ -377,10 +392,10 @@ class Application:
 
     def stop(self) -> None:
         self.state = AppState.APP_STOPPING
-        # persist the cockpit-derived warmup bucket plan beside the XLA
-        # cache (ISSUE 11): the next start warms only the shapes this
-        # run's real traffic used. Best-effort no-op on CPU backends or
-        # when the cockpit saw no traffic.
+        # persist the cockpit-derived warmup bucket plan beside the
+        # bucket directory (ISSUE 11): the next start warms only the
+        # shapes this run's real traffic used. Best-effort no-op on CPU
+        # backends, without buckets or when the cockpit saw no traffic.
         save_plan = getattr(self.sig_verifier, "save_warmup_plan", None)
         if save_plan is not None:
             save_plan()
@@ -426,11 +441,20 @@ class Application:
         return self._load_generator
 
     def enable_buckets(self, bucket_dir: Optional[str] = None) -> None:
+        import os
         from ..bucket.bucket_index import BucketDbStats
         from ..bucket.bucket_manager import BucketManager
         lm = self.ledger_manager
+        bucket_dir = bucket_dir or self.config.BUCKET_DIR_PATH
+        # node state a device verifier keeps across restarts lives
+        # beside the bucket directory, never in the compile cache
+        dev = getattr(self.sig_verifier, "inner", self.sig_verifier)
+        if hasattr(dev, "warmup_plan_path"):
+            dev.warmup_plan_path = os.path.join(
+                os.path.dirname(os.path.abspath(bucket_dir)),
+                dev.PLAN_BASENAME)
         self.bucket_manager = BucketManager(
-            bucket_dir or self.config.BUCKET_DIR_PATH,
+            bucket_dir,
             stats=lm.apply_stats,
             bucketdb_stats=BucketDbStats(metrics=self.metrics,
                                          tracer=self.tracer,

@@ -168,6 +168,9 @@ class CommandHandler:
         out: dict = {
             "configured_backend": self.app.config.SIG_VERIFY_BACKEND,
             "verifier": v.name,
+            # platform / device_kind / count as JAX reported them at
+            # start-up; null on a node with no device backend
+            "device": getattr(self.app, "device", None),
         }
         stats = getattr(v, "stats", None)
         if stats is not None:
@@ -209,6 +212,7 @@ class CommandHandler:
         out: dict = {
             "configured_backend": self.app.config.HASH_BACKEND,
             "hasher": h.name,
+            "device": getattr(self.app, "device", None),
         }
         stats = getattr(h, "stats", None)
         if stats is not None:

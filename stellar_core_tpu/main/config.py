@@ -156,8 +156,6 @@ class Config:
         # AOT-compile all kernel bucket shapes at startup (background
         # thread) so no lazy compile lands on the consensus path
         self.SIG_VERIFY_WARMUP = True
-        # persistent XLA compilation cache (None = env or ~/.cache default)
-        self.SIG_VERIFY_COMPILE_CACHE_DIR: Optional[str] = None
 
         # device-dispatch circuit breaker (crypto/batch_verifier.py,
         # docs/robustness.md): consecutive dispatch failures before the
@@ -170,8 +168,8 @@ class Config:
         # "cpu" (default, hashlib), "cpu-resilient" (breaker-wrapped CPU,
         # for chaos runs on device-less containers), "tpu" (JAX batched
         # kernel behind the breaker + CPU fallback). The hasher shares
-        # the SIG_VERIFY_BREAKER_* knobs and compile-cache dir — one
-        # device failure domain, one operator surface.
+        # the SIG_VERIFY_BREAKER_* knobs — one device failure domain,
+        # one operator surface.
         self.HASH_BACKEND = "cpu"
         # signed state-checkpoint cadence (ledger/state_commitment.py):
         # a StateCheckpoint {seq, header hash, Merkle root, node sig} is

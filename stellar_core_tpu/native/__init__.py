@@ -57,6 +57,9 @@ else:
 _LOCK = threading.Lock()
 _LIB: Optional[ctypes.CDLL] = None
 _TRIED = False
+# engine name -> why it is missing here (engine_status): every engine
+# below degrades to a slower Python path, which must not also be silent
+_WHY_MISSING: dict = {}
 
 
 def _cc_build(src_path: str, so_path: str, include_dir: str) -> bool:
@@ -64,6 +67,7 @@ def _cc_build(src_path: str, so_path: str, include_dir: str) -> bool:
     Shared by the prep library and the XDR extension builds."""
     import tempfile
     extra = list(_SANITIZE_FLAGS)
+    why = "no C compiler (cc/gcc/g++) on PATH"
     # the compiler must NOT inherit a sanitizer-runtime LD_PRELOAD: the
     # preload is for loading the built .so into THIS process, and a
     # TSan-preloaded python forking gcc can deadlock in the runtime's
@@ -87,6 +91,8 @@ def _cc_build(src_path: str, so_path: str, include_dir: str) -> bool:
             os.rename(tmp.name, so_path)  # atomic: concurrent builders ok
             return True
         os.unlink(tmp.name)
+        why = "%s failed: %s" % (cc, r.stderr.strip()[-400:])
+    _WHY_MISSING[os.path.basename(src_path)] = why
     return False
 
 
@@ -136,13 +142,29 @@ def _load() -> Optional[ctypes.CDLL]:
                 ctypes.c_char_p, ctypes.c_char_p, ctypes.c_void_p,
                 ctypes.c_void_p, ctypes.c_int64, ctypes.c_void_p]
             _LIB = lib
-        except Exception:
+        except Exception as e:
+            _WHY_MISSING["prep.c"] = repr(e)
             _LIB = None
         return _LIB
 
 
 def available() -> bool:
     return _load() is not None
+
+
+def engine_status() -> dict:
+    """{engine: None | why it is missing} for the three native engines
+    the served path silently runs slower without — "prep" (verify-kernel
+    host prep; numpy/hashlib otherwise), "apply" (the close engine; the
+    Python apply loop otherwise), "xdr" (the serializer; fastcodec
+    otherwise). Builds whatever is not built yet."""
+    _compile_xdr_ext()
+    have = {"prep": (_load(), "prep.c"),
+            "apply": (apply_engine(), "applyc.c"),
+            "xdr": (_XDR_MOD, "xdrc.c")}
+    return {name: None if engine is not None else _WHY_MISSING.get(
+                src, "switched off by its SCT_NATIVE_* variable")
+            for name, (engine, src) in have.items()}
 
 
 def prepare_batch_native(pub_arr: np.ndarray, sig_arr: np.ndarray,
@@ -353,7 +375,8 @@ def apply_engine():
             mod = importlib.util.module_from_spec(spec)
             spec.loader.exec_module(mod)
             _APPLY_MOD = mod
-        except Exception:
+        except Exception as e:
+            _WHY_MISSING["applyc.c"] = repr(e)
             _APPLY_MOD = None
         return _APPLY_MOD
 
@@ -399,7 +422,8 @@ def _compile_xdr_ext() -> None:
             mod = importlib.util.module_from_spec(spec)
             spec.loader.exec_module(mod)
             _XDR_MOD = mod
-        except Exception:
+        except Exception as e:
+            _WHY_MISSING["xdrc.c"] = repr(e)
             _XDR_MOD = None
         _XDR_TRIED = True
 

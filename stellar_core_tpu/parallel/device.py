@@ -1,0 +1,139 @@
+"""The device boundary's two process-wide facts: which platform JAX
+resolved, and where its persistent compile cache lives.
+
+Importing this module never imports JAX — launchers that must stay off
+the chip (bench.py's parent, tests/conftest.py) call
+`configure_compile_cache()` too.
+"""
+
+from __future__ import annotations
+
+import contextlib
+import os
+import sys
+import threading
+from typing import Iterator, Optional
+
+_CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
+_CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+
+
+def configure_compile_cache() -> str:
+    """Place JAX's persistent compile cache, by the one rule: where
+    JAX_COMPILATION_CACHE_DIR is set JAX already has the directory and
+    nothing is set here; otherwise the cache is `<checkout>/.jax_cache`.
+    Returns the directory.
+
+    Call it on the main thread before the first compile of the process:
+    JAX opens its cache once, at the first compile, and ignores a
+    directory set after that. The variable is exported, so child
+    processes (and a JAX this process has yet to import) keep the same
+    cache."""
+    path = os.environ.get(_CACHE_ENV)
+    if not path:
+        path = os.environ[_CACHE_ENV] = os.path.join(_CHECKOUT, ".jax_cache")
+        jax = sys.modules.get("jax")
+        if jax is not None:     # imported before the export: tell it
+            jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
+def compile_cache_dir() -> Optional[str]:
+    """The persistent compile cache directory as JAX itself holds it
+    (None: this process compiles without one)."""
+    import jax
+    return jax.config.jax_compilation_cache_dir or None
+
+
+class DeviceUnavailable(RuntimeError):
+    """A device backend is configured and JAX resolved no accelerator."""
+
+
+def cpu_requested() -> bool:
+    """True when JAX_PLATFORMS itself puts the CPU first — how tier-1
+    and the verify skill run the device backends on jax-CPU. The chip
+    machine's `tpu,cpu` does not count: there the CPU is only the host
+    platform beside the chip."""
+    first = os.environ.get("JAX_PLATFORMS", "").split(",")[0]
+    return first.strip().lower() == "cpu"
+
+
+def device_info() -> dict:
+    """The device as JAX reports it (initializes the backend)."""
+    import jax
+    devs = jax.devices()
+    return {"platform": devs[0].platform,
+            "device_kind": devs[0].device_kind, "count": len(devs)}
+
+
+def require_accelerator(what: str) -> dict:
+    """device_info(), or DeviceUnavailable naming the platform found
+    when it is not a TPU and the CPU was not asked for by name. Call it
+    where a device backend is configured, OUTSIDE the breaker/fallback
+    layers: they exist to survive a device that fails, not to stand in
+    for one that was never there."""
+    info = device_info()
+    if info["platform"] != "tpu" and not cpu_requested():
+        raise DeviceUnavailable(
+            "%s needs a TPU, but JAX resolved platform %r (%s x%d); "
+            "refusing to start. Run on a machine with a chip, or set "
+            "JAX_PLATFORMS=cpu to run the device path on jax-CPU on "
+            "purpose." % (what, info["platform"], info["device_kind"],
+                          info["count"]))
+    return info
+
+
+# -- persistent-cache classification -----------------------------------------
+# JAX reports what its persistent cache did for each compile through
+# jax.monitoring events, on the compiling thread. Warmup reads them to
+# say whether a shape was loaded ("hit"), compiled and written ("miss"),
+# or neither — a compile under JAX's persistence floor is never written,
+# and an executable still in this process's memory compiles nothing.
+
+_EVENT_PREFIX = "/jax/compilation_cache/"
+_tls = threading.local()
+_listener_lock = threading.Lock()
+_listening = False
+
+
+def _on_event(event: str, **_kw) -> None:
+    counts = getattr(_tls, "counts", None)
+    if counts is not None and event.startswith(_EVENT_PREFIX):
+        key = event[len(_EVENT_PREFIX):]
+        counts[key] = counts.get(key, 0) + 1
+
+
+@contextlib.contextmanager
+def compile_cache_events() -> Iterator[dict]:
+    """Counts of JAX's compilation-cache events (`cache_hits`,
+    `cache_misses`, `compile_requests_use_cache`) for compiles this
+    thread runs inside the block."""
+    global _listening
+    with _listener_lock:
+        if not _listening:
+            from jax import monitoring
+            monitoring.register_event_listener(_on_event)
+            _listening = True
+    outer = getattr(_tls, "counts", None)
+    _tls.counts = counts = {}
+    try:
+        yield counts
+    finally:
+        _tls.counts = outer
+
+
+def cache_hit(counts: dict) -> Optional[bool]:
+    """True: every compile in the block loaded from the persistent
+    cache; False: at least one was compiled and written; None: neither
+    happened (nothing persisted, or nothing compiled)."""
+    if counts.get("cache_misses"):
+        return False
+    if counts.get("cache_hits"):
+        return True
+    return None
+
+
+def compile_cache_entries(path: str) -> int:
+    """Files under the compile cache directory (0 when it is absent)."""
+    return sum(len(files) for _d, _s, files in os.walk(path))

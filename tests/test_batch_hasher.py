@@ -198,13 +198,15 @@ _CHILD = r"""
 import json, os
 from stellar_core_tpu.crypto.batch_hasher import HasherStats, TpuBatchHasher
 
+import jax
+# the tiny test shape compiles in ms on CPU — drop JAX's persistence
+# floor so the cache actually records it (the floor only skips compiles
+# too cheap to be worth caching)
+jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+
 def warmed_node():
-    h = TpuBatchHasher(compile_cache_dir=os.environ["SCT_TEST_CACHE"])
+    h = TpuBatchHasher()
     h.WARM_SHAPES = ((32, 1),)
-    # the tiny test shape compiles in ms on CPU — drop the persistence
-    # floor so the cache actually records it (the production floor only
-    # skips compiles too cheap to be worth caching)
-    h.CACHE_PERSIST_MIN_S = 0.0
     h.stats = HasherStats()
     h.warmup(wait=True)
     import hashlib
@@ -217,7 +219,6 @@ entries_after_cold = sum(len(fs) for _d, _s, fs
 # the "restart": drop every in-memory executable, then a FRESH hasher
 # instance warms against the same persistent dir — the same mechanism a
 # process restart exercises, without paying a second jax import
-import jax
 jax.clear_caches()
 warm = warmed_node()
 entries_after_warm = sum(len(fs) for _d, _s, fs
@@ -227,6 +228,8 @@ print("HASH_COLD_JSON " + json.dumps(
      "cold_cache_enabled": cold["compile_cache"]["enabled"],
      "warm_state": warm["warmup"]["state"],
      "warm_cache_enabled": warm["compile_cache"]["enabled"],
+     "cold_class": cold["warmup"]["shapes"]["32x1"]["cache"],
+     "warm_class": warm["warmup"]["shapes"]["32x1"]["cache"],
      "entries_after_cold": entries_after_cold,
      "entries_after_warm": entries_after_warm}))
 """
@@ -241,8 +244,7 @@ def test_hash_warmup_restart_uses_persistent_cache(tmp_path):
     jax import) keeps the tier-1 cost at half the verifier twin's."""
     cache = str(tmp_path / "hash-xla-cache")
     env = dict(os.environ)
-    env["SCT_TEST_CACHE"] = cache
-    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    env["SCT_TEST_CACHE"] = env["JAX_COMPILATION_CACHE_DIR"] = cache
     r = subprocess.run([sys.executable, "-c", _CHILD],
                        capture_output=True, text=True, timeout=900,
                        env=env)
@@ -258,3 +260,4 @@ def test_hash_warmup_restart_uses_persistent_cache(tmp_path):
         "warmup persisted nothing to the compile cache"
     assert got["entries_after_warm"] == got["entries_after_cold"], \
         "the warm restart re-compiled instead of loading from the cache"
+    assert (got["cold_class"], got["warm_class"]) == ("miss", "hit"), got

@@ -325,10 +325,14 @@ def test_prewarm_batches_checkpoint_sigs(publisher):
         raw_calls.__setitem__(0, raw_calls[0] + 1) or orig_raw(k, s, m))
 
     def counting_batch(triples):
-        # CpuSigVerifier.verify_many drains misses through ONE native
-        # batch call now; count each triple like a raw verify
-        raw_calls[0] += len(triples)
-        return orig_batch(triples)
+        # CpuSigVerifier.verify_many drains misses through the bulk
+        # call: one native call without `cryptography`, a loop over the
+        # (patched) raw_verify with it. Each triple counts ONCE either
+        # way — the invariant chip_smoke.py asserts on the device.
+        n0 = raw_calls[0]
+        out = orig_batch(triples)
+        raw_calls[0] = n0 + len(triples)
+        return out
 
     _keys.raw_verify_batch = counting_batch
     try:

@@ -1,44 +1,50 @@
-"""Cold-start story (VERDICT r2 #9): a restarted validator must not re-pay
-kernel compilation — the persistent XLA cache makes the second process's
-warmup fast.
+"""Cold-start story: a restarted validator must not re-pay kernel
+compilation — the persistent compile cache turns the second process's
+warmup into a load.
 
 Reference analog: no lazy work on the consensus path; a stellar-core
 restart is serving envelopes as soon as state is restored. Here the
-equivalent hazard is XLA compilation (~67s on TPU in round 2), so
-TpuSigVerifier.warmup() + jax_compilation_cache_dir must turn a restart
-into a cache load.
+equivalent hazard is XLA compilation, so TpuSigVerifier.warmup() over the
+compile cache placed by the one rule (parallel/device.py: where
+JAX_COMPILATION_CACHE_DIR points, else `<repo>/.jax_cache`) must turn a
+restart into a cache load. What a CPU run can say about that is counts
+and classes, not seconds: how long a load takes is the chip's to answer
+(chip_smoke.py prints it per shape).
 """
 
 import json
 import os
 import subprocess
 import sys
-import time
-
-import pytest
 
 _CHILD = r"""
-import json, os, time
-t0 = time.perf_counter()
-from stellar_core_tpu.crypto.batch_verifier import TpuSigVerifier
+import json, os
+from stellar_core_tpu.parallel.device import (
+    compile_cache_entries, configure_compile_cache)
+cache = configure_compile_cache()
+assert cache == os.environ["SCT_TEST_CACHE"], cache
+from stellar_core_tpu.crypto.batch_verifier import (
+    TpuSigVerifier, VerifierStats)
 from stellar_core_tpu.crypto.keys import SecretKey
-v = TpuSigVerifier(compile_cache_dir=os.environ["SCT_TEST_CACHE"])
+before = compile_cache_entries(cache)
+v = TpuSigVerifier()
 v.BUCKETS = (32,)
+v.stats = VerifierStats()
 v.warmup(wait=True)
-warm_s = time.perf_counter() - t0
 sk = SecretKey.from_seed(b"\x31" * 32)
-t0 = time.perf_counter()
-res = v.verify_many([(sk.public_key.key_bytes, sk.sign(b"m"), b"m")])
-verify_s = time.perf_counter() - t0
-assert res == [True]
-print("COLD_JSON " + json.dumps({"warm_s": warm_s, "verify_s": verify_s}))
+assert v.verify_many([(sk.public_key.key_bytes, sk.sign(b"m"), b"m")]) \
+    == [True]
+j = v.stats.to_json()
+print("COLD_JSON " + json.dumps(
+    {"cache": j["warmup"]["buckets"]["32"]["cache"],
+     "dir": j["compile_cache"]["dir"],
+     "before": before, "after": compile_cache_entries(cache)}))
 """
 
 
 def _run_node(cache_dir: str) -> dict:
     env = dict(os.environ)
-    env["SCT_TEST_CACHE"] = cache_dir
-    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    env["SCT_TEST_CACHE"] = env["JAX_COMPILATION_CACHE_DIR"] = cache_dir
     r = subprocess.run([sys.executable, "-c", _CHILD],
                        capture_output=True, text=True, timeout=900,
                        env=env)
@@ -50,19 +56,14 @@ def _run_node(cache_dir: str) -> dict:
 
 
 def test_restart_compiles_from_cache(tmp_path):
-    """Second process start loads the kernel from the persistent cache —
-    dramatically faster than the cold compile. (Absolute restart time on
-    this CPU test host is dominated by jax import + cache deserialization;
-    the TPU validator's restart compile time is what BENCH records as
-    compile_s.)"""
+    """The first process compiles the kernel and writes it to the cache
+    the environment named; the second adds no entry and its warmup
+    classifies the shape `hit`."""
     cache = str(tmp_path / "xla-cache")
     cold = _run_node(cache)
-    assert os.path.exists(cache) and os.listdir(cache), \
-        "persistent compilation cache was not populated"
+    assert cold["dir"] == cache
+    assert cold["cache"] == "miss", cold
+    assert cold["before"] == 0 and cold["after"] > 0, cold
     warm = _run_node(cache)
-    assert warm["warm_s"] < cold["warm_s"] / 2, (cold, warm)
-    # generous absolute bounds: this host runs suites concurrently and the
-    # python+jax import alone is ~15s; the RELATIVE checks are the real
-    # contract for both warmup and the first live batch
-    assert warm["warm_s"] < 120.0, warm
-    assert warm["verify_s"] < max(10.0, cold["verify_s"] * 3), (cold, warm)
+    assert warm["cache"] == "hit", warm
+    assert warm["before"] == warm["after"] == cold["after"], (cold, warm)

@@ -1,0 +1,95 @@
+"""The device boundary's start-up rules (parallel/device.py): where the
+compile cache lives, and that a device backend refuses to start without
+the chip instead of running the node on jax-CPU behind its fallbacks.
+"""
+
+import os
+import subprocess
+import sys
+
+import pytest
+
+from stellar_core_tpu.main.application import Application
+from stellar_core_tpu.main.config import Config
+from stellar_core_tpu.parallel import device
+from stellar_core_tpu.util.timer import ClockMode, VirtualClock
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def _app(backend: str = "cpu", hash_backend: str = "cpu") -> Application:
+    cfg = Config.test_config(0, backend=backend)
+    cfg.HASH_BACKEND = hash_backend
+    return Application(VirtualClock(ClockMode.VIRTUAL_TIME), cfg)
+
+
+@pytest.mark.parametrize("platforms", ["", "tpu,cpu", "tpu"])
+@pytest.mark.parametrize("backend,hash_backend", [
+    ("tpu", "cpu"), ("tpu-async", "cpu"), ("cpu", "tpu")])
+def test_device_backend_refuses_to_start_without_the_chip(
+        monkeypatch, platforms, backend, hash_backend):
+    """This process's JAX resolved the CPU. Unless JAX_PLATFORMS itself
+    asks for the CPU first (how this suite runs), a node configured onto
+    the device must not come up — its breaker and CPU fallback would
+    carry it, and the only trace would be a span tag."""
+    monkeypatch.setenv("JAX_PLATFORMS", platforms)
+    with pytest.raises(device.DeviceUnavailable, match="'cpu'"):
+        _app(backend, hash_backend)
+
+
+def test_cpu_by_name_runs_the_device_path_on_jax_cpu(monkeypatch):
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+    app = _app("tpu")
+    assert app.device["platform"] == "cpu" and app.device["count"] >= 1
+    # the endpoints say which device the backends run on
+    assert app.command_handler.cmd_verifier({})["device"] == app.device
+    assert app.command_handler.cmd_hasher({})["device"] == app.device
+    # a cpu-backend node never asks JAX anything
+    monkeypatch.setenv("JAX_PLATFORMS", "")
+    assert _app("cpu").device is None
+
+
+def test_compile_cache_rule(tmp_path):
+    """JAX_COMPILATION_CACHE_DIR set: JAX has it and nothing else is set;
+    unset: `<checkout>/.jax_cache`, told to a JAX that was imported
+    first, and exported for child processes."""
+    code = ("import os, sys\n"
+            "if sys.argv[1] == 'jax-first': import jax\n"
+            "from stellar_core_tpu.parallel.device import (\n"
+            "    compile_cache_dir, configure_compile_cache)\n"
+            "print('RULE', configure_compile_cache(), compile_cache_dir(),\n"
+            "      os.environ['JAX_COMPILATION_CACHE_DIR'])\n")
+
+    def run(order: str, env_dir) -> list:
+        env = dict(os.environ, HOME=str(tmp_path / "home"))
+        env.pop("JAX_COMPILATION_CACHE_DIR", None)
+        if env_dir:
+            env["JAX_COMPILATION_CACHE_DIR"] = env_dir
+        r = subprocess.run([sys.executable, "-c", code, order], cwd=REPO,
+                           env=env, capture_output=True, text=True,
+                           timeout=300)
+        assert r.returncode == 0, r.stderr[-2000:]
+        return [ln.split()[1:] for ln in r.stdout.splitlines()
+                if ln.startswith("RULE ")][0]
+
+    default = os.path.join(REPO, ".jax_cache")
+    assert run("jax-first", None) == [default] * 3
+    assert run("jax-later", None) == [default] * 3
+    outside = str(tmp_path / "outside-cache")
+    assert run("jax-first", outside) == [outside] * 3
+    assert not (tmp_path / "home").exists()      # nothing under ~
+
+
+def test_chip_smoke_needs_the_chip():
+    """`python chip_smoke.py` on a machine whose JAX resolves the CPU
+    exits non-zero naming the platform, prints no result, and has not
+    imported the package — let alone compiled anything."""
+    r = subprocess.run(
+        [sys.executable, "-X", "importtime",
+         os.path.join(REPO, "chip_smoke.py")],
+        cwd=REPO, env=dict(os.environ, JAX_PLATFORMS="cpu"),
+        capture_output=True, text=True, timeout=300)
+    assert r.returncode != 0
+    assert "platform 'cpu'" in r.stderr and "not a TPU" in r.stderr
+    assert r.stdout == ""
+    assert "stellar_core_tpu" not in r.stderr    # -X importtime log

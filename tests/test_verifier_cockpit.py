@@ -160,33 +160,23 @@ def test_threaded_queue_depth_inflight_and_wait(monkeypatch):
 
 # ------------------------------------------------------ warmup observability
 
-def _stub_warmup(v, tmp_path, per_bucket_new_files=()):
-    """Patch the jax-touching pieces of warmup: the compile-cache enable
-    resolves to a real tmp dir and each bucket 'compile' optionally
-    drops a new cache file (-> miss classification)."""
-    cache = tmp_path / "xla-cache"
-    cache.mkdir(exist_ok=True)
-
-    def fake_enable():
-        v._cache_path = str(cache)
-        if v.stats is not None:
-            v.stats.compile_cache_enabled(str(cache))
-
-    new_files = set(per_bucket_new_files)
+def _stub_warmup(v, cache_events):
+    """Patch the kernel-compiling piece of warmup: each bucket 'compile'
+    reports the JAX compilation-cache events listed for it (the way
+    jax.monitoring would, on the compiling thread)."""
+    from stellar_core_tpu.parallel import device
 
     def fake_compile(b):
-        if b in new_files:
-            (cache / ("exec-%d" % b)).write_text("x")
+        for ev in cache_events.get(b, ()):
+            device._on_event("/jax/compilation_cache/" + ev)
 
-    v._enable_compile_cache = fake_enable
     v._compile_bucket = fake_compile
-    return cache
 
 
 def test_warmup_instants_stamps_and_cache_classification(tmp_path):
     """Warmup emits begin/bucket/end tracer instants, stamps per-bucket
     progress on the app clock, and classifies each bucket compile as a
-    persistent-cache hit or miss by diffing the cache dir."""
+    persistent-cache hit or miss from JAX's own cache events."""
     from stellar_core_tpu.util.timer import ClockMode, VirtualClock
 
     clock = VirtualClock(ClockMode.VIRTUAL_TIME)
@@ -196,12 +186,9 @@ def test_warmup_instants_stamps_and_cache_classification(tmp_path):
     tr.enable()
     v = TpuSigVerifier()
     v.BUCKETS = (128, 512)
-    # the stubbed per-bucket 'compile' is instant; drop the persistence
-    # threshold so a no-new-entry compile classifies as a hit (the
-    # default-threshold "unknown" rule is pinned separately below)
-    v.CACHE_PERSIST_MIN_S = 0.0
     v.stats = VerifierStats(metrics=reg, tracer=tr, now_fn=clock.now)
-    _stub_warmup(v, tmp_path, per_bucket_new_files={128})  # 128 cold
+    _stub_warmup(v, {128: ["compile_requests_use_cache", "cache_misses"],
+                     512: ["compile_requests_use_cache", "cache_hits"]})
     v.warmup(wait=True)
     assert v._warmed
     w = v.stats.warmup_json()
@@ -231,18 +218,16 @@ def test_warmup_instants_stamps_and_cache_classification(tmp_path):
     assert m["verifier.warmup.bucket-seconds"]["count"] == 2
 
 
-def test_warmup_fast_compile_classifies_unknown_not_hit(tmp_path):
-    """A compile faster than jax's persistence threshold writes no
-    cache entry either way, so 'no new entry' proves nothing: it must
-    classify 'unknown', never inflate the compile-cache hit counter
-    (a node silently re-paying sub-threshold compiles every restart
-    must not read as a healthy cache)."""
+def test_warmup_fast_compile_classifies_unknown_not_hit():
+    """A compile faster than jax's persistence threshold is neither
+    loaded nor written: it must classify 'unknown', never inflate the
+    compile-cache hit counter (a node silently re-paying sub-threshold
+    compiles every restart must not read as a healthy cache)."""
     reg = MetricsRegistry()
     v = TpuSigVerifier()
     v.BUCKETS = (128,)
-    assert v.CACHE_PERSIST_MIN_S == 0.5     # default threshold
     v.stats = VerifierStats(metrics=reg)
-    _stub_warmup(v, tmp_path)               # instant, no new entry
+    _stub_warmup(v, {128: ["compile_requests_use_cache"]})
     v.warmup(wait=True)
     w = v.stats.warmup_json()
     assert w["state"] == "done"
@@ -254,8 +239,8 @@ def test_warmup_fast_compile_classifies_unknown_not_hit(tmp_path):
 
 
 def test_warmup_failure_dumps_flight(tmp_path):
-    """A warmup failure was a swallowed log.warning; now it marks the
-    failure meter, sets the state gauge and leaves a flight dump."""
+    """A warmup failure marks the failure meter, sets the state gauge
+    and leaves a flight dump; the caller that waited gets it raised."""
     reg = MetricsRegistry()
     tr = Tracer()
     tr.enable()
@@ -263,13 +248,13 @@ def test_warmup_failure_dumps_flight(tmp_path):
     v = TpuSigVerifier()
     v.BUCKETS = (128,)
     v.stats = VerifierStats(metrics=reg, tracer=tr, flight_recorder=fr)
-    v._enable_compile_cache = lambda: None
 
     def boom(b):
         raise RuntimeError("no device")
 
     v._compile_bucket = boom
-    v.warmup(wait=True)
+    with pytest.raises(RuntimeError, match="no device"):
+        v.warmup(wait=True)
     assert not v._warmed
     assert v.stats.warmup["state"] == "failed"
     assert "no device" in v.stats.warmup["error"]
@@ -286,17 +271,16 @@ def test_warmup_failure_dumps_flight(tmp_path):
 
 
 def test_compile_cache_unavailable_dumps_flight(tmp_path):
-    """Compile-cache unavailability (previously a swallowed log.warning
-    in _enable_compile_cache) marks a meter, emits a tracer instant and
-    leaves a flight dump naming the error."""
+    """A node compiling without a persistent cache marks a meter, emits
+    a tracer instant and leaves a flight dump naming the error."""
     reg = MetricsRegistry()
     tr = Tracer()
     tr.enable()
     fr = FlightRecorder(tr, metrics=reg, out_dir=str(tmp_path))
     st = VerifierStats(metrics=reg, tracer=tr, flight_recorder=fr)
-    st.compile_cache_error("PermissionError('/ro/cache')")
+    st.set_compile_cache_dir(None)
     assert st.compile_cache["enabled"] is False
-    assert "PermissionError" in st.compile_cache["error"]
+    assert "no persistent compile cache" in st.compile_cache["error"]
     m = reg.to_json()
     assert m["verifier.compile-cache.unavailable"]["count"] == 1
     assert m["verifier.compile-cache.enabled"]["value"] == 0

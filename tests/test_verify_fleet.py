@@ -16,6 +16,7 @@ and staging layers and never touch a device.
 """
 
 import json
+import os
 
 import numpy as np
 import pytest
@@ -339,28 +340,29 @@ def test_warmup_plan_dedups_low_occupancy_extras():
     assert info["low_occupancy_extra"] == []
 
 
-def test_warmup_plan_persisted_beside_cache_and_used(tmp_path):
-    """save_warmup_plan writes the cockpit plan beside the XLA cache;
-    a fresh verifier on the same cache dir warms exactly that set and
-    stamps source=cockpit (the warm-restart contract)."""
-    cache = str(tmp_path / "xla-cache")
+def test_warmup_plan_persisted_and_used(tmp_path):
+    """save_warmup_plan writes the cockpit plan to the node's plan path;
+    a fresh verifier on the same path warms exactly that set and stamps
+    source=cockpit (the warm-restart contract)."""
+    plan_path = str(tmp_path / TpuSigVerifier.PLAN_BASENAME)
     st = VerifierStats()
     for _ in range(4):
         st.record_bucket_dispatch(512, 512, 0)
-    v = TpuSigVerifier(compile_cache_dir=cache)
+    v = TpuSigVerifier()
+    v.warmup_plan_path = plan_path
     v.stats = st
-    path = v.save_warmup_plan()
-    assert path is not None and path.endswith("warmup_buckets.json")
+    assert v.save_warmup_plan() == plan_path
+    path = plan_path
     with open(path) as fh:
         blob = json.load(fh)
     assert blob["buckets"] == [512]
     assert blob["traffic"] == {"512": 4}
 
-    # fresh process analog: same cache dir, no cockpit history
-    v2 = TpuSigVerifier(compile_cache_dir=cache)
+    # fresh process analog: same plan path, no cockpit history
+    v2 = TpuSigVerifier()
+    v2.warmup_plan_path = plan_path
     v2.stats = VerifierStats()
     compiled = []
-    v2._enable_compile_cache = lambda: None
     v2._compile_bucket = compiled.append
     v2.warmup(wait=True)
     assert compiled == [512]
@@ -370,22 +372,44 @@ def test_warmup_plan_persisted_beside_cache_and_used(tmp_path):
     assert w["planned"] == [512]
 
     # a plan that no longer fits the candidate ladder is rejected
-    v3 = TpuSigVerifier(compile_cache_dir=cache)
+    v3 = TpuSigVerifier()
+    v3.warmup_plan_path = plan_path
     v3.BUCKETS = (128, 2048)
     v3.stats = VerifierStats()
     compiled3 = []
-    v3._enable_compile_cache = lambda: None
     v3._compile_bucket = compiled3.append
     v3.warmup(wait=True)
     assert compiled3 == [128, 2048]
     assert v3.stats.warmup_json()["source"] == "default"
 
 
-def test_warmup_plan_not_saved_without_evidence(tmp_path):
-    v = TpuSigVerifier(compile_cache_dir=str(tmp_path / "c"))
+def test_warmup_plan_not_saved_without_evidence_or_path(tmp_path):
+    v = TpuSigVerifier()
+    v.warmup_plan_path = str(tmp_path / "plan.json")
     assert v.save_warmup_plan() is None          # no stats at all
     v.stats = VerifierStats()
     assert v.save_warmup_plan() is None          # stats but no traffic
+    v.stats.record_bucket_dispatch(512, 512, 0)
+    v.warmup_plan_path = None                    # no node state dir
+    assert v.save_warmup_plan() is None
+
+
+def test_warmup_plan_lives_beside_the_bucket_directory(tmp_path):
+    """A node keeps its plan with its own state — beside its bucket
+    directory — never in the compile cache, which may be shared."""
+    from stellar_core_tpu.main.application import Application
+    from stellar_core_tpu.main.config import Config
+    from stellar_core_tpu.util.timer import ClockMode, VirtualClock
+    app = Application(VirtualClock(ClockMode.VIRTUAL_TIME),
+                      Config.test_config(0, backend="tpu-async"))
+    dev = app.sig_verifier.inner
+    assert dev.warmup_plan_path is None          # no buckets, no plan
+    app.enable_buckets(str(tmp_path / "node" / "buckets"))
+    assert dev.warmup_plan_path == \
+        str(tmp_path / "node" / "warmup_buckets.json")
+    dev.stats.record_bucket_dispatch(512, 512, 0)
+    app.stop()                                   # persists the plan
+    assert os.path.exists(dev.warmup_plan_path)
 
 
 def test_unbucketed_drain_sizes_feed_bucket_traffic():
