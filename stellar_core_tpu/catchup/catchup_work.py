@@ -23,6 +23,7 @@ from ..historywork.works import (BatchDownloadWork, DownloadBucketsWork,
                                  VerifyLedgerChainWork)
 from ..util.log import get_logger
 from ..util.tmpdir import TmpDir
+from ..util.tracing import app_tracer
 from ..util.xdrstream import XDRInputFileStream
 from ..work.basic_work import (FAILURE, RETRY_NEVER, RUNNING, SUCCESS,
                                WAITING, BasicWork, State)
@@ -39,6 +40,10 @@ class CatchupWork(BasicWork):
 
     GET_HAS, GET_APPLY_HAS, DOWNLOAD_VERIFY, BUCKETS, APPLY_TXS, DONE = \
         range(6)
+    # catchup.phase.<name>: one completed span per phase as it ends
+    PHASE_NAMES = {GET_HAS: "get_has", GET_APPLY_HAS: "get_apply_has",
+                   DOWNLOAD_VERIFY: "download_verify", BUCKETS: "buckets",
+                   APPLY_TXS: "apply_txs"}
 
     def __init__(self, app, config: Optional[CatchupConfiguration] = None,
                  archive=None,
@@ -52,6 +57,7 @@ class CatchupWork(BasicWork):
         self.trusted_hash = trusted_hash     # optional (seq, hash) pin
         self.download_dir = TmpDir("catchup")
         self._phase = self.GET_HAS
+        self._phase_t0 = 0.0    # tracer clock; 0.0: tracing was off
         self._child: Optional[BasicWork] = None
         self._children: list = []
         self.remote_has: Optional[HistoryArchiveState] = None
@@ -95,8 +101,17 @@ class CatchupWork(BasicWork):
             return self._advance()
         return self._enter_phase()
 
+    def _trace_phase_end(self) -> None:
+        tracer = app_tracer(self.app)
+        name = self.PHASE_NAMES.get(self._phase)
+        if self._phase_t0 and name is not None and tracer is not None:
+            tracer.record("catchup.phase.%s" % name, "catchup",
+                          self._phase_t0, tracer.now() - self._phase_t0)
+        self._phase_t0 = 0.0
+
     def _advance(self) -> State:
         """Called when the current phase's children all succeeded."""
+        self._trace_phase_end()
         if self._phase == self.GET_HAS:
             self.remote_has = self._get_has.has
             cfg = self.config.resolve(self.remote_has.current_ledger)
@@ -128,6 +143,9 @@ class CatchupWork(BasicWork):
         ph = self._phase
         if ph == self.DONE:
             return self._finish_catchup()
+        tracer = app_tracer(self.app)
+        if tracer is not None:
+            self._phase_t0 = tracer.now()
         if ph == self.GET_HAS:
             self._get_has = GetHistoryArchiveStateWork(
                 self.app, self.archive, self.download_dir.path)
@@ -206,5 +224,6 @@ class CatchupWork(BasicWork):
         return SUCCESS
 
     def _finish(self, st: State) -> None:
+        self._trace_phase_end()      # a phase cut short by failure/abort
         self.download_dir.remove()   # no temp-dir leak across attempts
         super()._finish(st)

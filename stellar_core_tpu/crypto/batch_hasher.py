@@ -631,7 +631,7 @@ class TpuBatchHasher(BatchHasher):
             sp.set_tag("batches", batches)
             sp.set_tag("pad_blocks", pad_blocks)
             sp.set_tag("oversize", len(over_idx))
-            if staged_chunks:
+            if staged_chunks and sp.live:
                 sp.set_tag("staging_overlap_pct", round(
                     100.0 * overlap_s / staged_s, 1) if staged_s > 0
                     else 100.0)

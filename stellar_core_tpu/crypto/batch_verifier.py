@@ -33,10 +33,11 @@ Clock/threading audit (ISSUE 5 satellite — the 9 touch points):
    constructions. Cooldown/reprobe advance deterministically under a
    virtual clock.
 2-4. ThreadedBatchVerifier enqueue/dispatch/complete stamps — all three
-   read the injected app clock, so the queue-wait span tags and the
+   read the injected app clock, so the queue-wait gauges and the
    crypto.verify.latency timer are virtual-clock-deterministic in chaos
    soaks (module-level `time` is gone from this file; the D1 static
-   rule keeps it out).
+   rule keeps it out). The crypto.queue_wait.<class> spans are stamped
+   on the tracer's own clock, and only while tracing is on.
 5. ThreadedBatchVerifier._lock — TrackedLock, watched by the lock-order
    checker (util/threads.py).
 6. ThreadedBatchVerifier worker thread — dispatch off-main; futures
@@ -79,7 +80,7 @@ from ..util.log import get_logger
 from ..util.metrics import MetricsRegistry
 from ..util.threads import TrackedLock, spawn_worker
 from ..util.timer import real_monotonic
-from ..util.tracing import tracer_instant
+from ..util.tracing import enabled_tracer, tracer_instant, tracer_span
 from ..xdr import PublicKey
 from . import keys as _keys
 
@@ -552,10 +553,13 @@ class BatchSigVerifier:
     stats = None
 
     def _span(self, name: str, **tags):
-        from ..util.tracing import tracer_span
         return tracer_span(self.tracer, name, cat="crypto", **tags)
 
-    def enqueue(self, key: PublicKey, sig: bytes, msg: bytes) -> VerifyFuture:
+    def enqueue(self, key: PublicKey, sig: bytes, msg: bytes,
+                cls: str = "tx") -> VerifyFuture:
+        """`cls` names the caller's verify class ("scp" envelopes, "tx"
+        signatures): the async backend's queue wait is traced per
+        class."""
         raise NotImplementedError
 
     def flush(self) -> None:
@@ -572,24 +576,27 @@ class BatchSigVerifier:
         native call (prep.c sct_cache_keys) when available."""
         with self._span("crypto.prewarm", backend=self.name,
                         n=len(triples)) as sp:
-            cks = None
-            if len(triples) >= 256:   # below this the fixed numpy/ctypes
-                # marshalling cost exceeds hashlib's per-triple overhead
-                # (the native apply engine calls here once per tx, ~20-ish
-                # triples; checkpoint drains come in by the thousand)
-                from ..native import cache_keys_native
-                cks = cache_keys_native(triples)
-            if cks is None:
-                cks = [_keys._cache_key(k, s, m) for (k, s, m) in triples]
             out: List[Optional[bool]] = [None] * len(triples)
             todo: List[Tuple[int, Triple, bytes]] = []  # (idx, triple, key)
-            with _keys._cache_lock:
-                for i, (t, ck) in enumerate(zip(triples, cks)):
-                    hit = _keys._verify_cache.maybe_get(ck)
-                    if hit is not None:
-                        out[i] = hit
-                    else:
-                        todo.append((i, t, ck))
+            with self._span("crypto.cache_probe", n=len(triples)):
+                cks = None
+                if len(triples) >= 256:   # below this the fixed numpy/
+                    # ctypes marshalling cost exceeds hashlib's per-triple
+                    # overhead (the native apply engine calls here once
+                    # per tx, ~20-ish triples; checkpoint drains come in
+                    # by the thousand)
+                    from ..native import cache_keys_native
+                    cks = cache_keys_native(triples)
+                if cks is None:
+                    cks = [_keys._cache_key(k, s, m)
+                           for (k, s, m) in triples]
+                with _keys._cache_lock:
+                    for i, (t, ck) in enumerate(zip(triples, cks)):
+                        hit = _keys._verify_cache.maybe_get(ck)
+                        if hit is not None:
+                            out[i] = hit
+                        else:
+                            todo.append((i, t, ck))
             sp.set_tag("cache_hits", len(triples) - len(todo))
             if todo:
                 results = self.verify_many([t for (_i, t, _ck) in todo])
@@ -662,7 +669,8 @@ class CpuSigVerifier(BatchSigVerifier):
 
     name = "cpu"
 
-    def enqueue(self, key: PublicKey, sig: bytes, msg: bytes) -> VerifyFuture:
+    def enqueue(self, key: PublicKey, sig: bytes, msg: bytes,
+                cls: str = "tx") -> VerifyFuture:
         f = VerifyFuture()
         f._complete(_keys.PubKeyUtils.verify_sig(key, sig, msg))
         return f
@@ -848,6 +856,14 @@ class TpuSigVerifier(BatchSigVerifier):
                 "pre_ok": prep["pre_ok"], "n": len(chunk), "b": b,
                 "fn": fn, "idxs": idxs}
 
+    def _stage_inline(self, chunk: Sequence[Triple]) -> dict:
+        """Route and stage on the dispatching thread (chunk 0, a re-stage
+        after a stall): `crypto.stage`, on the drain's critical path. The
+        staging worker's span is `crypto.stage_ahead` (_StagingJob): two
+        names because a reader of spans sees names, not threads."""
+        with self._span("crypto.stage", n=len(chunk)):
+            return self._stage_chunk(chunk, self._route(len(chunk)))
+
     def _device_args(self, padded: dict, idxs: tuple) -> tuple:
         """Explicit host→device placement: sharded over the mesh for a
         fleet dispatch, committed to the default device otherwise — the
@@ -975,7 +991,8 @@ class TpuSigVerifier(BatchSigVerifier):
             if st is not None:
                 st.warmup_failed(repr(e))
 
-    def enqueue(self, key: PublicKey, sig: bytes, msg: bytes) -> VerifyFuture:
+    def enqueue(self, key: PublicKey, sig: bytes, msg: bytes,
+                cls: str = "tx") -> VerifyFuture:
         return self._batch_enqueue(key, sig, msg)
 
     def pending(self) -> int:
@@ -1011,67 +1028,78 @@ class TpuSigVerifier(BatchSigVerifier):
             pad_waste = 0
             staged_s = overlap_s = 0.0
             staged_chunks = 0
-            staged = self._stage_chunk(chunks[0],
-                                       self._route(len(chunks[0]))) \
-                if chunks else None
+            staged = self._stage_inline(chunks[0]) if chunks else None
             for k in range(len(chunks)):
                 # double buffer: chunk K+1 packs + device_puts on the
                 # staging worker while the device executes chunk K
-                job = _StagingJob(self, chunks[k + 1]) \
-                    if k + 1 < len(chunks) else None
+                job = None
+                if k + 1 < len(chunks):
+                    # Thread.start() returns once the worker runs, and the
+                    # worker may keep the interpreter for a switch interval
+                    with self._span("crypto.stage_spawn"):
+                        job = _StagingJob(self, chunks[k + 1], cause=sp.sid)
                 n, b, idxs = staged["n"], staged["b"], staged["idxs"]
                 if st is not None:
                     for di in idxs:
                         st.set_device_inflight(di, True)
-                try:
-                    with self._span("crypto.dispatch", backend=self.name,
-                                    n=n, bucket=b, pad=b - n,
-                                    devices=len(idxs)):
-                        ok_dev = staged["fn"](*staged["args"])  # async
+                with self._span("crypto.dispatch", backend=self.name,
+                                n=n, bucket=b, pad=b - n,
+                                devices=len(idxs)):
+                    try:
+                        with self._span("crypto.launch"):
+                            ok_dev = staged["fn"](*staged["args"])  # async
                         wait_t0 = real_monotonic()
-                        ok = np.asarray(ok_dev)   # blocks on the fleet
+                        with self._span("crypto.device_wait"):
+                            ok = np.asarray(ok_dev)  # blocks on the fleet
                         wait_t1 = real_monotonic()
-                except Exception:
-                    # a raising fleet dispatch counts against every
-                    # participating device's breaker (attribution to ONE
-                    # chip needs the fault-injection path); the batch
-                    # itself is completed by the resilient layer above
-                    health = self._fleet_health
-                    if health is not None:
-                        for di in idxs:
-                            health.record_failure(di)
-                    raise
-                finally:
-                    if st is not None:
-                        for di in idxs:
-                            st.set_device_inflight(di, False)
-                # every participant's breaker sees the success — single-
-                # device dispatches included, so transient failures
-                # spread over time never read as consecutive and a
-                # half-open device can recover via small drains too
-                health = self._fleet_health
-                if health is not None:
-                    for di in idxs:
-                        health.record_success(di)
-                out.extend((ok[:n] & staged["pre_ok"]).tolist())
-                self.batches_dispatched += 1
-                self.sigs_verified += n
-                batches += 1
-                pad_waste += b - n
-                if st is not None:
-                    # keyed by the LADDER shape, not the mesh-rounded
-                    # padded size: a degraded 3-device fleet rounds 8192
-                    # to 8193, and an off-ladder key would both escape
-                    # warmup_plan's pad-waste rule and mint unbounded
-                    # verifier.bucket.<b>.* metric families
-                    st.record_bucket_dispatch(self._bucket(n), n, b - n)
-                    lanes = b // len(idxs)
-                    for j, di in enumerate(idxs):
-                        real = min(max(n - j * lanes, 0), lanes)
-                        st.record_device_dispatch(di, real, lanes - real)
+                    except Exception:
+                        # a raising fleet dispatch counts against every
+                        # participating device's breaker (attribution to
+                        # ONE chip needs the fault-injection path); the
+                        # batch itself is completed by the resilient
+                        # layer above
+                        health = self._fleet_health
+                        if health is not None:
+                            for di in idxs:
+                                health.record_failure(di)
+                        raise
+                    finally:
+                        if st is not None:
+                            for di in idxs:
+                                st.set_device_inflight(di, False)
+                    with self._span("crypto.unpack"):
+                        # every participant's breaker sees the success —
+                        # single-device dispatches included, so transient
+                        # failures spread over time never read as
+                        # consecutive and a half-open device can recover
+                        # via small drains too
+                        health = self._fleet_health
+                        if health is not None:
+                            for di in idxs:
+                                health.record_success(di)
+                        out.extend((ok[:n] & staged["pre_ok"]).tolist())
+                        self.batches_dispatched += 1
+                        self.sigs_verified += n
+                        batches += 1
+                        pad_waste += b - n
+                        if st is not None:
+                            # keyed by the LADDER shape, not the mesh-
+                            # rounded padded size: a degraded 3-device
+                            # fleet rounds 8192 to 8193, and an off-ladder
+                            # key would both escape warmup_plan's
+                            # pad-waste rule and mint unbounded
+                            # verifier.bucket.<b>.* metric families
+                            st.record_bucket_dispatch(self._bucket(n), n,
+                                                      b - n)
+                            lanes = b // len(idxs)
+                            for j, di in enumerate(idxs):
+                                real = min(max(n - j * lanes, 0), lanes)
+                                st.record_device_dispatch(di, real,
+                                                          lanes - real)
                 if job is not None:
-                    staged, s_s, o_s, stalled = job.result(wait_t0,
-                                                           wait_t1)
+                    with self._span("crypto.stage_wait"):
+                        staged, s_s, o_s, stalled = job.result(wait_t0,
+                                                               wait_t1)
                     if stalled:
                         # staging stalled: re-stage synchronously so the
                         # drain still completes (the device idles for
@@ -1081,22 +1109,22 @@ class TpuSigVerifier(BatchSigVerifier):
                         # chunk must not report near-100% overlap.
                         if st is not None:
                             st.record_staging_stall()
-                        staged = self._stage_chunk(
-                            chunks[k + 1], self._route(len(chunks[k + 1])))
+                        staged = self._stage_inline(chunks[k + 1])
                     else:
                         staged_s += s_s
                         overlap_s += o_s
                         staged_chunks += 1
-            sp.set_tag("batches", batches)
-            sp.set_tag("pad_waste", pad_waste)
             total = len(triples)
-            sp.set_tag("occupancy_pct", round(
-                100.0 * total / (total + pad_waste), 1)
-                if total + pad_waste else 100.0)
-            if staged_chunks:
-                sp.set_tag("staging_overlap_pct", round(
-                    100.0 * overlap_s / staged_s, 1) if staged_s > 0
-                    else 100.0)
+            if sp.live:
+                sp.set_tag("batches", batches)
+                sp.set_tag("pad_waste", pad_waste)
+                sp.set_tag("occupancy_pct", round(
+                    100.0 * total / (total + pad_waste), 1)
+                    if total + pad_waste else 100.0)
+                if staged_chunks:
+                    sp.set_tag("staging_overlap_pct", round(
+                        100.0 * overlap_s / staged_s, 1) if staged_s > 0
+                        else 100.0)
             if st is not None:
                 if staged_chunks:
                     st.record_staging(staged_s, overlap_s, staged_chunks)
@@ -1118,12 +1146,14 @@ class _StagingJob:
     point) is reported as `stalled` — the caller re-stages synchronously
     so the drain always completes."""
 
-    __slots__ = ("v", "chunk", "staged", "error", "t0", "t1", "thread")
+    __slots__ = ("v", "chunk", "cause", "staged", "error", "t0", "t1",
+                 "thread")
 
     def __init__(self, verifier: "TpuSigVerifier",
-                 chunk: Sequence[Triple]) -> None:
+                 chunk: Sequence[Triple], cause: int = 0) -> None:
         self.v = verifier
         self.chunk = chunk
+        self.cause = cause      # the drain's crypto.verify_many span
         self.staged = None
         self.error: Optional[Exception] = None
         self.t0 = self.t1 = 0.0
@@ -1134,8 +1164,10 @@ class _StagingJob:
         try:
             if self.v.faults is not None:
                 self.v.faults.fire_point("verify.staging-stall")
-            self.staged = self.v._stage_chunk(
-                self.chunk, self.v._route(len(self.chunk)))
+            with self.v._span("crypto.stage_ahead", cause=self.cause,
+                              n=len(self.chunk)):
+                self.staged = self.v._stage_chunk(
+                    self.chunk, self.v._route(len(self.chunk)))
         except Exception as e:
             self.error = e
         self.t1 = real_monotonic()
@@ -1344,7 +1376,6 @@ class ResilientBatchVerifier(BatchSigVerifier):
             self.metrics.new_meter("crypto.breaker.%s" % event).mark()
             self.metrics.new_counter("crypto.breaker.state").set_count(
                 self.breaker.state_code())
-        from ..util.tracing import tracer_instant
         tracer_instant(self.tracer, "crypto.breaker.%s" % event,
                        cat="crypto", primary=self.primary.name,
                        failures=self.breaker.consecutive_failures)
@@ -1434,7 +1465,8 @@ class ResilientBatchVerifier(BatchSigVerifier):
                         n=len(triples), breaker=self.breaker.state):
             return self.fallback.verify_many(triples)
 
-    def enqueue(self, key: PublicKey, sig: bytes, msg: bytes) -> VerifyFuture:
+    def enqueue(self, key: PublicKey, sig: bytes, msg: bytes,
+                cls: str = "tx") -> VerifyFuture:
         return self._batch_enqueue(key, sig, msg)
 
     def pending(self) -> int:
@@ -1460,12 +1492,15 @@ class ThreadedBatchVerifier(BatchSigVerifier):
         self._clock = clock
         self._metrics = metrics
         self._lock = TrackedLock("crypto.threaded-pending")
-        # (triple, future, enqueue app-clock stamp): the timestamp feeds
-        # the crypto.verify.latency enqueue-to-complete timer (the
-        # p50/p99 the live SCP path actually feels); the app clock, not
-        # wall time, so chaos soaks under a virtual clock stay
-        # deterministic
-        self._pending: List[Tuple[Triple, VerifyFuture, float]] = []
+        # (triple, future, enqueue app-clock stamp, class, tracer-clock
+        # stamp): the app-clock stamp feeds the crypto.verify.latency
+        # enqueue-to-complete timer (the p50/p99 the live SCP path
+        # actually feels); the app clock, not wall time, so chaos soaks
+        # under a virtual clock stay deterministic. The tracer-clock
+        # stamp (0.0 while tracing is off) is the start of the batch's
+        # crypto.queue_wait.<class> span.
+        self._pending: List[Tuple[Triple, VerifyFuture, float, str,
+                                  float]] = []
         self._inflight = False
 
     @property
@@ -1495,7 +1530,8 @@ class ThreadedBatchVerifier(BatchSigVerifier):
     def fleet_health(self):
         return getattr(self._inner, "fleet_health", None)
 
-    def enqueue(self, key: PublicKey, sig: bytes, msg: bytes) -> VerifyFuture:
+    def enqueue(self, key: PublicKey, sig: bytes, msg: bytes,
+                cls: str = "tx") -> VerifyFuture:
         ck = _keys._cache_key(key.key_bytes, sig, msg)
         with _keys._cache_lock:
             hit = _keys._verify_cache.maybe_get(ck)
@@ -1503,9 +1539,11 @@ class ThreadedBatchVerifier(BatchSigVerifier):
         if hit is not None:
             f._complete(hit)
             return f
+        tr = enabled_tracer(self.tracer)
+        t_trace = tr.now() if tr is not None else 0.0
         with self._lock:
-            self._pending.append(
-                ((key.key_bytes, sig, msg), f, self._clock.now()))
+            self._pending.append(((key.key_bytes, sig, msg), f,
+                                  self._clock.now(), cls, t_trace))
             depth = len(self._pending)
         if self.stats is not None:
             self.stats.set_queue_depth(depth)
@@ -1525,21 +1563,33 @@ class ThreadedBatchVerifier(BatchSigVerifier):
         if st is not None:
             st.set_queue_depth(0)
             st.set_inflight(True)
+        tr = enabled_tracer(self.tracer)
+        cause = tr.current_sid() if tr is not None else 0
 
         def work() -> None:
-            triples = [t for (t, _f, _t0) in batch]
+            triples = [t for (t, _f, _ta, _c, _tt) in batch]
             # queue-wait: enqueue → dispatch start, per batch; dispatch
             # time is the span's own duration (inner verify_many nests)
-            t_disp = self._clock.now()
-            waits = [t_disp - t0 for (_t, _f, t0) in batch]
             if st is not None:
+                t_disp = self._clock.now()
+                waits = [t_disp - ta for (_t, _f, ta, _c, _tt) in batch]
                 st.record_queue_wait(sum(waits) / len(waits), max(waits))
-            with self._span("crypto.batch_dispatch",
-                            backend="threaded:%s" % self._inner.name,
-                            n=len(batch),
-                            queue_wait_max_ms=round(max(waits) * 1e3, 3),
-                            queue_wait_mean_ms=round(
-                                sum(waits) / len(waits) * 1e3, 3)):
+            if tr is not None:
+                # one span per class in the batch, from its oldest
+                # enqueue (stamped while tracing was on) to here
+                t_disp = tr.now()
+                oldest: dict = {}
+                for (_t, _f, _ta, c, tt) in batch:
+                    if tt and tt < oldest.get(c, t_disp):
+                        oldest[c] = tt
+                for c, t0 in oldest.items():
+                    tr.record("crypto.queue_wait.%s" % c, "crypto", t0,
+                              t_disp - t0, cause=cause, n=len(batch))
+            with self._span("crypto.batch_dispatch", cause=cause,
+                            n=len(batch)) as bsp:
+                if bsp.live:
+                    bsp.set_tag("backend",
+                                "threaded:%s" % self._inner.name)
                 try:
                     results = self._inner.verify_many(triples)
                 except Exception as e:
@@ -1554,7 +1604,7 @@ class ThreadedBatchVerifier(BatchSigVerifier):
                 done = self._clock.now()
                 lat = (self._metrics.new_timer("crypto.verify.latency")
                        if self._metrics is not None else None)
-                for ((k, s, m), f, t0), ok in zip(batch, results):
+                for ((k, s, m), f, t0, _c, _tt), ok in zip(batch, results):
                     with _keys._cache_lock:
                         _keys._verify_cache.put(_keys._cache_key(k, s, m), ok)
                     if lat is not None:

@@ -24,6 +24,7 @@ from ..scp.scp import SCP
 from ..util.log import get_logger
 from ..util.threads import main_thread_only
 from ..util.timer import VirtualTimer
+from ..util.tracing import app_span, app_tracer
 from ..xdr import (
     EnvelopeType, LedgerCloseValueSignature, LedgerUpgrade, SCPEnvelope,
     SCPQuorumSet, SCPStatementType, StellarValue, StellarValueExt, Uint32,
@@ -292,13 +293,13 @@ class Herder:
         from .tx_lifecycle import TxLifecycle
         self.tx_lifecycle = TxLifecycle(
             metrics=getattr(app, "metrics", None),
-            tracer=getattr(app, "tracer", None),
             now_fn=app.clock.now)
         self.tx_queue = TransactionQueue(
             app.ledger_manager, cfg.TRANSACTION_QUEUE_PENDING_DEPTH,
             cfg.TRANSACTION_QUEUE_BAN_DEPTH, cfg.POOL_LEDGER_MULTIPLIER,
             self.verifier, metrics=getattr(app, "metrics", None),
-            lifecycle=self.tx_lifecycle)
+            lifecycle=self.tx_lifecycle,
+            tracer=getattr(app, "tracer", None))
         # ingress admission tier (ISSUE 18): per-source rate classes +
         # bounded intake in FRONT of the queue, so overload sheds before
         # paying signature validation (docs/robustness.md#ingress--overload)
@@ -347,6 +348,8 @@ class Herder:
         self.quorum_tracker = QuorumTracker(
             cfg.node_id(), lambda: self.app.config.QUORUM_SET)
         self._nominate_started: dict = {}
+        # slot -> tracer-clock start of its scp.slot span (tracing on only)
+        self._slot_trace_t0: Dict[int, float] = {}
         self.last_quorum_intersection: Optional[dict] = None
         # in-flight background intersection check (reference
         # QuorumMapIntersectionState): the main loop owns these fields;
@@ -467,7 +470,7 @@ class Herder:
             return   # already counted: no repeat verify work
         fut = self.verifier.enqueue(
             st.nodeID, envelope.signature,
-            self.scp_driver._envelope_sign_bytes(st))
+            self.scp_driver._envelope_sign_bytes(st), cls="scp")
 
         def done(ok: bool) -> None:
             if not ok:
@@ -630,12 +633,28 @@ class Herder:
     def _metrics(self):
         return getattr(self.app, "metrics", None)
 
+    def _trace_slot_start(self, tracer, slot: int) -> None:
+        """scp.slot starts at this node's trigger or at the first
+        envelope it sees for the slot, whichever comes first."""
+        t0s = self._slot_trace_t0
+        if slot not in t0s:
+            t0s[slot] = tracer.now()
+            # envelopes are accepted for cur-1 .. cur+bracket only
+            while len(t0s) > self.LEDGER_VALIDITY_BRACKET + 2:
+                del t0s[min(t0s)]
+
     def recv_transaction(self, frame) -> int:
         """HOT CALLER #2 via TransactionQueue.try_add → checkValid.
         The ingress tier (ISSUE 18) decides first: a throttled or shed
         tx returns TRY_AGAIN_LATER *before* any signature validation is
         paid, with `last_retry_after` carrying the hint `cmd_tx`
         surfaces to the submitter."""
+        with app_span(self.app, "herder.admit", cat="herder") as sp:
+            status = self._admit(frame)
+            sp.set_tag("status", status)
+            return status
+
+    def _admit(self, frame) -> int:
         m = self._metrics()
         if m is not None:
             m.new_meter("herder.tx.received").mark()
@@ -717,6 +736,9 @@ class Herder:
             log.debug("dropping envelope from %s (not in quorum)",
                       st.nodeID.value.hex()[:8])
             return SCP.EnvelopeState.INVALID
+        tracer = app_tracer(self.app)
+        if tracer is not None and slot >= cur:
+            self._trace_slot_start(tracer, slot)
         eh = sha256(envelope.to_xdr())
         if not self.pending.begin_verify(envelope, eh):
             # duplicate (processed / discarded / already verifying)
@@ -730,7 +752,7 @@ class Herder:
         t_recv = self.app.clock.now()
         fut = self.verifier.enqueue(
             st.nodeID, envelope.signature,
-            self.scp_driver._envelope_sign_bytes(st))
+            self.scp_driver._envelope_sign_bytes(st), cls="scp")
 
         def done(ok: bool) -> None:
             if not ok:
@@ -916,7 +938,6 @@ class Herder:
     # -- nomination ----------------------------------------------------------
     @main_thread_only
     def trigger_next_ledger(self, ledger_seq_to_trigger: int) -> None:
-        from ..util.tracing import app_span
         lm = self.app.ledger_manager
         cfg = self.app.config
         lcl = lm.lcl_header
@@ -925,6 +946,9 @@ class Herder:
             log.debug("stale trigger for %d (slot %d)",
                       ledger_seq_to_trigger, slot)
             return
+        tracer = app_tracer(self.app)
+        if tracer is not None:
+            self._trace_slot_start(tracer, slot)
         with app_span(self.app, "herder.trigger", cat="scp",
                       slot=slot) as tsp:
             if self.ingress is not None:
@@ -1008,13 +1032,26 @@ class Herder:
                 # reference scp.timing.externalized: nomination-start →
                 # externalize latency per slot
                 m.new_timer("scp.timing.externalized").update(lat)
-        tracer = getattr(self.app, "tracer", None)
-        if tracer is not None and tracer.enabled:
-            # round timing rides as a tag: the latency is measured on the
-            # app clock, not the tracer clock, so it can't be a span
+        tracer = app_tracer(self.app)
+        if tracer is not None:
+            # the app-clock latency rides as a tag; the scp.slot span
+            # below is the same interval on the tracer's clock
             tracer.instant("scp.externalize", cat="scp", slot=slot_index,
                            **({} if lat is None else
                               {"nominate_to_externalize_s": round(lat, 6)}))
+            slot_t0 = self._slot_trace_t0.get(slot_index)
+            if slot_t0 is not None:
+                rep = self.scp_stats.slot_report(slot_index) or {}
+                tracer.record(
+                    "scp.slot", "scp", slot_t0, tracer.now() - slot_t0,
+                    slot=slot_index,
+                    timeouts=sum(t["fired"] for t in
+                                 rep.get("timers", {}).values()),
+                    ballot_counter=rep.get("rounds", {}).get("ballot", 0))
+        if self._slot_trace_t0:
+            self._slot_trace_t0 = {s: t for s, t in
+                                   self._slot_trace_t0.items()
+                                   if s > slot_index}
         tl = getattr(self.app, "slot_timeline", None)
         if tl is not None:
             tl.record(slot_index, "externalize", dedupe=True,
@@ -1099,9 +1136,22 @@ class Herder:
         # consensus cockpit: attribute every fire to (timer, round) —
         # arming over a pending schedule counts the implicit cancel
         ss.timer_armed(slot_index, timer_id)
+        tracer = app_tracer(self.app)
+        t_armed = tracer.now() if tracer is not None else 0.0
 
         def fired() -> None:
-            ss.timer_fired(slot_index, timer_id)
+            rnd = ss.timer_fired(slot_index, timer_id)
+            tr = app_tracer(self.app)
+            if tr is not None:
+                from ..scp.scp_stats import TIMER_NAMES
+                name = TIMER_NAMES.get(timer_id)
+                if name is not None:    # the timers ScpStats counts
+                    tags = {"slot": slot_index, "round": rnd,
+                            "timer": name}
+                    tr.instant("scp.timer.fired", cat="scp", **tags)
+                    if t_armed:
+                        tr.record("scp.timer.wait", "scp", t_armed,
+                                  tr.now() - t_armed, **tags)
             cb()
 
         t.expires_from_now(timeout)

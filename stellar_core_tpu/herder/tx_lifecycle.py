@@ -52,7 +52,7 @@ class TxLifecycle:
 
     MAX_TRACKED = 8192
 
-    def __init__(self, metrics=None, tracer=None, now_fn=None) -> None:
+    def __init__(self, metrics=None, now_fn=None) -> None:
         self._now = now_fn or real_monotonic
         # a private registry when none is injected keeps direct
         # constructions (tests, harnesses) app-registry-free while
@@ -60,7 +60,6 @@ class TxLifecycle:
         # metric-catalog scanner keys on
         self.metrics = metrics if metrics is not None \
             else MetricsRegistry(now_fn=self._now)
-        self.tracer = tracer
         self._lock = TrackedLock("herder.tx-lifecycle")
         m = self.metrics
         self._h_stage = {
@@ -189,9 +188,6 @@ class TxLifecycle:
             self.last_slot = {"slot": slot, **slot_funnel}
         if finalized:
             self._outcome_meter("applied").mark(finalized)
-        if self.tracer is not None and self.tracer.enabled and finalized:
-            self.tracer.instant("herder.tx.applied", cat="herder",
-                                slot=slot, txs=finalized)
         return finalized
 
     # -- exports -------------------------------------------------------------

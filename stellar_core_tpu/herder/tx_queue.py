@@ -16,6 +16,7 @@ from typing import Dict, List, Optional, Tuple
 from ..ledger.ledgertxn import LedgerTxn
 from ..util.log import get_logger
 from ..util.threads import main_thread_only
+from ..util.tracing import tracer_span
 from .txset import TxSetFrame
 
 log = get_logger("Herder")
@@ -34,9 +35,11 @@ class TransactionQueue:
 
     def __init__(self, ledger_access, pending_depth: int = 4,
                  ban_depth: int = 10, pool_ledger_multiplier: int = 2,
-                 verifier=None, metrics=None, lifecycle=None) -> None:
+                 verifier=None, metrics=None, lifecycle=None,
+                 tracer=None) -> None:
         """ledger_access: object exposing .ltx_root() and .header()."""
         self._ledger = ledger_access
+        self.tracer = tracer
         self.pending_depth = pending_depth
         self.ban_depth = ban_depth
         self.pool_multiplier = pool_ledger_multiplier
@@ -86,6 +89,10 @@ class TransactionQueue:
     # -- add ----------------------------------------------------------------
     @main_thread_only
     def try_add(self, frame) -> int:
+        with tracer_span(self.tracer, "txqueue.try_add", cat="herder"):
+            return self._try_add(frame)
+
+    def _try_add(self, frame) -> int:
         h = frame.full_hash()
         if h in self._known_hashes:
             return TxQueueResult.ADD_STATUS_DUPLICATE
@@ -132,7 +139,9 @@ class TransactionQueue:
                 # a synchronous admission call.
                 self.verifier.prewarm_many(frame.candidate_sig_triples(ltx))
             seq_base = frame.seq_num - 1
-            if not frame.check_valid(ltx, seq_base, self.verifier):
+            with tracer_span(self.tracer, "tx.check_valid", cat="herder"):
+                valid = frame.check_valid(ltx, seq_base, self.verifier)
+            if not valid:
                 return TxQueueResult.ADD_STATUS_ERROR
             # the fee source must cover this full fee BID on top of every
             # bid it already sponsors in the pool (reference

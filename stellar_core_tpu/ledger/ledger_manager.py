@@ -539,7 +539,7 @@ class LedgerManager:
                           seq=lcd.ledger_seq) as msp:
                 cp = sce.on_close(bl.bucket_list, lcd.ledger_seq,
                                   self.lcl_hash)
-                if sce.root is not None:
+                if sce.root is not None and msp.live:
                     msp.set_tag("root", sce.root.hex()[:16])
                 if cp is not None:
                     msp.set_tag("checkpoint_seq", cp.ledger_seq)
@@ -565,7 +565,7 @@ class LedgerManager:
         # dumps regardless.
         close_blob = stats.end_close(apply_path, apply_wall_s,
                                      write_set=len(delta))
-        if close_blob is not None:
+        if close_blob is not None and apply_sp.live:
             apply_sp.set_tag("op_mix", {
                 n: d["count"] for n, d in close_blob["ops"].items()})
             apply_sp.set_tag("reads", close_blob["reads"])

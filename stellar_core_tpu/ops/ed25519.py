@@ -343,29 +343,33 @@ def verify_kernel(ay: jnp.ndarray, a_sign: jnp.ndarray,
     k_nibs = jnp.moveaxis(k_nibs, -1, 0)
     batch = ay.shape[1:]
 
-    ax, a_ok = fe_decompress(ay, a_sign)
-    rx, r_ok = fe_decompress(ry, r_sign)
+    # the named scopes put a stage name into every op's metadata (the
+    # device trace's op_name), and into nothing else: numerics untouched
+    with jax.named_scope("ed25519.decompress"):
+        ax, a_ok = fe_decompress(ay, a_sign)
+        rx, r_ok = fe_decompress(ry, r_sign)
 
-    # A in extended coords, negated: Q = [S]B + [k](−A)
-    neg_ax = fe_neg(ax)
-    neg_at = fe_neg(fe_mul(ax, ay))
-    a_pt = (neg_ax, ay, fe_one(batch), neg_at)
+    with jax.named_scope("ed25519.table"):
+        # A in extended coords, negated: Q = [S]B + [k](−A)
+        neg_ax = fe_neg(ax)
+        neg_at = fe_neg(fe_mul(ax, ay))
+        a_pt = (neg_ax, ay, fe_one(batch), neg_at)
 
-    # per-item table of v·(−A), v = 0..8 (signed digits select a
-    # magnitude and negate), extended coords; entry T is pre-multiplied
-    # by 2d so the ladder add does c = T1·(2d·T2) in ONE multiply
-    # (Niels-style T folding)
-    entries = [pt_identity(batch), a_pt]
-    for v in range(2, 9):
-        if v % 2 == 0:
-            entries.append(pt_dbl(entries[v // 2]))
-        else:
-            entries.append(pt_add(entries[v - 1], a_pt))
-    d2 = _bcast(_D2_LIMBS, ax)
-    a_table = tuple(
-        jnp.stack([e[c] if c < 3 else fe_mul(e[3], d2) for e in entries],
-                  axis=0)
-        for c in range(4))                       # 4 × (9, 20, B)
+        # per-item table of v·(−A), v = 0..8 (signed digits select a
+        # magnitude and negate), extended coords; entry T is
+        # pre-multiplied by 2d so the ladder add does c = T1·(2d·T2) in
+        # ONE multiply (Niels-style T folding)
+        entries = [pt_identity(batch), a_pt]
+        for v in range(2, 9):
+            if v % 2 == 0:
+                entries.append(pt_dbl(entries[v // 2]))
+            else:
+                entries.append(pt_add(entries[v - 1], a_pt))
+        d2 = _bcast(_D2_LIMBS, ax)
+        a_table = tuple(
+            jnp.stack([e[c] if c < 3 else fe_mul(e[3], d2)
+                       for e in entries], axis=0)
+            for c in range(4))                       # 4 × (9, 20, B)
 
     # variable-base: MSB-first over 64 signed digits of k. The window
     # add's T output is never read (the next 4 doublings ignore T; the
@@ -382,10 +386,11 @@ def verify_kernel(ay: jnp.ndarray, a_sign: jnp.ndarray,
     def vb_body(i, q):
         return vb_window(q, k_nibs[63 - i], False)
 
-    q = jax.lax.fori_loop(0, 63, vb_body, pt_identity(batch))
-    # final window peeled: its add DOES produce T, which the fixed-base
-    # Niels chain below consumes
-    q = vb_window(q, k_nibs[0], True)
+    with jax.named_scope("ed25519.varbase"):
+        q = jax.lax.fori_loop(0, 63, vb_body, pt_identity(batch))
+        # final window peeled: its add DOES produce T, which the
+        # fixed-base Niels chain below consumes
+        q = vb_window(q, k_nibs[0], True)
 
     # fixed-base: Σ_j table[j][s_dig_j], 64 Niels additions, no doublings
     ftab = jnp.asarray(fixed_table())  # (64, 9, 3, 20) static
@@ -406,12 +411,14 @@ def verify_kernel(ay: jnp.ndarray, a_sign: jnp.ndarray,
         xy2d = jnp.where(fneg, fe_neg(sel[2]), sel[2])
         return pt_add_niels(acc, (ypx, ymx, xy2d))
 
-    q = jax.lax.fori_loop(0, 64, fb_body, q)
+    with jax.named_scope("ed25519.fixedbase"):
+        q = jax.lax.fori_loop(0, 64, fb_body, q)
 
-    # projective compare with affine R: X == rx·Z and Y == ry·Z
-    xq, yq, zq, _ = q
-    eq = fe_eq(xq, fe_mul(rx, zq)) & fe_eq(yq, fe_mul(ry, zq))
-    return a_ok & r_ok & eq
+    with jax.named_scope("ed25519.compare"):
+        # projective compare with affine R: X == rx·Z and Y == ry·Z
+        xq, yq, zq, _ = q
+        eq = fe_eq(xq, fe_mul(rx, zq)) & fe_eq(yq, fe_mul(ry, zq))
+        return a_ok & r_ok & eq
 
 
 # --- host-side batch preparation (numpy-vectorized) ------------------------

@@ -14,6 +14,7 @@ from typing import Callable, Dict, List, Optional
 from ..util import rnd
 from ..util.log import get_logger
 from ..util.timer import VirtualTimer
+from ..util.tracing import app_tracer
 from ..xdr import SCPEnvelope, StellarMessage
 
 log = get_logger("Overlay")
@@ -37,11 +38,16 @@ class Tracker:
         self.overlay = overlay
         self.item_hash = item_hash
         self.make_request = make_request
+        # tracer-clock stamp of the first request (0.0: tracing was off),
+        # the start of this item's overlay.fetch_wait span
+        tracer = app_tracer(overlay.app)
+        self.t_trace = tracer.now() if tracer is not None else 0.0
         self.waiting: List[SCPEnvelope] = []
         self.last_asked_peer: Optional[str] = None
         self.peers_asked: List[str] = []
         self.timer = VirtualTimer(overlay.app.clock)
         self.num_list_rebuild = 0
+        self.tries = 0      # requests sent
         self._stopped = False
         # called (with self) when the tracker abandons the fetch, so the
         # owning ItemFetcher can drop it from its registry
@@ -73,6 +79,7 @@ class Tracker:
             self.peers_asked.append(pid)
             peer = self.overlay.get_peer(pid)
             if peer is not None:
+                self.tries += 1
                 peer.send_message(self.make_request(self.item_hash))
         delay = MS_TO_WAIT_FOR_FETCH_REPLY * (1 + min(
             self.num_list_rebuild, MAX_DELAY_REBUILDS))
@@ -105,9 +112,11 @@ class ItemFetcher:
     """Hash → Tracker registry (reference ItemFetcher.h:41-96)."""
 
     def __init__(self, overlay,
-                 make_request: Callable[[bytes], StellarMessage]) -> None:
+                 make_request: Callable[[bytes], StellarMessage],
+                 kind: str = "item") -> None:
         self.overlay = overlay
         self.make_request = make_request
+        self.kind = kind    # "txset" / "qset": the fetch_wait span's tag
         self.trackers: Dict[bytes, Tracker] = {}
 
     def fetch(self, item_hash: bytes,
@@ -129,6 +138,11 @@ class ItemFetcher:
         tr = self.trackers.pop(item_hash, None)
         if tr is None:
             return
+        tracer = app_tracer(self.overlay.app)
+        if tr.t_trace and tracer is not None:
+            tracer.record("overlay.fetch_wait", "overlay", tr.t_trace,
+                          tracer.now() - tr.t_trace, kind=self.kind,
+                          tries=tr.tries)
         waiting = list(tr.waiting)
         tr.stop()
         for env in waiting:

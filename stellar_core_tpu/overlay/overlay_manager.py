@@ -67,9 +67,11 @@ class OverlayManager:
         self.pending_peers: List[Peer] = []
         self.authenticated_peers: Dict[bytes, Peer] = {}
         self.tx_set_fetcher = ItemFetcher(
-            self, lambda h: StellarMessage(MessageType.GET_TX_SET, h))
+            self, lambda h: StellarMessage(MessageType.GET_TX_SET, h),
+            kind="txset")
         self.qset_fetcher = ItemFetcher(
-            self, lambda h: StellarMessage(MessageType.GET_SCP_QUORUMSET, h))
+            self, lambda h: StellarMessage(MessageType.GET_SCP_QUORUMSET, h),
+            kind="qset")
         from .survey_manager import SurveyManager
         self.survey_manager = SurveyManager(app, self)
         from .load_manager import LoadManager

@@ -237,10 +237,11 @@ class ScpStats:
         if fire:
             self._m_cancelled[name].mark()
 
-    def timer_fired(self, slot: int, timer_id: int) -> None:
+    def timer_fired(self, slot: int, timer_id: int) -> Optional[int]:
+        """Returns the round the timer was armed for (None: unknown)."""
         name = TIMER_NAMES.get(timer_id)
         if name is None:
-            return
+            return None
         with self._lock:
             rnd = self._pending_timers.pop((slot, timer_id), None)
             rec = self._slots.get(slot)
@@ -250,6 +251,7 @@ class ScpStats:
                     rec["fires"].append({"timer": name, "round": rnd})
             self.totals["timer_fired"] += 1
         self._m_fired[name].mark()
+        return rnd
 
     # -- envelope accounting (Herder.emit_envelope, Slot.process_envelope) ---
     def envelope_sent(self, slot: int, kind: str) -> None:

@@ -101,7 +101,7 @@ class SignatureChecker:
             for i in self._by_hint.get(_hint_of(kb), ()):
                 futs[(i, kb)] = self._verifier.enqueue(
                     PublicKey.ed25519(kb), self._sigs[i].signature,
-                    self._contents_hash)
+                    self._contents_hash, cls="tx")
         if futs:
             self._verifier.flush()
 

@@ -25,12 +25,24 @@ Threading: span stacks are thread-local (worker-thread dispatches nest
 correctly); the ring buffer append is a deque op under a lock only on
 the multi-producer paths' writes — GIL-atomic deque.append keeps the
 single-threaded hot path lock-free.
+
+Across threads a span names its `cause`: the span (usually on another
+thread) that handed the work over. `parent` stays "the enclosing span on
+this thread" and is all that self-time arithmetic reads — a worker's
+span runs concurrently with its cause and must never be subtracted from
+it. `Tracer.record` writes a completed span for an interval measured
+where it happened (a queue wait, a consensus slot). While enabled, every
+`with` span is mirrored into the JAX profiler's trace as a
+`TraceAnnotation` of the same name, so program spans sit on the device
+trace's clock — only when `jax` is already loaded: cpu-backend nodes
+never import it because of tracing.
 """
 
 from __future__ import annotations
 
 import json
 import os
+import sys
 import threading
 import time
 import traceback
@@ -48,19 +60,23 @@ class Span:
     """One completed (or in-flight) traced region."""
 
     __slots__ = ("name", "cat", "t0", "dur", "tags", "tid", "sid",
-                 "parent", "_tracer")
+                 "parent", "cause", "_tracer", "_mirror")
 
     def __init__(self, tracer: "Tracer", name: str, cat: str,
-                 tags: Optional[dict]) -> None:
+                 tags: Optional[dict], cause: int = 0) -> None:
         self._tracer = tracer
         self.name = name
         self.cat = cat
         self.tags: Optional[dict] = tags
         self.tid = threading.get_ident()
         self.sid = 0
-        self.parent = 0
+        self.parent = 0    # the enclosing span on THIS thread
+        self.cause = cause  # the span, on any thread, that led to this one
         self.t0 = 0.0
         self.dur: Optional[float] = None   # None while open
+        self._mirror = None
+
+    live = True
 
     def set_tag(self, key: str, value) -> "Span":
         if self.tags is None:
@@ -69,6 +85,10 @@ class Span:
         return self
 
     def __enter__(self) -> "Span":
+        note = _annotation(self.name)
+        if note is not None:
+            note.__enter__()
+            self._mirror = note
         self._tracer._push(self)
         return self
 
@@ -76,15 +96,29 @@ class Span:
         if exc_type is not None:
             self.set_tag("error", exc_type.__name__)
         self._tracer._pop(self)
+        if self._mirror is not None:
+            self._mirror.__exit__(None, None, None)
+            self._mirror = None
         return False
 
     def to_dict(self) -> dict:
         d = {"name": self.name, "cat": self.cat, "ts": self.t0,
              "dur": self.dur, "tid": self.tid, "sid": self.sid,
              "parent": self.parent}
+        if self.cause:
+            d["cause"] = self.cause
         if self.tags:
             d["tags"] = dict(self.tags)
         return d
+
+
+def _annotation(name: str):
+    """The profiler's host-plane twin of a span, or None where `jax` is
+    not loaded (or is still being imported by another thread). Outside a
+    profiler session a TraceMe is a flag check."""
+    prof = getattr(sys.modules.get("jax"), "profiler", None)
+    cls = getattr(prof, "TraceAnnotation", None)
+    return cls(name) if cls is not None else None
 
 
 class _NoopSpan:
@@ -101,18 +135,22 @@ class _NoopSpan:
     def set_tag(self, key: str, value) -> "_NoopSpan":
         return self
 
+    sid = 0         # a disabled span causes nothing
+    live = False    # `if sp.live:` guards a tag that costs work to compute
+
 
 _NOOP = _NoopSpan()
 
 
-def tracer_span(tracer, name: str, cat: str = "core", **tags):
+def tracer_span(tracer, name: str, cat: str = "core", cause: int = 0,
+                **tags):
     """The single tracer-guard: a span against a possibly-absent,
     possibly-disabled tracer. Every instrumentation site goes through
     this (or the wrappers below) so the enable semantics live in one
     place."""
     if tracer is None or not tracer.enabled:
         return _NOOP
-    return tracer.span(name, cat, **tags)
+    return tracer.span(name, cat, cause=cause, **tags)
 
 
 def tracer_instant(tracer, name: str, cat: str = "core", **tags) -> None:
@@ -125,6 +163,18 @@ def app_span(app, name: str, cat: str = "core", **tags):
     wirings) that have no tracer at all — the instrumentation sites must
     never require one."""
     return tracer_span(getattr(app, "tracer", None), name, cat, **tags)
+
+
+def enabled_tracer(tracer):
+    """`tracer` while it is enabled, else None: the guard of a site that
+    stamps the tracer's clock or writes a completed span (`record`)."""
+    return tracer if tracer is not None and tracer.enabled else None
+
+
+def app_tracer(app):
+    """`app.tracer` while it is enabled, else None (apps without a
+    tracer included)."""
+    return enabled_tracer(getattr(app, "tracer", None))
 
 
 class Tracer:
@@ -159,12 +209,43 @@ class Tracer:
         self.dropped = 0
 
     # -- recording -----------------------------------------------------------
-    def span(self, name: str, cat: str = "core", **tags):
+    def now(self) -> float:
+        """The tracer's clock, for a `t0` handed to `record` later. Sites
+        stamp it only when enabled."""
+        return self._now()
+
+    def current_sid(self) -> int:
+        """The innermost span open on the calling thread (0: none, or
+        disabled): capture it before handing work to a thread or to
+        `post_to_main`, and pass it on as that work's `cause`."""
+        if not self.enabled:
+            return 0
+        st = self._stack()
+        return st[-1].sid if st else 0
+
+    def span(self, name: str, cat: str = "core", cause: int = 0, **tags):
         """`with tracer.span("close.apply", seq=7):` — returns a shared
-        no-op when disabled; tag values must be JSON-serializable."""
+        no-op when disabled; tag values must be JSON-serializable.
+        `cause` is the sid of the span that led to this one from another
+        thread (`current_sid()` there)."""
         if not self.enabled:
             return _NOOP
-        return Span(self, name, cat, tags or None)
+        return Span(self, name, cat, tags or None, cause)
+
+    def record(self, name: str, cat: str, t0: float, dur: float,
+               cause: int = 0, **tags) -> None:
+        """A completed span for an interval that was measured where it
+        happened (a queue wait, a slot, a timer wait); `t0` is on this
+        tracer's clock (`now()`). Its `parent` is 0 whatever is open on
+        the thread, so it never changes another span's self time. It is
+        ring-only: the profiler's trace cannot be backdated."""
+        if not self.enabled:
+            return
+        s = Span(self, name, cat, tags or None, cause)
+        s.t0 = t0
+        s.dur = max(0.0, dur)
+        s.sid = self._new_sid()
+        self._record(s)
 
     def instant(self, name: str, cat: str = "core", **tags) -> None:
         """Zero-duration marker event (Chrome 'i' phase)."""
@@ -235,8 +316,10 @@ class Tracer:
                   "ts": round(s.t0 * 1e6, 1),
                   "dur": round((s.dur or 0.0) * 1e6, 1),
                   "pid": os.getpid(), "tid": s.tid}
-            if s.tags:
-                ev["args"] = s.tags
+            if s.tags or s.cause:
+                ev["args"] = dict(s.tags or {})
+                if s.cause:
+                    ev["args"]["cause"] = s.cause
             if ev["ph"] == "i":
                 ev["s"] = "t"
                 del ev["dur"]

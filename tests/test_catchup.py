@@ -191,6 +191,37 @@ def test_catchup_minimal_buckets(publisher):
     assert AppLedgerAdapter(app_b).balance(root) > 0
 
 
+@pytest.mark.parametrize("mode,phases", [
+    ("complete", ["get_has", "download_verify", "apply_txs"]),
+    ("minimal", ["get_has", "get_apply_has", "download_verify", "buckets"]),
+])
+def test_catchup_records_one_span_per_phase(publisher, mode, phases):
+    """catchup.phase.<name>: one completed span per phase as it ends, in
+    order, end to start; a replay that applies no bucket records no
+    `buckets` phase (and a bucket catchup to the tip no `apply_txs`)."""
+    app_a, tmp_path, archive_root = publisher
+    app_b = make_app(tmp_path, 7, archive_root, writable=False)
+    app_b.tracer.enable()
+    t0 = app_b.tracer.now()
+    work = app_b.catchup_manager.start_catchup(
+        getattr(CatchupConfiguration, mode)())
+    assert run_work(app_b, work) == State.SUCCESS
+    t1 = app_b.tracer.now()
+    spans = [s for s in app_b.tracer.spans()
+             if s.name.startswith("catchup.phase.")]
+    assert [s.name for s in spans] == ["catchup.phase." + p for p in phases]
+    assert all(s.parent == 0 and s.dur >= 0.0 for s in spans)
+    for a, b in zip(spans, spans[1:]):
+        assert a.t0 + a.dur <= b.t0 + 1e-6
+    assert t0 <= spans[0].t0 and spans[-1].t0 + spans[-1].dur <= t1
+    # with tracing off nothing is stamped
+    app_c = make_app(tmp_path, 8, archive_root, writable=False)
+    work = app_c.catchup_manager.start_catchup(
+        getattr(CatchupConfiguration, mode)())
+    assert run_work(app_c, work) == State.SUCCESS
+    assert work._phase_t0 == 0.0 and app_c.tracer.spans() == []
+
+
 def make_lcd_from_db(app_src, seq):
     """Rebuild the LedgerCloseData node A externalized for `seq`."""
     from stellar_core_tpu.herder.txset import TxSetFrame

@@ -1,10 +1,14 @@
 """Tracing subsystem tests (ISSUE 2): span nesting, ring bounding,
 Chrome-trace export, phase attribution, flight-recorder triggers, the
-admin `trace` endpoint, and the disabled-overhead guard.
+admin `trace` endpoint, and the disabled-overhead guard; causes across
+threads, completed spans and the profiler mirror (ISSUE 25).
 """
 
 import json
 import os
+import subprocess
+import sys
+import threading
 import time
 
 import pytest
@@ -144,6 +148,168 @@ def test_phase_breakdown_self_time_sums_to_wall():
     assert total == pytest.approx(8.0)
     assert pb["accounted_s"] == pytest.approx(8.0)
     assert ph["verify:cpu"]["pct_of_wall"] == pytest.approx(25.0)
+
+
+# ------------------------------------------- causes, completed spans, mirror
+
+def test_cause_survives_a_worker_thread_and_is_not_self_time():
+    """A worker's span names the span that handed it the work; it runs
+    concurrently with that span and is never subtracted from it."""
+    clk = FakeClock()
+    tr = Tracer(now_fn=clk)
+    tr.enable()
+    seen = {}
+
+    def worker(cause):
+        with tr.span("stage_ahead", cat="test", cause=cause) as sp:
+            seen["parent"], seen["cause"] = sp.parent, sp.cause
+
+    with tr.span("drain") as drain:
+        assert tr.current_sid() == drain.sid
+        t = threading.Thread(target=worker, args=(tr.current_sid(),))
+        t.start()
+        t.join(10)
+        assert not t.is_alive()
+        clk.advance(1.0)
+    assert seen == {"parent": 0, "cause": drain.sid}
+    by = {s.name: s for s in tr.spans()}
+    assert by["stage_ahead"].to_dict()["cause"] == drain.sid
+    assert "cause" not in by["drain"].to_dict()
+    pb = tr.phase_breakdown()
+    assert pb["phases"]["drain"]["total_s"] == pytest.approx(1.0)
+    # no span open, or tracing off: nothing to name as a cause
+    assert tr.current_sid() == 0
+    tr.disable()
+    assert tr.current_sid() == 0 and _NOOP.sid == 0 and not _NOOP.live
+
+
+def test_cause_survives_post_to_main():
+    clock = VirtualClock(ClockMode.VIRTUAL_TIME)
+    tr = Tracer()
+    tr.enable()
+    with tr.span("flush") as flush:
+        cause = tr.current_sid()
+        clock.post_to_main(
+            lambda: tr.span("complete", cause=cause).__enter__()
+            .__exit__(None, None, None))
+    assert [s.name for s in tr.spans()] == ["flush"]
+    clock.crank(False)
+    done = tr.spans()[-1]
+    assert done.name == "complete" and done.parent == 0
+    assert done.cause == flush.sid
+
+
+def test_record_is_parentless_and_round_trips(tmp_path):
+    """record(): a completed span for an interval measured elsewhere; it
+    takes nothing from the self time of the span it is recorded under,
+    and survives to_dict, the Chrome export and a flight dump."""
+    clk = FakeClock()
+    tr = Tracer(now_fn=clk)
+    tr.enable()
+    t0 = tr.now()
+    clk.advance(0.5)
+    with tr.span("flush") as flush:
+        clk.advance(0.25)
+        tr.record("queue_wait.scp", "test", t0, tr.now() - t0,
+                  cause=flush.sid, n=3)
+    rec = next(s for s in tr.spans() if s.name == "queue_wait.scp")
+    assert rec.parent == 0 and rec.cause == flush.sid and rec.sid
+    assert rec.t0 == 0.0 and rec.dur == pytest.approx(0.75)
+    assert tr.phase_breakdown()["phases"]["flush"]["total_s"] == \
+        pytest.approx(0.25)
+    assert rec.to_dict() == {
+        "name": "queue_wait.scp", "cat": "test", "ts": 0.0, "dur": 0.75,
+        "tid": rec.tid, "sid": rec.sid, "parent": 0, "cause": flush.sid,
+        "tags": {"n": 3}}
+    ev = next(e for e in tr.to_chrome_trace()["traceEvents"]
+              if e["name"] == "queue_wait.scp")
+    assert ev["ph"] == "X" and ev["dur"] == pytest.approx(750000.0)
+    assert ev["args"] == {"n": 3, "cause": flush.sid}
+    path = FlightRecorder(tr, out_dir=str(tmp_path)).dump("test")
+    with open(path) as fh:
+        dumped = json.load(fh)["spans"]
+    assert rec.to_dict() in dumped
+    # a negative interval (clocks misread) is clamped, a disabled tracer
+    # records nothing
+    tr.record("late", "test", 5.0, -1.0)
+    assert tr.spans()[-1].dur == 0.0
+    n = len(tr.spans())
+    tr.disable()
+    tr.record("off", "test", 0.0, 1.0)
+    assert len(tr.spans()) == n
+
+
+class _StubAnnotation:
+    log = []
+
+    def __init__(self, name):
+        self.name = name
+
+    def __enter__(self):
+        self.log.append(("enter", self.name, threading.get_ident()))
+
+    def __exit__(self, *exc):
+        self.log.append(("exit", self.name, threading.get_ident()))
+
+
+def test_mirror_emits_one_annotation_per_span(monkeypatch):
+    import jax
+    _StubAnnotation.log = []
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", _StubAnnotation)
+    tr = Tracer()
+    tr.enable()
+    with tr.span("crypto.verify_many"):
+        with tr.span("crypto.dispatch"):
+            tr.instant("marker")            # ring-only
+        tr.record("crypto.queue_wait.scp", "crypto", tr.now(), 0.001)
+    me = threading.get_ident()
+    assert _StubAnnotation.log == [
+        ("enter", "crypto.verify_many", me), ("enter", "crypto.dispatch", me),
+        ("exit", "crypto.dispatch", me), ("exit", "crypto.verify_many", me)]
+    tr.disable()
+    with tr.span("crypto.verify_many"):
+        pass
+    assert len(_StubAnnotation.log) == 4
+
+
+def test_mirror_imports_no_jax(monkeypatch):
+    """With `jax` not loaded (a cpu-backend node) a span neither imports
+    it nor fails; nor while another thread is half way through importing
+    it (a module in sys.modules without its `profiler` yet)."""
+    import types
+    monkeypatch.delitem(sys.modules, "jax", raising=False)
+    tr = Tracer()
+    tr.enable()
+    with tr.span("close.apply"):
+        pass
+    assert "jax" not in sys.modules
+    monkeypatch.setitem(sys.modules, "jax", types.ModuleType("jax"))
+    with tr.span("close.apply"):
+        pass
+    assert [s.name for s in tr.spans()] == ["close.apply"] * 2
+
+
+def test_cpu_backend_node_never_imports_jax_because_of_tracing():
+    code = (
+        "import sys\n"
+        "from stellar_core_tpu.main.application import Application\n"
+        "from stellar_core_tpu.main.config import Config\n"
+        "from stellar_core_tpu.util.timer import ClockMode, VirtualClock\n"
+        "cfg = Config.test_config(0)\n"
+        "cfg.DATABASE = 'sqlite3://:memory:'\n"
+        "cfg.TRACE_ENABLED = True\n"
+        "app = Application(VirtualClock(ClockMode.VIRTUAL_TIME), cfg)\n"
+        "app.start()\n"
+        "app.manual_close()\n"
+        "names = {s.name for s in app.tracer.spans()}\n"
+        "app.stop()\n"
+        "assert 'ledger.close' in names, names\n"
+        "assert 'jax' not in sys.modules\n")
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, PYTHONPATH=root)
+    r = subprocess.run([sys.executable, "-c", code], env=env, cwd=root,
+                       capture_output=True, text=True, timeout=120)
+    assert r.returncode == 0, r.stderr[-2000:]
 
 
 # ------------------------------------------------------------ flight recorder
@@ -310,30 +476,69 @@ def test_metrics_filter_prefix(tmp_path):
 
 # -------------------------------------------------------------- overhead guard
 
-def test_disabled_tracing_close_overhead_within_noise():
-    """A traced-but-disabled close must cost the same as an
-    uninstrumented one: every span site degrades to one attribute check.
-    Medians over repeated closes; generous bound to stay flake-free on
-    loaded CI."""
+def _overhead_app():
+    app = make_app()
+    return app, [app, app.sig_verifier, app.herder.tx_queue]
 
-    def median_close_s(app, n=15):
+
+def _close(app):
+    app.manual_close()
+
+
+def _admit(app):
+    """One admission: herder.admit → txqueue.try_add → crypto.prewarm →
+    tx.check_valid; the close that follows (untimed) applies it, so the
+    next one gets the next sequence number."""
+    from stellar_core_tpu.crypto import keys
+    from stellar_core_tpu.testing import AppLedgerAdapter
+    root = AppLedgerAdapter(app).root_account()
+    frame = root.tx([root.op_payment(root.account_id, 1)])
+    keys.flush_verify_cache()
+    t0 = time.perf_counter()
+    status = app.submit_transaction(frame)
+    dt = time.perf_counter() - t0
+    assert status == 0
+    app.manual_close()
+    return dt
+
+
+def _verify_many(app):
+    from stellar_core_tpu.crypto.keys import SecretKey
+    sk = SecretKey.from_seed(b"o" * 32)
+    triples = [(sk.public_key.key_bytes, sk.sign(b"m%d" % i), b"m%d" % i)
+               for i in range(8)]
+    t0 = time.perf_counter()
+    assert all(app.sig_verifier.verify_many(triples))
+    return time.perf_counter() - t0
+
+
+@pytest.mark.parametrize("op", [_close, _admit, _verify_many])
+def test_disabled_tracing_close_overhead_within_noise(op):
+    """A traced-but-disabled close, admission or drain must cost the
+    same as an uninstrumented one: every span site degrades to one
+    attribute check. Medians over repeats; generous bound to stay
+    flake-free on loaded CI."""
+
+    def median_s(app, n=15):
         samples = []
         for _ in range(n):
             t0 = time.perf_counter()
-            app.manual_close()
-            samples.append(time.perf_counter() - t0)
+            dt = op(app)
+            samples.append(time.perf_counter() - t0 if dt is None else dt)
         samples.sort()
         return samples[len(samples) // 2]
 
-    app = make_app()
+    app, holders = _overhead_app()
     try:
-        median_close_s(app, n=3)   # warm caches/JIT paths
-        app.tracer = None          # uninstrumented: no tracer at all
-        app.sig_verifier.tracer = None
-        base = median_close_s(app)
-        app.tracer = Tracer()      # present but disabled
-        app.sig_verifier.tracer = app.tracer
-        disabled = median_close_s(app)
+        median_s(app, n=3)   # warm caches/JIT paths
+        for h in holders:    # uninstrumented: no tracer at all
+            h.tracer = None
+        base = median_s(app)
+        tracer = Tracer()    # present but disabled
+        for h in holders:
+            h.tracer = tracer
+        disabled = median_s(app)
+        assert tracer.spans() == []
     finally:
         app.stop()
     assert disabled <= base * 2.0 + 0.005, (disabled, base)
