@@ -59,13 +59,12 @@ def cpu_baseline_rate(n: int = 2000) -> float:
 # --- device bench (child process) ------------------------------------------
 
 def _prep_args(batch: int, n_keys: int = 64) -> tuple:
-    """Signed batch → device-ready jnp arg tuple for verify_batch_jit."""
+    """Signed batch → device-ready jnp arg tuple for verify_batch_packed
+    (the served entry: one packed (B, 128) uint8 array)."""
     import jax.numpy as jnp
     from stellar_core_tpu.ops import ed25519 as E
     pubs, sigs, msgs = _example_batch(batch, n_keys=n_keys)
-    prep = E.prepare_batch(pubs, sigs, msgs)
-    return tuple(jnp.asarray(prep[k]) for k in
-                 ("ay", "a_sign", "ry", "r_sign", "s_nibs", "k_nibs"))
+    return (jnp.asarray(E.prepare_batch(pubs, sigs, msgs)["packed"]),)
 
 
 def device_bench(batch: int = 8192, iters: int = 10,
@@ -80,14 +79,14 @@ def device_bench(batch: int = 8192, iters: int = 10,
     if args is None:
         args = _prep_args(batch)
     t_c = time.perf_counter()
-    ok = E.verify_batch_jit(*args)
+    ok = E.verify_batch_packed(*args)
     ok.block_until_ready()
     compile_s = time.perf_counter() - t_c
     assert bool(ok.all()), "verify kernel rejected valid signatures"
     best = 0.0
     for _ in range(iters):
         t0 = time.perf_counter()
-        E.verify_batch_jit(*args).block_until_ready()
+        E.verify_batch_packed(*args).block_until_ready()
         dt = time.perf_counter() - t0
         best = max(best, batch / dt)
     out = {"rate": best, "platform": platform, "batch": batch,
@@ -95,11 +94,11 @@ def device_bench(batch: int = 8192, iters: int = 10,
     # live-SCP SLO: per-dispatch latency of the SMALL (128) bucket — the
     # p50/p99 consensus actually feels (SCP timers budget ~1s)
     args2 = _prep_args(128, n_keys=32)
-    E.verify_batch_jit(*args2).block_until_ready()   # compile shape
+    E.verify_batch_packed(*args2).block_until_ready()   # compile shape
     lats = []
     for _ in range(50):
         t0 = time.perf_counter()
-        E.verify_batch_jit(*args2).block_until_ready()
+        E.verify_batch_packed(*args2).block_until_ready()
         lats.append(time.perf_counter() - t0)
     lats.sort()
     out["latency128_p50_ms"] = round(lats[len(lats) // 2] * 1000, 3)
@@ -148,7 +147,7 @@ def device_full_bench(batch: int = 8192, iters: int = 10) -> dict:
     from stellar_core_tpu.ops import ed25519 as E
     jax.clear_caches()
     t_w = time.perf_counter()
-    E.verify_batch_jit(*args).block_until_ready()
+    E.verify_batch_packed(*args).block_until_ready()
     results["compile_warm_s"] = round(time.perf_counter() - t_w, 2)
 
     # stage 2b: the verifier's own warmup over the two shapes stage 1

@@ -165,6 +165,7 @@ class VerifierStats:
         self._g_wdone = m.new_gauge("verifier.warmup.buckets-done")
         self._g_wsource = m.new_gauge("verifier.warmup.source")
         self._g_cc = m.new_gauge("verifier.compile-cache.enabled")
+        self._c_h2d = m.new_counter("verifier.h2d.bytes")
         self._c_hit = m.new_counter("verifier.compile-cache.hit")
         self._c_miss = m.new_counter("verifier.compile-cache.miss")
 
@@ -218,6 +219,17 @@ class VerifierStats:
         b["_occ"].update(occ)
         b["_pad"].update(pad)
         b["_m"].mark()
+
+    def record_h2d(self, nbytes: int) -> None:
+        """One dispatch's host→device input: 128 bytes a lane of the
+        bucket (ops/ed25519.py's packed array)."""
+        with self._lock:    # dispatches run on the main and worker threads
+            self._c_h2d.inc(nbytes)
+
+    @property
+    def h2d_bytes(self) -> int:
+        """Bytes handed to the device, summed over dispatches."""
+        return self._c_h2d.count
 
     # -- fleet: per-device attribution (ISSUE 11) ----------------------------
     def record_device_dispatch(self, idx: int, n: int, pad: int) -> None:
@@ -731,9 +743,6 @@ class TpuSigVerifier(BatchSigVerifier):
     PLAN_AUTOSAVE_DRAINS = 32
     PLAN_BASENAME = "warmup_buckets.json"
 
-    # the kernel's device argument order (prepare_batch dict keys)
-    ARG_KEYS = ("ay", "a_sign", "ry", "r_sign", "s_nibs", "k_nibs")
-
     def __init__(self, max_pending: int = 8192,
                  shard_threshold: Optional[int] = None,
                  devices: Optional[Sequence] = None,
@@ -803,8 +812,8 @@ class TpuSigVerifier(BatchSigVerifier):
         return got
 
     def _single_fn(self):
-        from ..ops.ed25519 import verify_batch_jit
-        return verify_batch_jit
+        from ..ops.ed25519 import verify_batch_packed
+        return verify_batch_packed
 
     def _route(self, n: int):
         """(fn, padded bucket, device idxs) for an n-sig sub-batch.
@@ -846,13 +855,12 @@ class TpuSigVerifier(BatchSigVerifier):
         everything dispatch needs, so the dispatch thread never touches
         host marshalling."""
         from ..ops import ed25519 as _e
-        from ..parallel.mesh import pad_batch_to
         fn, b, idxs = route
+        # one (b, 128) uint8 array, written at the bucket's size
         prep = _e.prepare_batch(
             [t[0] for t in chunk], [t[1] for t in chunk],
-            [t[2] for t in chunk])
-        padded = pad_batch_to(prep, b)
-        return {"args": self._device_args(padded, idxs),
+            [t[2] for t in chunk], size=b)
+        return {"arg": self._device_arg(prep["packed"], idxs),
                 "pre_ok": prep["pre_ok"], "n": len(chunk), "b": b,
                 "fn": fn, "idxs": idxs}
 
@@ -864,11 +872,12 @@ class TpuSigVerifier(BatchSigVerifier):
         with self._span("crypto.stage", n=len(chunk)):
             return self._stage_chunk(chunk, self._route(len(chunk)))
 
-    def _device_args(self, padded: dict, idxs: tuple) -> tuple:
-        """Explicit host→device placement: sharded over the mesh for a
-        fleet dispatch, committed to the default device otherwise — the
-        transfer happens here (on the staging thread when overlapped),
-        not inside the jit call."""
+    def _device_arg(self, packed, idxs: tuple):
+        """Explicit host→device placement of a dispatch's one input
+        array: sharded over the mesh for a fleet dispatch, committed to
+        its device otherwise — the transfer happens here (inside the
+        stage span, on the staging thread when overlapped), not inside
+        the jit call."""
         import jax
         devs, _health = self._fleet()
         if len(idxs) > 1:
@@ -877,8 +886,7 @@ class TpuSigVerifier(BatchSigVerifier):
             target = NamedSharding(mesh, P("dp"))
         else:
             target = devs[idxs[0]] if idxs else devs[0]
-        return tuple(jax.device_put(padded[k], target)
-                     for k in self.ARG_KEYS)
+        return jax.device_put(packed, target)
 
     # -- cockpit-driven warm start (ISSUE 11 tentpole) -----------------------
     def _load_warmup_plan(self):
@@ -951,16 +959,10 @@ class TpuSigVerifier(BatchSigVerifier):
         like live traffic (mesh-sharded at or above SHARD_MIN_BATCH) so
         warmup compiles the executables dispatch will actually use."""
         import numpy as np
+        from ..ops.ed25519 import PACKED_WIDTH
         fn, bb, idxs = self._route(b)
-        zeros = {
-            "ay": np.zeros((bb, 20), np.int32),
-            "a_sign": np.zeros((bb,), np.int32),
-            "ry": np.zeros((bb, 20), np.int32),
-            "r_sign": np.zeros((bb,), np.int32),
-            "s_nibs": np.zeros((bb, 64), np.int32),
-            "k_nibs": np.zeros((bb, 64), np.int32),
-        }
-        np.asarray(fn(*self._device_args(zeros, idxs)))
+        zeros = np.zeros((bb, PACKED_WIDTH), np.uint8)
+        np.asarray(fn(self._device_arg(zeros, idxs)))
 
     def _warmup_impl(self) -> None:
         from ..parallel.device import (
@@ -1047,7 +1049,7 @@ class TpuSigVerifier(BatchSigVerifier):
                                 devices=len(idxs)):
                     try:
                         with self._span("crypto.launch"):
-                            ok_dev = staged["fn"](*staged["args"])  # async
+                            ok_dev = staged["fn"](staged["arg"])  # async
                         wait_t0 = real_monotonic()
                         with self._span("crypto.device_wait"):
                             ok = np.asarray(ok_dev)  # blocks on the fleet
@@ -1083,6 +1085,7 @@ class TpuSigVerifier(BatchSigVerifier):
                         batches += 1
                         pad_waste += b - n
                         if st is not None:
+                            st.record_h2d(staged["arg"].nbytes)
                             # keyed by the LADDER shape, not the mesh-
                             # rounded padded size: a degraded 3-device
                             # fleet rounds 8192 to 8193, and an off-ladder

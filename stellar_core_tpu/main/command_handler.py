@@ -189,6 +189,7 @@ class CommandHandler:
         out["counters"] = {
             "batches_dispatched": getattr(inner, "batches_dispatched", 0),
             "sigs_verified": getattr(inner, "sigs_verified", 0),
+            "h2d_bytes": getattr(stats, "h2d_bytes", 0),
             "pending": v.pending(),
         }
         from ..crypto import keys as _keys

@@ -130,13 +130,11 @@ def _load() -> Optional[ctypes.CDLL]:
             if so is None:
                 return None
             lib = ctypes.CDLL(so)
-            lib.sct_prepare_batch.restype = ctypes.c_int
-            lib.sct_prepare_batch.argtypes = [
+            lib.sct_prepare_packed.restype = ctypes.c_int
+            lib.sct_prepare_packed.argtypes = [
                 ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p,
-                ctypes.c_void_p, ctypes.c_int64,
-                ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p,
-                ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p,
-                ctypes.c_void_p]
+                ctypes.c_void_p, ctypes.c_void_p, ctypes.c_int64,
+                ctypes.c_void_p, ctypes.c_void_p]
             lib.sct_cache_keys.restype = ctypes.c_int
             lib.sct_cache_keys.argtypes = [
                 ctypes.c_char_p, ctypes.c_char_p, ctypes.c_void_p,
@@ -167,11 +165,14 @@ def engine_status() -> dict:
             for name, (engine, src) in have.items()}
 
 
-def prepare_batch_native(pub_arr: np.ndarray, sig_arr: np.ndarray,
-                         msgs: list) -> Optional[dict]:
-    """(n,32)/(n,64) uint8 + message list → device-ready arrays, or None
-    when the native library is unavailable. Rows with wrong-length keys or
-    sigs must be pre-zeroed by the caller (same contract as _pack32)."""
+def prepare_packed_native(pub_arr: np.ndarray, sig_arr: np.ndarray,
+                          msgs: list, good: np.ndarray,
+                          out: np.ndarray) -> Optional[np.ndarray]:
+    """(n,32)/(n,64) uint8 + message list → the first n lanes of `out`
+    (a zeroed (size, 128) uint8 buffer, size >= n) written in place, and
+    the (n,) bool precheck mask; None when the native library is
+    unavailable. Rows with wrong-length keys or sigs are zero rows with
+    `good` False (same contract as _pack32)."""
     lib = _load()
     if lib is None:
         return None
@@ -179,26 +180,22 @@ def prepare_batch_native(pub_arr: np.ndarray, sig_arr: np.ndarray,
     blob = b"".join(msgs)
     off = np.zeros(n + 1, np.uint64)
     np.cumsum([len(m) for m in msgs], out=off[1:])
-    ay = np.empty((n, 20), np.int32)
-    ry = np.empty((n, 20), np.int32)
-    a_sign = np.empty(n, np.int32)
-    r_sign = np.empty(n, np.int32)
-    s_nibs = np.empty((n, 64), np.int32)
-    k_nibs = np.empty((n, 64), np.int32)
     pre_ok = np.empty(n, np.uint8)
     pub_c = np.ascontiguousarray(pub_arr)
     sig_c = np.ascontiguousarray(sig_arr)
+    good_c = np.ascontiguousarray(good, np.uint8)
     msg_c = np.frombuffer(blob, np.uint8) if blob else \
         np.zeros(1, np.uint8)
-    lib.sct_prepare_batch(
+    if out.dtype != np.uint8 or not out.flags.c_contiguous or \
+            out.shape[0] < n or out.shape[1:] != (128,):
+        raise ValueError("packed buffer must be C-contiguous uint8 "
+                         "(>= %d, 128), got %s %r" % (n, out.dtype,
+                                                      out.shape))
+    lib.sct_prepare_packed(
         pub_c.ctypes.data, sig_c.ctypes.data, msg_c.ctypes.data,
-        off.ctypes.data, n,
-        ay.ctypes.data, a_sign.ctypes.data,
-        ry.ctypes.data, r_sign.ctypes.data,
-        s_nibs.ctypes.data, k_nibs.ctypes.data, pre_ok.ctypes.data)
-    return {"ay": ay, "a_sign": a_sign, "ry": ry, "r_sign": r_sign,
-            "s_nibs": s_nibs, "k_nibs": k_nibs,
-            "pre_ok": pre_ok.astype(bool)}
+        off.ctypes.data, good_c.ctypes.data, n,
+        out.ctypes.data, pre_ok.ctypes.data)
+    return pre_ok.astype(bool)
 
 
 def cache_keys_native(triples) -> Optional[list]:

@@ -6,11 +6,12 @@
  *   - SHA-512 of R‖A‖M per item (k derivation, RFC 8032)
  *   - 512-bit reduction mod the group order L (Barrett, 64-bit limbs)
  *   - canonicality prechecks (S < L, y < p) per item
- *   - bit-slicing: 13-bit field limbs and radix-16 scalar digits
+ *   - the signed-digit recode of S and k, as one 256-bit addition each
  *
  * The reference does the equivalent work inside libsodium one signature
  * at a time (/root/reference/src/crypto/SecretKey.cpp:310-337); here it
- * feeds fixed-shape int32 arrays straight to the device kernel.
+ * writes one packed byte array, 128 bytes a signature, that the device
+ * kernel's entry splits into limbs and digits itself (ops/ed25519.py).
  *
  * Portable C11 + __int128 (gcc/clang on x86-64/aarch64). Constants are
  * generated exactly by gen_constants.py (see prep_constants.h).
@@ -209,37 +210,21 @@ static void mod_L(const uint64_t x[8], uint64_t r[4])
         r[i] = rr[i];
 }
 
-/* --------------------------------------------------------- bit slicing */
+/* ------------------------------------------------------ canonicality */
 
-static void le_bytes_to_limbs13(const uint8_t b[32], int32_t out[20])
+static inline uint64_t load_le64(const uint8_t *p)
 {
-    for (int i = 0; i < 20; i++) {
-        int bit = 13 * i;
-        int k = bit >> 3, sh = bit & 7;
-        uint32_t v = b[k] >> sh;
-        if (k + 1 < 32)
-            v |= (uint32_t)b[k + 1] << (8 - sh);
-        if (k + 2 < 32)
-            v |= (uint32_t)b[k + 2] << (16 - sh);
-        out[i] = (int32_t)(v & 0x1fff);
-    }
-}
-
-static void le_bytes_to_nibs(const uint8_t b[32], int32_t out[64])
-{
-    for (int i = 0; i < 32; i++) {
-        out[2 * i] = b[i] & 15;
-        out[2 * i + 1] = b[i] >> 4;
-    }
+    uint64_t v = 0;
+    for (int j = 7; j >= 0; j--)
+        v = (v << 8) | p[j];
+    return v;
 }
 
 /* little-endian 32-byte < 4×64-bit-limb constant */
 static int lt_le(const uint8_t b[32], const uint64_t lim[4])
 {
     for (int i = 3; i >= 0; i--) {
-        uint64_t v = 0;
-        for (int j = 7; j >= 0; j--)
-            v = (v << 8) | b[8 * i + j];
+        uint64_t v = load_le64(b + 8 * i);
         if (v < lim[i])
             return 1;
         if (v > lim[i])
@@ -248,59 +233,74 @@ static int lt_le(const uint8_t b[32], const uint64_t lim[4])
     return 0;
 }
 
+/* ---------------------------------------------- signed-digit recode */
+
+/* out = x + 0x88…88 as 32 little-endian bytes. Nibble i of the sum is
+ * the signed radix-16 digit of x plus 8: adding 8 to every nibble
+ * carries exactly when the carry-propagating recode does (nibble +
+ * carry-in >= 8), so the device gets the digits in [-8, 8) with one
+ * subtraction a nibble. x < 2^253 (S and k are both < L), so the sum
+ * stays below 2^256. */
+static void add_recode_bias(const uint64_t x[4], uint8_t out[32])
+{
+    uint64_t carry = 0;
+    for (int w = 0; w < 4; w++) {
+        u128 t = (u128)x[w] + 0x8888888888888888ULL + carry;
+        carry = (uint64_t)(t >> 64);
+        for (int j = 0; j < 8; j++)
+            out[8 * w + j] = (uint8_t)((uint64_t)t >> (8 * j));
+    }
+}
+
 /* ------------------------------------------------------------ batch API */
 
-int sct_prepare_batch(const uint8_t *pubs,      /* n*32 */
-                      const uint8_t *sigs,      /* n*64 */
-                      const uint8_t *msgs,      /* concatenated bodies */
-                      const uint64_t *msg_off,  /* n+1 offsets */
-                      int64_t n,
-                      int32_t *ay, int32_t *a_sign,
-                      int32_t *ry, int32_t *r_sign,
-                      int32_t *s_nibs, int32_t *k_nibs,
-                      uint8_t *pre_ok)
+/* One verify dispatch's device input: 128 bytes a lane,
+ *   A (32) | R (32) | S + 0x88…88 (32) | k + 0x88…88 (32)
+ * with k = SHA-512(R‖A‖M) mod L and the sign bits where they already
+ * sit (bit 255 of A and R). `out` is the caller's buffer, already of the
+ * bucket's size and zeroed; the first n lanes are written. A lane that
+ * fails a precheck (or whose `good` byte is 0: a key or signature of
+ * the wrong length) stays all zero and reads pre_ok 0: the host masks
+ * its verdict, whatever the device makes of it. */
+int sct_prepare_packed(const uint8_t *pubs,      /* n*32 */
+                       const uint8_t *sigs,      /* n*64 */
+                       const uint8_t *msgs,      /* concatenated bodies */
+                       const uint64_t *msg_off,  /* n+1 offsets */
+                       const uint8_t *good,      /* n */
+                       int64_t n,
+                       uint8_t *out,             /* >= n*128, zeroed */
+                       uint8_t *pre_ok)          /* n */
 {
     for (int64_t i = 0; i < n; i++) {
         const uint8_t *pub = pubs + 32 * i;
         const uint8_t *sig = sigs + 64 * i;
+        uint8_t *lane = out + 128 * i;
         uint8_t ayb[32], ryb[32];
         memcpy(ayb, pub, 32);
         memcpy(ryb, sig, 32);
-        a_sign[i] = ayb[31] >> 7;
-        r_sign[i] = ryb[31] >> 7;
         ayb[31] &= 0x7f;
         ryb[31] &= 0x7f;
 
-        int ok = lt_le(sig + 32, ED_L) && lt_le(ayb, ED_P) &&
+        int ok = good[i] && lt_le(sig + 32, ED_L) && lt_le(ayb, ED_P) &&
                  lt_le(ryb, ED_P);
         pre_ok[i] = (uint8_t)ok;
-        if (!ok) {
-            memset(ay + 20 * i, 0, 20 * 4);
-            memset(ry + 20 * i, 0, 20 * 4);
-            memset(s_nibs + 64 * i, 0, 64 * 4);
-            memset(k_nibs + 64 * i, 0, 64 * 4);
+        if (!ok)
             continue;
-        }
-        le_bytes_to_limbs13(ayb, ay + 20 * i);
-        le_bytes_to_limbs13(ryb, ry + 20 * i);
-        le_bytes_to_nibs(sig + 32, s_nibs + 64 * i);
+        memcpy(lane, pub, 32);
+        memcpy(lane + 32, sig, 32);
+
+        uint64_t x[8], sred[4], kred[4];
+        for (int w = 0; w < 4; w++)
+            sred[w] = load_le64(sig + 32 + 8 * w);
+        add_recode_bias(sred, lane + 64);
 
         uint8_t digest[64];
         sha512_ram(sig, pub, msgs + msg_off[i],
                    msg_off[i + 1] - msg_off[i], digest);
-        uint64_t x[8], kred[4];
-        for (int w = 0; w < 8; w++) {
-            uint64_t v = 0;
-            for (int j = 7; j >= 0; j--)
-                v = (v << 8) | digest[8 * w + j];
-            x[w] = v;
-        }
+        for (int w = 0; w < 8; w++)
+            x[w] = load_le64(digest + 8 * w);
         mod_L(x, kred);
-        uint8_t kb[32];
-        for (int w = 0; w < 4; w++)
-            for (int j = 0; j < 8; j++)
-                kb[8 * w + j] = (uint8_t)(kred[w] >> (8 * j));
-        le_bytes_to_nibs(kb, k_nibs + 64 * i);
+        add_recode_bias(kred, lane + 96);
     }
     return 0;
 }

@@ -7,6 +7,13 @@ Design (TPU-first; replaces the reference's per-call libsodium
   backend semantics exactly): [S]B == R + [k]A with k = SHA512(R‖A‖M) mod L.
   We compute Q = [S]B + [k](−A) on-device and compare with the decompressed
   R projectively (no inversion).
+- INPUT CONTRACT: one `(B, 128)` uint8 array a dispatch, 128 bytes a
+  lane: A (32) | R (32) | S + 0x88…88 (32) | k + 0x88…88 (32), sign bits
+  where they already sit (bit 255 of A and R). The served entry
+  `verify_batch_packed` splits it on the device (`unpack_packed`, scope
+  `ed25519.unpack`) into limbs, sign bits and signed digits and calls
+  `verify_kernel`, the six-argument kernel body, which the differential
+  tests also call directly.
 - LAYOUT: all device arrays are limb-first / batch-last ((20, B) field
   elements, (64, B) scalar digits) so the batch rides the TPU lane
   dimension at full width; see ops/field.py header. The public
@@ -16,11 +23,18 @@ Design (TPU-first; replaces the reference's per-call libsodium
   (4, 20) axis for XLA to pad; each coordinate is an independent
   full-lane array.
 - Host does the byte-level work that TPUs are bad at: SHA-512 (tiny
-  messages), canonicality prechecks (S < L, y < p), bit-slicing keys into
-  13-bit limbs and scalars into 4-bit windows — all numpy-vectorized
-  across the batch except the per-item SHA-512 + mod L (C-speed hashlib).
-- Scalars use SIGNED radix-16 digits in [−8, 8) (wNAF-style recoding on
-  the host): table magnitudes only span 0..8, so both lookup tables are
+  messages), mod L, canonicality prechecks (S < L, y < p), and one
+  256-bit addition a scalar (below) — one native call a batch
+  (native/prep.c), or numpy + hashlib + Python ints with
+  SCT_NATIVE_PREP=0, byte for byte the same buffer. The bit-slicing
+  (13-bit limbs, 4-bit windows) is shifts and masks on the device.
+- Scalars use SIGNED radix-16 digits in [−8, 8). The carry-propagating
+  recode of x is `nibble_i(x + 0x88…88) − 8`, digit by digit the same
+  carry rule (a nibble plus its carry-in reaches 8 exactly when adding 8
+  more carries out), so the host adds one constant and the device
+  subtracts 8 from every nibble; x < 2^253 keeps the sum below 2^256.
+  `signed_recode_nibs_np` is the digit-by-digit form, kept as the tests'
+  oracle. Table magnitudes only span 0..8, so both lookup tables are
   9-wide instead of 16-wide (≈44% less masked-select traffic — the
   select is pure data movement on the VPU) and the per-item table build
   shrinks from 14 point ops to 7. Negation is a cheap conditional on the
@@ -51,6 +65,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from ..parallel.device import verify_compile_options
 from .field import (
     NLIMBS, LIMB_BITS, LIMB_MASK, P, _bcast, fe_add, fe_carry, fe_eq,
     fe_freeze, fe_is_zero, fe_mul, fe_mul_small, fe_neg, fe_one, fe_parity,
@@ -331,8 +346,8 @@ def verify_kernel(ay: jnp.ndarray, a_sign: jnp.ndarray,
     """Batched verify core. All inputs int32, batch-first (host layout):
     ay, ry: (B, 20) canonical y limbs; a_sign, r_sign: (B,);
     s_nibs, k_nibs: (B, 64) SIGNED radix-16 digits in [−8, 8)
-    (LSB-first, host-recoded by signed_recode_nibs_np) of S and of
-    k = SHA512(R‖A‖M) mod L. Returns (B,) bool.
+    (LSB-first, as unpack_packed or signed_recode_nibs_np gives them) of
+    S and of k = SHA512(R‖A‖M) mod L. Returns (B,) bool.
 
     Internally everything is limb-first (20, B) / digit-first (64, B); the
     transposes below are the only layout shuffles in the whole kernel.
@@ -421,7 +436,7 @@ def verify_kernel(ay: jnp.ndarray, a_sign: jnp.ndarray,
         return a_ok & r_ok & eq
 
 
-# --- host-side batch preparation (numpy-vectorized) ------------------------
+# --- host-side batch preparation, and the device-side unpack ---------------
 
 _L_BYTES_BE = np.frombuffer(L.to_bytes(32, "big"), np.uint8)
 _P_BYTES_BE = np.frombuffer(P.to_bytes(32, "big"), np.uint8)
@@ -485,38 +500,43 @@ def _pack32(items, n: int, width: int) -> np.ndarray:
     return np.frombuffer(blob, np.uint8).reshape(n, width)
 
 
-def prepare_batch(pubs: list[bytes], sigs: list[bytes],
-                  msgs: list[bytes]) -> dict:
-    """Host preprocessing: hashing, canonicality prechecks, bit-slicing.
-    Returns device-ready int32 arrays + a host-side precheck mask.
+PACKED_WIDTH = 128
+# x + RECODE_BIAS: nibble i is the signed radix-16 digit of x, plus 8
+RECODE_BIAS = int.from_bytes(b"\x88" * 32, "little")
 
-    Everything is numpy-vectorized across the batch except the per-item
-    SHA-512 + 512-bit mod L (hashlib/CPython bignum — C speed, ~1.5 µs
-    per item; at the 100K sigs/s north star this is ~15% of one core,
-    and it overlaps the device batch in the async backend)."""
+
+def prepare_batch(pubs: list[bytes], sigs: list[bytes],
+                  msgs: list[bytes], size: int | None = None) -> dict:
+    """Host preprocessing: hashing, canonicality prechecks, the recode
+    bias. Returns {"packed": the (size, 128) uint8 device input (size
+    defaults to the batch; a bucket's size pads with zero lanes), and
+    "pre_ok": the (n,) host-side precheck mask}. A lane that fails a
+    precheck is all zero, like padding: its device verdict is masked.
+
+    One native call (prep.c) writes the lanes in place; the Python path
+    below gives the same bytes with a per-item SHA-512 + Python-int
+    loop (the oracle tests select it with SCT_NATIVE_PREP=0)."""
     n = len(pubs)
+    size = n if size is None else size
+    if size < n:
+        raise ValueError("size %d below the batch's %d" % (size, n))
     good = np.zeros(n, bool)
     for i in range(min(n, len(sigs), len(msgs))):
         good[i] = len(pubs[i]) == 32 and len(sigs[i]) == 64
     msgs = list(msgs[:n]) + [b""] * (n - len(msgs))
     pub_arr = _pack32(pubs, n, 32)
     sig_arr = _pack32(sigs, n, 64)
+    packed = np.zeros((size, PACKED_WIDTH), np.uint8)
 
     if os.environ.get("SCT_NATIVE_PREP", "1") != "0":
         from .. import native
-        prep = native.prepare_batch_native(pub_arr, sig_arr, msgs)
-        if prep is not None:
-            prep["pre_ok"] = prep["pre_ok"] & good
-            # the native layer keeps the plain unsigned-nibble contract;
-            # the kernel wants signed digits
-            prep["s_nibs"] = signed_recode_nibs_np(prep["s_nibs"])
-            prep["k_nibs"] = signed_recode_nibs_np(prep["k_nibs"])
-            return prep
+        pre_ok = native.prepare_packed_native(pub_arr, sig_arr, msgs,
+                                              good, packed)
+        if pre_ok is not None:
+            return {"packed": packed, "pre_ok": pre_ok}
     r_arr = sig_arr[:, :32]
     s_arr = sig_arr[:, 32:]
 
-    a_sign = (pub_arr[:, 31] >> 7).astype(np.int32)
-    r_sign = (r_arr[:, 31] >> 7).astype(np.int32)
     ay = pub_arr.copy()
     ay[:, 31] &= 0x7F
     ry = r_arr.copy()
@@ -528,28 +548,98 @@ def prepare_batch(pubs: list[bytes], sigs: list[bytes],
     ry_ok = _lex_lt_be(ry[:, ::-1], _P_BYTES_BE)
     pre_ok = good & s_ok & ay_ok & ry_ok
 
-    # k = SHA512(R‖A‖M) mod L — the only per-item loop
-    k_bytes = bytearray(32 * n)
-    for i in range(n):
-        if not pre_ok[i]:
-            continue
-        h = hashlib.sha512(
-            sig_arr[i, :32].tobytes() + pub_arr[i].tobytes() +
-            msgs[i]).digest()
-        k = int.from_bytes(h, "little") % L
-        k_bytes[32 * i:32 * i + 32] = k.to_bytes(32, "little")
-    k_arr = np.frombuffer(bytes(k_bytes), np.uint8).reshape(n, 32)
-
-    zero_bad = pre_ok[:, None].astype(np.uint8)
-    return {
-        "ay": bytes_to_limbs_np(ay * zero_bad), "a_sign": a_sign,
-        "ry": bytes_to_limbs_np(ry * zero_bad), "r_sign": r_sign,
-        "s_nibs": signed_recode_nibs_np(bytes_to_nibs_np(s_arr * zero_bad)),
-        "k_nibs": signed_recode_nibs_np(bytes_to_nibs_np(k_arr)),
-        "pre_ok": pre_ok,
-    }
+    # k = SHA512(R‖A‖M) mod L and the two biased scalars — the only
+    # per-item loop
+    for i in np.flatnonzero(pre_ok):
+        r_b, a_b = r_arr[i].tobytes(), pub_arr[i].tobytes()
+        k = int.from_bytes(hashlib.sha512(r_b + a_b + msgs[i]).digest(),
+                           "little") % L
+        s = int.from_bytes(s_arr[i].tobytes(), "little")
+        packed[i] = np.frombuffer(
+            a_b + r_b + (s + RECODE_BIAS).to_bytes(32, "little") +
+            (k + RECODE_BIAS).to_bytes(32, "little"), np.uint8)
+    return {"packed": packed, "pre_ok": pre_ok}
 
 
+def unpack_packed_np(packed: np.ndarray) -> tuple:
+    """Host mirror of unpack_packed, the long way round: the bias taken
+    off again with Python ints and the digits recoded one by one
+    (signed_recode_nibs_np). The differential tests' oracle for the
+    device's shifts and masks; nothing served calls it."""
+    a, r = packed[:, 0:32].copy(), packed[:, 32:64].copy()
+    a_sign, r_sign = a[:, 31] >> 7, r[:, 31] >> 7
+    a[:, 31] &= 0x7F
+    r[:, 31] &= 0x7F
+
+    def scalar_digits(cols):
+        vals = [int.from_bytes(row.tobytes(), "little") for row in cols]
+        raw = b"".join(max(v - RECODE_BIAS, 0).to_bytes(32, "little")
+                       for v in vals)
+        digs = signed_recode_nibs_np(bytes_to_nibs_np(
+            np.frombuffer(raw, np.uint8).reshape(-1, 32)))
+        # a zero lane (padding, a failed precheck) is no scalar's image:
+        # the device reads every nibble as 0 − 8 there
+        digs[[v < RECODE_BIAS for v in vals]] = -8
+        return digs
+
+    return (bytes_to_limbs_np(a), a_sign.astype(np.int32),
+            bytes_to_limbs_np(r), r_sign.astype(np.int32),
+            scalar_digits(packed[:, 64:96]), scalar_digits(packed[:, 96:128]))
+
+
+# limb i reads bytes k, k+1, k+2 from bit offset r (k + 2 = 33 at most:
+# two zero rows pad the 32 bytes)
+_LIMB_BYTE = [(LIMB_BITS * i) >> 3 for i in range(NLIMBS)]
+_LIMB_SHIFT = np.array([(LIMB_BITS * i) & 7 for i in range(NLIMBS)],
+                       np.int32)
+
+
+def unpack_packed(packed: jnp.ndarray) -> tuple:
+    """(B, 128) uint8 → verify_kernel's six int32 arguments (batch-first:
+    ay, a_sign, ry, r_sign, s_nibs, k_nibs). Works limb-first on the
+    transposed bytes, so every shift and mask runs at full lane width;
+    the transposes back meet verify_kernel's own and cancel."""
+    x = packed.astype(jnp.int32).T                         # (128, B)
+
+    def point(b):                                          # b: (32, B)
+        sign = b[31] >> 7
+        b = jnp.concatenate([b[:31], b[31:] & 0x7F,
+                             jnp.zeros_like(b[:2])], axis=0)
+        sh = _LIMB_SHIFT[:, None]
+        b0, b1, b2 = (jnp.stack([b[k + j] for k in _LIMB_BYTE])
+                      for j in range(3))          # static slices, (20, B)
+        limbs = ((b0 >> sh) | (b1 << (8 - sh)) | (b2 << (16 - sh))) \
+            & LIMB_MASK
+        return limbs.T, sign
+
+    def digits(b):                                         # b: (32, B)
+        d = jnp.stack([(b & 15) - 8, (b >> 4) - 8], axis=1)
+        return d.reshape(64, -1).T
+
+    ay, a_sign = point(x[0:32])
+    ry, r_sign = point(x[32:64])
+    return ay, a_sign, ry, r_sign, digits(x[64:96]), digits(x[96:128])
+
+
+def verify_packed(packed: jnp.ndarray) -> jnp.ndarray:
+    """The served entry's body: one (B, 128) uint8 array in, (B,) bool
+    out. Jitted as `verify_batch_packed` below and, sharded over the dp
+    axis, by parallel/mesh.py."""
+    with jax.named_scope("ed25519.unpack"):
+        args = unpack_packed(packed)
+    return verify_kernel(*args)
+
+
+# the device module is named after the function: the benchmark finds the
+# verify executable's runs in a device trace by the prefix
+# `jit_verify_batch`
+@partial(jax.jit, compiler_options=verify_compile_options())
+def verify_batch_packed(packed):
+    return verify_packed(packed)
+
+
+# the six-argument kernel jitted alone: what the differential tests and
+# benchmark/tests/record_trace.py call; nothing served does
 @partial(jax.jit, static_argnames=())
 def verify_batch_jit(ay, a_sign, ry, r_sign, s_nibs, k_nibs):
     return verify_kernel(ay, a_sign, ry, r_sign, s_nibs, k_nibs)
@@ -559,8 +649,5 @@ def verify_batch(pubs: list[bytes], sigs: list[bytes],
                  msgs: list[bytes]) -> np.ndarray:
     """End-to-end batched verify (host prep + device kernel)."""
     prep = prepare_batch(pubs, sigs, msgs)
-    ok = np.asarray(verify_batch_jit(
-        jnp.asarray(prep["ay"]), jnp.asarray(prep["a_sign"]),
-        jnp.asarray(prep["ry"]), jnp.asarray(prep["r_sign"]),
-        jnp.asarray(prep["s_nibs"]), jnp.asarray(prep["k_nibs"])))
+    ok = np.asarray(verify_batch_packed(prep["packed"]))
     return ok & prep["pre_ok"]

@@ -21,8 +21,9 @@ from typing import Optional, Sequence
 import numpy as np
 
 import jax
-import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+from .device import verify_compile_options
 
 
 def make_mesh(devices: Optional[Sequence] = None) -> Mesh:
@@ -31,35 +32,19 @@ def make_mesh(devices: Optional[Sequence] = None) -> Mesh:
 
 
 def sharded_verify_fn(mesh: Mesh):
-    """jit-compiled batched ed25519 verify with inputs/outputs sharded over
-    the dp axis. Batch size must be a multiple of the mesh size."""
-    from ..ops.ed25519 import verify_kernel
+    """jit-compiled batched ed25519 verify over the packed input
+    (ops/ed25519.py: one (B, 128) uint8 array), batch axis and verdicts
+    sharded over dp. Batch size must be a multiple of the mesh size."""
+    from ..ops.ed25519 import verify_packed
 
     data = NamedSharding(mesh, P("dp"))
 
-    @partial(jax.jit,
-             in_shardings=(data,) * 6,
-             out_shardings=data)
-    def fn(ay, a_sign, ry, r_sign, s_nibs, k_nibs):
-        return verify_kernel(ay, a_sign, ry, r_sign, s_nibs, k_nibs)
+    @partial(jax.jit, in_shardings=data, out_shardings=data,
+             compiler_options=verify_compile_options())
+    def verify_batch_packed_dp(packed):
+        return verify_packed(packed)
 
-    return fn
-
-
-def pad_batch_to(prep: dict, size: int) -> dict:
-    """Pad host-prepared arrays up to `size` (invalid padding lanes verify
-    False and are masked by pre_ok)."""
-    n = prep["ay"].shape[0]
-    assert size >= n
-    pad = size - n
-    out = {}
-    for k, v in prep.items():
-        if k == "pre_ok":
-            out[k] = np.concatenate([v, np.zeros(pad, bool)])
-        else:
-            out[k] = np.concatenate(
-                [v, np.zeros((pad,) + v.shape[1:], v.dtype)])
-    return out
+    return verify_batch_packed_dp
 
 
 def multichip_verify(pubs, sigs, msgs, mesh: Optional[Mesh] = None):
@@ -67,13 +52,7 @@ def multichip_verify(pubs, sigs, msgs, mesh: Optional[Mesh] = None):
     from ..ops.ed25519 import prepare_batch
     mesh = mesh or make_mesh()
     ndev = mesh.devices.size
-    prep = prepare_batch(pubs, sigs, msgs)
-    n = prep["ay"].shape[0]
-    padded = -(-n // ndev) * ndev
-    prep = pad_batch_to(prep, padded)
-    fn = sharded_verify_fn(mesh)
-    ok = np.asarray(fn(
-        jnp.asarray(prep["ay"]), jnp.asarray(prep["a_sign"]),
-        jnp.asarray(prep["ry"]), jnp.asarray(prep["r_sign"]),
-        jnp.asarray(prep["s_nibs"]), jnp.asarray(prep["k_nibs"])))
-    return ok[:n] & prep["pre_ok"][:n]
+    n = len(pubs)
+    prep = prepare_batch(pubs, sigs, msgs, size=-(-n // ndev) * ndev)
+    ok = np.asarray(sharded_verify_fn(mesh)(prep["packed"]))
+    return ok[:n] & prep["pre_ok"]

@@ -216,23 +216,66 @@ def test_core3_consensus_with_async_backend():
                for n in sim.nodes.values())
 
 
-def test_aot_warmup_compiles_all_buckets():
-    """After warmup, live flushes trigger no new kernel compilation."""
-    from stellar_core_tpu.ops.ed25519 import verify_batch_jit
-    v = TpuSigVerifier()
+@pytest.mark.parametrize("ndev", [1, 4])
+def test_aot_warmup_compiles_all_buckets(ndev):
+    """After warmup, live flushes trigger no new kernel compilation:
+    warm-up calls the served entry (the packed jit, or the dp-sharded
+    one on the mesh route) with the signature a dispatch calls it with."""
+    import jax
+    from stellar_core_tpu.ops.ed25519 import verify_batch_packed
+    v = TpuSigVerifier(shard_threshold=1, devices=jax.devices()[:ndev])
     v.BUCKETS = (32,)
     v.warmup(wait=True)
     assert v._warmed
-    cache_size_fn = getattr(verify_batch_jit, "_cache_size", None)
-    before = cache_size_fn() if cache_size_fn else None
+    served, _b, idxs = v._route(1)
+    assert len(idxs) == ndev
+    assert (served is verify_batch_packed) == (ndev == 1)
+    before = served._cache_size()
+    assert before >= 1, "warm-up did not call the served entry"
     from stellar_core_tpu.testing import root_secret_key
     sk = root_secret_key()
     _clear_verify_cache()
+    dispatched = v.batches_dispatched
     res = v.verify_many([(sk.public_key.key_bytes, sk.sign(b"warm"),
                           b"warm")])
-    assert res == [True]
-    if cache_size_fn:
-        assert cache_size_fn() == before, "flush after warmup recompiled"
+    assert res == [True] and v.batches_dispatched == dispatched + 1
+    assert served._cache_size() == before, "flush after warmup recompiled"
+
+
+def test_verifier_endpoint_counts_one_packed_array_a_dispatch():
+    """`GET verifier` (ISSUE 26): `h2d_bytes` beside `batches_dispatched`
+    reads 128 bytes a lane of the bucket, one array a dispatch, whatever
+    the batch held — through a node's admission path."""
+    from stellar_core_tpu.main.application import Application
+    from stellar_core_tpu.main.config import Config
+    from stellar_core_tpu.util.timer import ClockMode, VirtualClock
+
+    cfg = Config.test_config(0, backend="tpu")
+    cfg.SIG_VERIFY_WARMUP = False
+    app = Application(VirtualClock(ClockMode.VIRTUAL_TIME), cfg)
+    app.sig_verifier.inner.BUCKETS = (32,)
+    app.start()
+    try:
+        _clear_verify_cache()
+        st, body = app.command_handler.handle_command("verifier", {})
+        assert st == 200 and body["counters"]["h2d_bytes"] == 0
+        root = AppLedgerAdapter(app).root_account()
+        dest = K.SecretKey.from_seed(b"h" * 32)
+        frame = root.tx([root.op_create_account(dest.public_key, 10 ** 9)])
+        assert app.submit_transaction(frame) == 0
+        sk = K.SecretKey.from_seed(b"i" * 32)
+        triples = [(sk.public_key.key_bytes, sk.sign(b"h2d-%d" % i),
+                    b"h2d-%d" % i) for i in range(33)]   # 32 + 1: 2 chunks
+        assert all(app.sig_verifier.verify_many(triples))
+        st, body = app.command_handler.handle_command("verifier", {})
+        c = body["counters"]
+        assert c["batches_dispatched"] == 3 and c["sigs_verified"] == 34
+        assert c["h2d_bytes"] == 128 * 32 * c["batches_dispatched"]
+        m = app.command_handler.handle_command(
+            "metrics", {"filter": "verifier.h2d"})[1]
+        assert m["verifier.h2d.bytes"]["count"] == c["h2d_bytes"]
+    finally:
+        app.stop()
 
 
 def test_crank_until_flushes_pending_verifies():

@@ -10,7 +10,8 @@ data-parallel over ICI; the only cross-chip traffic is the result gather.
 import jax
 import pytest
 
-from stellar_core_tpu.crypto.batch_verifier import TpuSigVerifier
+from stellar_core_tpu.crypto.batch_verifier import (
+    TpuSigVerifier, VerifierStats)
 from stellar_core_tpu.crypto.keys import SecretKey
 from stellar_core_tpu.ops.ed25519 import L, verify_oracle
 from stellar_core_tpu.parallel.mesh import (
@@ -63,14 +64,11 @@ def test_multichip_verify_padding_not_multiple_of_mesh():
 
 
 def _device_args(pubs, sigs, msgs, pad_to=None):
-    import jax.numpy as jnp
+    """The served contract: one packed (B, 128) uint8 array."""
     from stellar_core_tpu.ops.ed25519 import prepare_batch
-    from stellar_core_tpu.parallel.mesh import pad_batch_to
-    prep = prepare_batch(pubs, sigs, msgs)
-    if pad_to is not None:
-        prep = pad_batch_to(prep, pad_to)
-    return tuple(jnp.asarray(prep[k]) for k in
-                 ("ay", "a_sign", "ry", "r_sign", "s_nibs", "k_nibs"))
+    prep = prepare_batch(pubs, sigs, msgs, size=pad_to)
+    assert prep["pre_ok"].all()
+    return (prep["packed"],)
 
 
 def test_weak_scaling_1_2_4_8_devices():
@@ -128,9 +126,12 @@ def test_production_size_sharded_batch_with_uneven_tail():
     for i in bad:
         sigs[i] = bytes([sigs[i][0] ^ 1]) + sigs[i][1:]
     v = TpuSigVerifier(shard_threshold=1)
+    v.stats = VerifierStats()
     got = v.verify_many(list(zip(pubs, sigs, msgs)))
     assert got == [i not in bad for i in range(n)]
     assert v.batches_dispatched == 2          # 8192 bucket + 147-tail bucket
+    # one packed array a dispatch, 128 bytes a lane of its bucket
+    assert v.stats.h2d_bytes == 128 * (8192 + 512)
     assert v.sigs_verified == n
     assert v._sharded_fn is not None          # mesh path actually taken
     for i in (0, 1, 8191, 8192, n - 1):       # sampled oracle agreement
@@ -138,18 +139,21 @@ def test_production_size_sharded_batch_with_uneven_tail():
 
 
 def test_sharded_fn_equals_single_device_kernel():
+    """The dp-sharded one-array entry against the six-argument kernel
+    on one device, its arguments unpacked on the host the long way."""
     import numpy as np
-    import jax.numpy as jnp
-    from stellar_core_tpu.ops.ed25519 import prepare_batch, verify_batch_jit
+    from stellar_core_tpu.ops.ed25519 import (
+        unpack_packed_np, verify_batch_jit)
 
     pubs, sigs, msgs = _batch(16)
     sigs[3] = bytes([sigs[3][0] ^ 1]) + sigs[3][1:]
-    prep = prepare_batch(pubs, sigs, msgs)
-    args = tuple(jnp.asarray(prep[k]) for k in
-                 ("ay", "a_sign", "ry", "r_sign", "s_nibs", "k_nibs"))
-    single = np.asarray(verify_batch_jit(*args))
-    sharded = np.asarray(sharded_verify_fn(make_mesh())(*args))
-    assert (single == sharded).all()
+    packed, = _device_args(pubs, sigs, msgs)
+    single = np.asarray(verify_batch_jit(*unpack_packed_np(packed)))
+    out = sharded_verify_fn(make_mesh())(packed)
+    # the one input is split over dp on its batch axis, 128 bytes a lane
+    assert {s.data.shape for s in out.addressable_shards} == {(2,)}
+    assert (single == np.asarray(out)).all()
+    assert list(single) == [i != 3 for i in range(16)]
 
 
 def test_graft_entry_returns_host_args_and_compiles():

@@ -1,9 +1,10 @@
 """Native (C) host-prep parity with the numpy/hashlib path.
 
-The C module owns SHA-512, Barrett mod-L, canonicality prechecks and bit
-slicing for the whole batch; any divergence from the Python path would
-change verify verdicts, so parity is asserted bit-for-bit on canonical
-rows and verdict-for-verdict end to end.
+The C module owns SHA-512, Barrett mod-L, canonicality prechecks and the
+recode bias for the whole batch, written as one packed byte array (128
+bytes a lane); any divergence from the Python path would change verify
+verdicts, so parity is asserted byte for byte on the whole buffer and
+verdict-for-verdict end to end.
 """
 
 import os
@@ -46,14 +47,17 @@ def _batch(n=200, seed=5):
 def test_native_matches_numpy_prep(monkeypatch):
     pubs, sigs, msgs = _batch()
     monkeypatch.setenv("SCT_NATIVE_PREP", "0")
-    ref = E.prepare_batch(pubs, sigs, msgs)
+    ref = E.prepare_batch(pubs, sigs, msgs, size=256)
     monkeypatch.setenv("SCT_NATIVE_PREP", "1")
-    nat = E.prepare_batch(pubs, sigs, msgs)
+    nat = E.prepare_batch(pubs, sigs, msgs, size=256)
     assert (np.asarray(ref["pre_ok"]) == np.asarray(nat["pre_ok"])).all()
-    mask = ref["pre_ok"]
-    for k in ("ay", "a_sign", "ry", "r_sign", "s_nibs", "k_nibs"):
-        assert (np.asarray(ref[k])[mask] ==
-                np.asarray(nat[k])[mask]).all(), k
+    assert not ref["pre_ok"][[5, 6, 7]].any() and ref["pre_ok"].sum() == 197
+    assert nat["packed"].shape == (256, 128)
+    assert nat["packed"].dtype == np.uint8
+    assert nat["packed"].tobytes() == ref["packed"].tobytes()
+    # a lane that failed a precheck, and padding, are all zero
+    assert not nat["packed"][[5, 6, 7]].any()
+    assert not nat["packed"][200:].any()
 
 
 def test_native_mod_l_against_python_ints():
@@ -68,10 +72,12 @@ def test_native_mod_l_against_python_ints():
         k = int.from_bytes(
             hashlib.sha512(sigs[i][:32] + pubs[i] + msgs[i]).digest(),
             "little") % E.L
-        # prepare_batch emits SIGNED radix-16 digits in [−8, 8); the
-        # recode must preserve the value exactly
-        digs = nat["k_nibs"][i]
-        assert (digs >= -8).all() and (digs < 8).all(), i
+        # the k field holds k + 0x88…88: nibble − 8 is the SIGNED
+        # radix-16 digit in [−8, 8), and the digits' value is k exactly
+        field = nat["packed"][i, 96:128]
+        assert int.from_bytes(field.tobytes(), "little") == \
+            k + E.RECODE_BIAS, i
+        digs = E.bytes_to_nibs_np(field) - 8
         got = sum(int(digs[j]) << (4 * j) for j in range(64))
         assert got == k, i
 

@@ -122,6 +122,51 @@ def test_per_device_drain_attribution(devices):
     assert j["drains"]["by_backend"]["tpu"]["sigs"] == 100
 
 
+@pytest.mark.parametrize("ndev", [1, 4])
+def test_one_dispatch_hands_the_device_one_packed_array(devices, ndev):
+    """The input contract at the boundary (ISSUE 26): a dispatch stages
+    ONE uint8 array of 128 × bucket bytes and launches with it as the
+    only argument, on the single-device and the dp-sharded route."""
+    st = VerifierStats(metrics=MetricsRegistry())
+    v = _fleet_verifier(devices, ndev, stats=st)
+    puts, calls = [], []
+    real_put, real_single, real_mesh = \
+        v._device_arg, v._single_fn, v._mesh_fn
+
+    def device_arg(packed, idxs):
+        puts.append((packed.dtype, packed.shape, idxs))
+        return real_put(packed, idxs)
+
+    def counting(fn):
+        def call(*args):
+            calls.append(args)
+            return fn(*args)
+        return call
+
+    v._device_arg = device_arg
+    v._single_fn = lambda: counting(real_single())
+    v._mesh_fn = lambda idxs: (counting(real_mesh(idxs)[0]),
+                               real_mesh(idxs)[1])
+    try:
+        triples = _corrupt(_batch(100), {7})
+        assert v.verify_many(triples) == [i != 7 for i in range(100)]
+    finally:
+        del v._device_arg, v._single_fn, v._mesh_fn
+    assert puts == [(np.uint8, (128, 128), tuple(range(ndev)))]
+    assert len(calls) == 1 and len(calls[0]) == 1
+    arg, = calls[0]
+    assert arg.dtype == np.uint8 and arg.nbytes == 128 * 128
+    # one transfer: committed to the device, or the lanes split over dp
+    # on the batch axis, 128 bytes each
+    assert {s.data.shape for s in arg.addressable_shards} == \
+        {(128 // ndev, 128)}
+    assert len(arg.addressable_shards) == ndev
+    assert st.h2d_bytes == 128 * 128
+    assert st.metrics.to_json()["verifier.h2d.bytes"]["count"] == 128 * 128
+    assert v.verify_many(triples[:3]) == [True] * 3
+    assert st.h2d_bytes == 2 * 128 * 128
+
+
 # --------------------------------------------------- scheduler logic (stubs)
 
 
@@ -157,12 +202,12 @@ class _StubbedFleet(TpuSigVerifier):
 
     def _mesh_fn(self, idxs):
         self._mesh_fns.setdefault(idxs, (None, None))
-        return (lambda *args: self._Lazy(np.ones(len(args[0]), bool),
-                                         self._dispatch_sleep_s)), None
+        return (lambda packed: self._Lazy(np.ones(len(packed), bool),
+                                          self._dispatch_sleep_s)), None
 
     def _single_fn(self):
-        return lambda *args: self._Lazy(np.ones(len(args[0]), bool),
-                                        self._dispatch_sleep_s)
+        return lambda packed: self._Lazy(np.ones(len(packed), bool),
+                                         self._dispatch_sleep_s)
 
     def _stage_chunk(self, chunk, route):
         import time
@@ -171,9 +216,9 @@ class _StubbedFleet(TpuSigVerifier):
             time.sleep(self._stage_sleep_s)
         fn, b, idxs = route
         prep = prepare_batch([t[0] for t in chunk], [t[1] for t in chunk],
-                             [t[2] for t in chunk])
-        pad = np.zeros((b,), np.int32)
-        return {"args": (pad,), "pre_ok": prep["pre_ok"],
+                             [t[2] for t in chunk], size=b)
+        # the host array stands in for the device's: same contract
+        return {"arg": prep["packed"], "pre_ok": prep["pre_ok"],
                 "n": len(chunk), "b": b, "fn": fn, "idxs": idxs}
 
 

@@ -21,7 +21,10 @@ inside its `crypto.device_wait`: launch latency (`crypto.launch` start →
 module start on the device) and readback latency (module end →
 `crypto.device_wait` end); and with --ops N the N longest device ops
 with the `op_name` of their HLO metadata (the `jax.named_scope`s of
-ops/ed25519.py show there once the executable was compiled with them).
+ops/ed25519.py show there for an executable that was compiled with op
+trace marks: the served verify executables are not,
+parallel/device.py::verify_compile_options; the six-argument
+`verify_batch_jit` is).
 """
 
 from __future__ import annotations
