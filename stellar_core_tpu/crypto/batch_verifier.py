@@ -563,6 +563,9 @@ class BatchSigVerifier:
     metrics = None
     faults = None
     stats = None
+    # the verdict cache in front of the backend: the process-wide one
+    # unless make_verifier gave the stack its own (keys.VerdictCache)
+    cache = _keys.PROCESS_CACHE
 
     def _span(self, name: str, **tags):
         return tracer_span(self.tracer, name, cat="crypto", **tags)
@@ -602,9 +605,9 @@ class BatchSigVerifier:
                 if cks is None:
                     cks = [_keys._cache_key(k, s, m)
                            for (k, s, m) in triples]
-                with _keys._cache_lock:
+                with self.cache.lock:
                     for i, (t, ck) in enumerate(zip(triples, cks)):
-                        hit = _keys._verify_cache.maybe_get(ck)
+                        hit = self.cache.store.maybe_get(ck)
                         if hit is not None:
                             out[i] = hit
                         else:
@@ -612,9 +615,9 @@ class BatchSigVerifier:
             sp.set_tag("cache_hits", len(triples) - len(todo))
             if todo:
                 results = self.verify_many([t for (_i, t, _ck) in todo])
-                with _keys._cache_lock:
+                with self.cache.lock:
                     for ((i, _t, ck), ok) in zip(todo, results):
-                        _keys._verify_cache.put(ck, ok)
+                        self.cache.store.put(ck, ok)
                         out[i] = ok
             return out  # type: ignore[return-value]
 
@@ -631,8 +634,8 @@ class BatchSigVerifier:
     def _batch_enqueue(self, key: PublicKey, sig: bytes,
                        msg: bytes) -> VerifyFuture:
         ck = _keys._cache_key(key.key_bytes, sig, msg)
-        with _keys._cache_lock:
-            hit = _keys._verify_cache.maybe_get(ck)
+        with self.cache.lock:
+            hit = self.cache.store.maybe_get(ck)
         f = VerifyFuture()
         if hit is not None:
             f._complete(hit)
@@ -658,8 +661,8 @@ class BatchSigVerifier:
                         "verifies on CPU fallback", e, len(batch))
             results = _flush_fallback(self, triples)
         for ((k, s, m), f), ok in zip(batch, results):
-            with _keys._cache_lock:
-                _keys._verify_cache.put(_keys._cache_key(k, s, m), ok)
+            with self.cache.lock:
+                self.cache.store.put(_keys._cache_key(k, s, m), ok)
             f._complete(ok)
 
 
@@ -684,7 +687,7 @@ class CpuSigVerifier(BatchSigVerifier):
     def enqueue(self, key: PublicKey, sig: bytes, msg: bytes,
                 cls: str = "tx") -> VerifyFuture:
         f = VerifyFuture()
-        f._complete(_keys.PubKeyUtils.verify_sig(key, sig, msg))
+        f._complete(_keys.verify_cached(self.cache, key, sig, msg))
         return f
 
     def flush(self) -> None:
@@ -1536,8 +1539,8 @@ class ThreadedBatchVerifier(BatchSigVerifier):
     def enqueue(self, key: PublicKey, sig: bytes, msg: bytes,
                 cls: str = "tx") -> VerifyFuture:
         ck = _keys._cache_key(key.key_bytes, sig, msg)
-        with _keys._cache_lock:
-            hit = _keys._verify_cache.maybe_get(ck)
+        with self.cache.lock:
+            hit = self.cache.store.maybe_get(ck)
         f = VerifyFuture()
         if hit is not None:
             f._complete(hit)
@@ -1608,8 +1611,8 @@ class ThreadedBatchVerifier(BatchSigVerifier):
                 lat = (self._metrics.new_timer("crypto.verify.latency")
                        if self._metrics is not None else None)
                 for ((k, s, m), f, t0, _c, _tt), ok in zip(batch, results):
-                    with _keys._cache_lock:
-                        _keys._verify_cache.put(_keys._cache_key(k, s, m), ok)
+                    with self.cache.lock:
+                        self.cache.store.put(_keys._cache_key(k, s, m), ok)
                     if lat is not None:
                         lat.update(done - t0)
                     f._complete(ok)
@@ -1636,8 +1639,11 @@ def make_verifier(backend: str = "cpu", clock=None,
                   metrics=None, tracer=None, faults=None,
                   flight_recorder=None,
                   breaker_threshold: int = 3,
-                  breaker_cooldown: float = 30.0) -> BatchSigVerifier:
+                  breaker_cooldown: float = 30.0,
+                  cache=None) -> BatchSigVerifier:
     """Config-gated backend selection (Config.SIG_VERIFY_BACKEND).
+    `cache` (keys.VerdictCache) is the stack's own verdict cache where
+    Config.VERIFY_CACHE_SCOPE is "node"; None keeps the process-wide one.
 
     Device backends ("tpu", "tpu-async") are always wrapped in a
     ResilientBatchVerifier with a CPU fallback; "cpu-resilient" wraps the
@@ -1651,8 +1657,10 @@ def make_verifier(backend: str = "cpu", clock=None,
     now_fn = clock.now if clock is not None else None
     stats = VerifierStats(metrics=metrics, tracer=tracer, now_fn=now_fn,
                           flight_recorder=flight_recorder)
+    cache = cache or _keys.PROCESS_CACHE
 
     def resilient(primary: BatchSigVerifier) -> ResilientBatchVerifier:
+        primary.cache = cache
         primary.tracer = tracer
         primary.metrics = metrics
         primary.stats = stats
@@ -1660,6 +1668,7 @@ def make_verifier(backend: str = "cpu", clock=None,
         # fire inside the device backend's route/staging, not just the
         # resilient layer's device.dispatch point
         fb = CpuSigVerifier()
+        fb.cache = cache
         fb.tracer = tracer
         fb.metrics = metrics
         fb.stats = stats
@@ -1668,6 +1677,7 @@ def make_verifier(backend: str = "cpu", clock=None,
             CircuitBreaker(threshold=breaker_threshold,
                            cooldown_s=breaker_cooldown, now_fn=now_fn),
             max_pending=max_pending)
+        r.cache = cache
         r.tracer = tracer
         r.flight_recorder = flight_recorder
         r.stats = stats
@@ -1697,6 +1707,7 @@ def make_verifier(backend: str = "cpu", clock=None,
         v = ThreadedBatchVerifier(inner, clock, metrics=metrics)
     else:
         raise ValueError("unknown sig verify backend %r" % backend)
+    v.cache = cache
     v.tracer = tracer
     v.metrics = metrics
     v.faults = faults

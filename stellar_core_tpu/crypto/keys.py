@@ -42,6 +42,26 @@ _cache_lock = TrackedLock("crypto.verify-cache")
 _verify_cache: RandomEvictionCache = RandomEvictionCache(VERIFY_CACHE_SIZE)
 
 
+class VerdictCache:
+    """A cache of verify verdicts and its lock. `PROCESS_CACHE` is the
+    reference's process-wide gVerifySigCache: every node of a one-process
+    simulation shares it, so a signature is verified once by whichever
+    node sees it first. A node with `VERIFY_CACHE_SCOPE = "node"` gives
+    its verifier stack one of its own, as a node in a process of its own
+    has: it trusts no verdict another node computed."""
+
+    __slots__ = ("lock", "store")
+
+    def __init__(self, lock=None, store=None) -> None:
+        self.lock = TrackedLock("crypto.verify-cache") \
+            if lock is None else lock
+        self.store = RandomEvictionCache(VERIFY_CACHE_SIZE) \
+            if store is None else store
+
+
+PROCESS_CACHE = VerdictCache(_cache_lock, _verify_cache)
+
+
 def _cache_key(key32: bytes, sig: bytes, msg: bytes) -> bytes:
     h = hashlib.sha256()
     h.update(key32)
@@ -50,10 +70,11 @@ def _cache_key(key32: bytes, sig: bytes, msg: bytes) -> bytes:
     return h.digest()
 
 
-def verify_cache_stats() -> dict:
-    with _cache_lock:
-        return {"hits": _verify_cache.hits, "misses": _verify_cache.misses,
-                "size": len(_verify_cache)}
+def verify_cache_stats(cache: Optional[VerdictCache] = None) -> dict:
+    cache = cache or PROCESS_CACHE
+    with cache.lock:
+        return {"hits": cache.store.hits, "misses": cache.store.misses,
+                "size": len(cache.store)}
 
 
 def flush_verify_cache() -> None:
@@ -157,20 +178,25 @@ def raw_verify_batch(triples) -> list:
     return [raw_verify(k, s, m) for (k, s, m) in triples]
 
 
+def verify_cached(cache: VerdictCache, key: PublicKey, sig: bytes,
+                  msg: bytes) -> bool:
+    ck = _cache_key(key.key_bytes, sig, msg)
+    with cache.lock:
+        got = cache.store.maybe_get(ck)
+    if got is not None:
+        return got
+    ok = raw_verify(key.key_bytes, sig, msg)
+    with cache.lock:
+        cache.store.put(ck, ok)
+    return ok
+
+
 class PubKeyUtils:
     @staticmethod
     def verify_sig(key: PublicKey, sig: bytes, msg: bytes) -> bool:
         """Cached verify — the L0 in front of any batch backend
         (reference SecretKey.cpp:310-337)."""
-        ck = _cache_key(key.key_bytes, sig, msg)
-        with _cache_lock:
-            got = _verify_cache.maybe_get(ck)
-        if got is not None:
-            return got
-        ok = raw_verify(key.key_bytes, sig, msg)
-        with _cache_lock:
-            _verify_cache.put(ck, ok)
-        return ok
+        return verify_cached(PROCESS_CACHE, key, sig, msg)
 
     @staticmethod
     def get_hint(key: PublicKey) -> bytes:

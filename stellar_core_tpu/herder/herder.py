@@ -360,9 +360,17 @@ class Herder:
 
     # -- state machine -------------------------------------------------------
     def bootstrap(self) -> None:
-        """FORCE_SCP start (reference Herder::bootstrap)."""
+        """FORCE_SCP start (reference Herder::bootstrap). A watcher
+        (NODE_IS_VALIDATOR off) has no slot of its own to start from: it
+        tracks from the first value its quorum externalizes. Until then
+        the stuck timer runs, so one that hears nothing goes looking
+        (out_of_sync_recovery asks its peers for their SCP state)."""
         cfg = self.app.config
         assert cfg.FORCE_SCP
+        if not cfg.NODE_IS_VALIDATOR:
+            self.app.ledger_manager.state = 1  # synced
+            self.track_heartbeat()
+            return
         self.set_tracking(self.app.ledger_manager.last_closed_ledger_num())
         self.app.ledger_manager.state = 1  # synced
         if not cfg.MANUAL_CLOSE:
@@ -387,8 +395,11 @@ class Herder:
 
     def set_tracking(self, slot: int) -> None:
         was_recovering = self.recovery_started_at is not None
+        began = self.state != HerderState.HERDER_TRACKING_STATE
         self.state = HerderState.HERDER_TRACKING_STATE
         self.tracking_slot = slot
+        if began:
+            self._sync_changed()
         if was_recovering:
             # a recovery episode ends the moment consensus tracks again:
             # stop the poll, stamp time-to-tracking (the scenario suite's
@@ -408,6 +419,13 @@ class Herder:
             log.info("consensus sync recovered at slot %d after %.3fs",
                      slot, dt)
         self.track_heartbeat()
+
+    def _sync_changed(self) -> None:
+        """Tell the application the herder began or stopped tracking
+        (a watcher's APP_SYNCED follows it; stub apps have no hook)."""
+        note = getattr(self.app, "herder_sync_changed", None)
+        if note is not None:
+            note(self.state == HerderState.HERDER_TRACKING_STATE)
 
     def track_heartbeat(self) -> None:
         cfg = self.app.config
@@ -429,6 +447,7 @@ class Herder:
                           extra={"tracking_slot": self.tracking_slot,
                                  "state": "syncing"})
         self.state = HerderState.HERDER_SYNCING_STATE
+        self._sync_changed()
         tl = getattr(self.app, "slot_timeline", None)
         if tl is not None:
             tl.record(self.current_slot(), "recovery.lost-sync",
@@ -643,18 +662,21 @@ class Herder:
             while len(t0s) > self.LEDGER_VALIDITY_BRACKET + 2:
                 del t0s[min(t0s)]
 
-    def recv_transaction(self, frame) -> int:
+    def recv_transaction(self, frame, origin: str = "local") -> int:
         """HOT CALLER #2 via TransactionQueue.try_add → checkValid.
         The ingress tier (ISSUE 18) decides first: a throttled or shed
         tx returns TRY_AGAIN_LATER *before* any signature validation is
         paid, with `last_retry_after` carrying the hint `cmd_tx`
-        surfaces to the submitter."""
-        with app_span(self.app, "herder.admit", cat="herder") as sp:
-            status = self._admit(frame)
+        surfaces to the submitter. `origin` is "local" (submitted to
+        this node) or "flood" (received from a peer); both take the same
+        path, on a validator and on a watcher alike."""
+        with app_span(self.app, "herder.admit", cat="herder",
+                      origin=origin) as sp:
+            status = self._admit(frame, origin)
             sp.set_tag("status", status)
             return status
 
-    def _admit(self, frame) -> int:
+    def _admit(self, frame, origin: str) -> int:
         m = self._metrics()
         if m is not None:
             m.new_meter("herder.tx.received").mark()
@@ -682,6 +704,10 @@ class Herder:
                 # open-loop submitters treat it as accepted)
                 return TxQueueResult.ADD_STATUS_PENDING
         status = self._queue_tx(frame, h, fresh)
+        if status == TxQueueResult.ADD_STATUS_PENDING and m is not None:
+            # admitted, by how it came: once a transaction, however many
+            # copies of it the flood delivers
+            m.new_meter("herder.tx.received.%s" % origin).mark()
         if status == TxQueueResult.ADD_STATUS_TRY_AGAIN_LATER:
             # pool-side backpressure (source limit / fee floor): a close
             # drains the pool, so that is the honest retry horizon
@@ -945,6 +971,14 @@ class Herder:
         if ledger_seq_to_trigger != slot:
             log.debug("stale trigger for %d (slot %d)",
                       ledger_seq_to_trigger, slot)
+            return
+        if not cfg.NODE_IS_VALIDATOR:
+            # reference HerderImpl::triggerNextLedger: "Non-validating
+            # node, skipping ledger triggering". What a watcher queued
+            # leaves its queue when a ledger it did not propose applies
+            # it; only the parked intake still needs its pump.
+            if self.ingress is not None:
+                self.ingress.pump()
             return
         tracer = app_tracer(self.app)
         if tracer is not None:
@@ -1228,6 +1262,7 @@ class Herder:
     def get_json_info(self) -> dict:
         return {
             "you": self.app.config.NODE_SEED.strkey_public(),
+            "validating": self.app.config.NODE_IS_VALIDATOR,
             "state": ("tracking" if self.state ==
                       HerderState.HERDER_TRACKING_STATE else "syncing"),
             "slot": self.tracking_slot,

@@ -14,7 +14,7 @@ from typing import Optional
 
 from ..crypto.batch_verifier import make_verifier
 from ..crypto.hashing import sha256
-from ..crypto.keys import SecretKey
+from ..crypto.keys import SecretKey, VerdictCache
 from ..database.database import Database
 from ..invariant.invariants import InvariantManager
 from ..ledger.ledger_manager import LedgerManager
@@ -126,13 +126,18 @@ class Application:
 
         # crypto backend (config-gated; the TPU boundary); device
         # backends sit behind a circuit breaker with a CPU fallback
+        if config.VERIFY_CACHE_SCOPE not in ("process", "node"):
+            raise ValueError("VERIFY_CACHE_SCOPE %r"
+                             % (config.VERIFY_CACHE_SCOPE,))
         self.sig_verifier = make_verifier(
             config.SIG_VERIFY_BACKEND, clock,
             config.SIG_VERIFY_MAX_BATCH,
             metrics=self.metrics, tracer=self.tracer,
             faults=self.faults, flight_recorder=self.flight_recorder,
             breaker_threshold=config.SIG_VERIFY_BREAKER_THRESHOLD,
-            breaker_cooldown=config.SIG_VERIFY_BREAKER_COOLDOWN)
+            breaker_cooldown=config.SIG_VERIFY_BREAKER_COOLDOWN,
+            cache=VerdictCache()
+            if config.VERIFY_CACHE_SCOPE == "node" else None)
 
         # batched SHA-256 boundary (crypto/batch_hasher.py, ISSUE 12):
         # the hashing twin of the verifier — config-gated device
@@ -368,11 +373,23 @@ class Application:
         force = self.config.FORCE_SCP or (
             self.persistent_state is not None and
             self.persistent_state.get_force_scp())
-        if force and self.config.NODE_IS_VALIDATOR:
+        # a watcher under FORCE_SCP bootstraps too, and is in sync once
+        # its herder tracks its quorum (herder_sync_changed)
+        self.state = AppState.APP_ACQUIRING_CONSENSUS
+        if force:
             self.herder.bootstrap()
-            self.state = AppState.APP_SYNCED
-        else:
-            self.state = AppState.APP_ACQUIRING_CONSENSUS
+            if self.config.NODE_IS_VALIDATOR:
+                self.state = AppState.APP_SYNCED
+
+    def herder_sync_changed(self, tracking: bool) -> None:
+        """A watcher (NODE_IS_VALIDATOR off) takes no part in consensus:
+        it is in sync exactly while its herder tracks the values its
+        quorum externalizes, so `info`, `tx` and the maintainer see a
+        node in sync. A validator's state is set at start, as before."""
+        if not self.config.NODE_IS_VALIDATOR and self.state in (
+                AppState.APP_ACQUIRING_CONSENSUS, AppState.APP_SYNCED):
+            self.state = AppState.APP_SYNCED if tracking \
+                else AppState.APP_ACQUIRING_CONSENSUS
 
     def crank(self, block: bool = False) -> int:
         n = self.clock.crank(block)

@@ -130,10 +130,12 @@ class CommandHandler:
         # crypto-boundary metrics live outside the registry (global cache,
         # per-verifier counters); merge them in medida-style names
         from ..crypto import keys as _keys
-        cache = _keys.verify_cache_stats()
+        v = getattr(self.app, "sig_verifier", None)
+        # the cache in front of this node's verifier stack: the
+        # process-wide one unless VERIFY_CACHE_SCOPE is "node"
+        cache = _keys.verify_cache_stats(getattr(v, "cache", None))
         out["crypto.verify.cache-hit"] = {"count": cache["hits"]}
         out["crypto.verify.cache-miss"] = {"count": cache["misses"]}
-        v = getattr(self.app, "sig_verifier", None)
         inner = getattr(v, "inner", v)
         if inner is not None and hasattr(inner, "batches_dispatched"):
             out["crypto.verify.batch-dispatch"] = {
@@ -193,7 +195,8 @@ class CommandHandler:
             "pending": v.pending(),
         }
         from ..crypto import keys as _keys
-        out["cache"] = _keys.verify_cache_stats()
+        out["cache"] = dict(_keys.verify_cache_stats(v.cache),
+                            scope=self.app.config.VERIFY_CACHE_SCOPE)
         return out
 
     def cmd_hasher(self, params) -> dict:

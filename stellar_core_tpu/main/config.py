@@ -153,6 +153,11 @@ class Config:
         # "cpu" (default, OpenSSL), "tpu" (JAX batched), "tpu-async"
         self.SIG_VERIFY_BACKEND = "cpu"
         self.SIG_VERIFY_MAX_BATCH = 8192
+        # "process": verify verdicts are cached process-wide (the
+        # reference's gVerifySigCache; nodes of a one-process simulation
+        # share them). "node": this node's verifier stack keeps a cache
+        # of its own, as a node in a process of its own has.
+        self.VERIFY_CACHE_SCOPE = "process"
         # AOT-compile all kernel bucket shapes at startup (background
         # thread) so no lazy compile lands on the consensus path
         self.SIG_VERIFY_WARMUP = True
@@ -254,7 +259,8 @@ class Config:
             "CONSENSUS_STUCK_TIMEOUT_SECONDS", "LEDGER_VALIDITY_BRACKET",
             "INVARIANT_CHECKS", "WORKER_THREADS",
             "MAX_CONCURRENT_SUBPROCESSES", "SIG_VERIFY_BACKEND",
-            "SIG_VERIFY_MAX_BATCH", "TRACE_ENABLED", "TRACE_CAPACITY",
+            "SIG_VERIFY_MAX_BATCH", "VERIFY_CACHE_SCOPE",
+            "TRACE_ENABLED", "TRACE_CAPACITY",
             "SLOT_TIMELINE_SLOTS", "PROPAGATION_STATS_ENABLED",
             "NODE_NAME",
             "FLIGHT_RECORDER_DIR", "CHECKPOINT_FREQUENCY",

@@ -19,6 +19,7 @@ from typing import Optional
 from ..crypto.hashing import hmac_sha256, hmac_sha256_verify, sha256
 from ..util import rnd
 from ..util.log import get_logger
+from ..util.tracing import app_span
 from ..xdr import (
     Auth, AuthenticatedMessage, AuthenticatedMessageV0, DontHave, Error,
     ErrorCode, Hello, MessageType, PeerAddress, SCPQuorumSet, StellarMessage,
@@ -295,18 +296,25 @@ class Peer:
                 # over the per-peer flood rate: dropped before any
                 # validation or relay (docs/robustness.md#flood-control)
                 return
-            self.overlay.recv_flooded_msg(msg, self)
-            from ..transactions.transaction_frame import TransactionFrame
-            frame = TransactionFrame.make_from_wire(
-                self.app.config.network_id, msg.value)
-            status = herder.recv_transaction(frame)
-            if status == 0:
-                self.overlay.broadcast_message(msg)
-            elif status == 3:
-                # ingress backpressure on a relayed tx: not relayed
-                # further, and the sender scores a fractional flood-ban
-                # point (docs/robustness.md#ingress--overload)
-                self.overlay.flood_backpressure(self)
+            # flood-receive admission: decode, the herder's admission
+            # (`herder.admit` is this span's child) and the relay
+            with app_span(self.app, "overlay.recv_tx",
+                          cat="overlay") as sp:
+                self.overlay.recv_flooded_msg(msg, self)
+                from ..transactions.transaction_frame import \
+                    TransactionFrame
+                frame = TransactionFrame.make_from_wire(
+                    self.app.config.network_id, msg.value)
+                status = herder.recv_transaction(frame, origin="flood")
+                sp.set_tag("status", status)
+                if status == 0:
+                    self.overlay.broadcast_message(msg)
+                elif status == 3:
+                    # ingress backpressure on a relayed tx: not relayed
+                    # further, and the sender scores a fractional
+                    # flood-ban point (docs/robustness.md
+                    # #ingress--overload)
+                    self.overlay.flood_backpressure(self)
         elif t == MessageType.GET_SCP_QUORUMSET:
             q = self._lookup_qset(msg.value)
             if q is not None:

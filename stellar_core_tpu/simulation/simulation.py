@@ -103,8 +103,10 @@ class Simulation:
     # -- topology -----------------------------------------------------------
     def add_node(self, secret: SecretKey, qset: SCPQuorumSet,
                  name: Optional[str] = None,
-                 cfg_tweak: Optional[Callable[[Config], None]] = None
-                 ) -> SimNode:
+                 cfg_tweak: Optional[Callable[[Config], None]] = None,
+                 is_validator: bool = True) -> SimNode:
+        """`is_validator=False` builds a watcher: it follows `qset`
+        (which does not name it) and emits no SCP envelope."""
         name = name or secret.strkey_public()[:5]
         cfg = Config()
         cfg.NETWORK_PASSPHRASE = self.network_passphrase
@@ -112,7 +114,7 @@ class Simulation:
         # sim node name flows into flight-recorder filenames and the
         # fleet aggregator's process lanes
         cfg.NODE_NAME = name
-        cfg.NODE_IS_VALIDATOR = True
+        cfg.NODE_IS_VALIDATOR = is_validator
         cfg.QUORUM_SET = qset
         cfg.UNSAFE_QUORUM = True
         cfg.RUN_STANDALONE = True   # no real overlay sockets
@@ -356,7 +358,7 @@ class Simulation:
             from ..transactions.transaction_frame import TransactionFrame
             frame = TransactionFrame.make_from_wire(
                 app.config.network_id, msg.value)
-            app.herder.recv_transaction(frame)
+            app.herder.recv_transaction(frame, origin="flood")
             app.overlay_manager.rebroadcast(msg, frm)
         elif t == MessageType.TX_SET:
             from ..herder.txset import TxSetFrame

@@ -22,13 +22,20 @@ def _keys(n: int, tag: bytes) -> List[SecretKey]:
 def core(n: int, threshold: int,
          passphrase: str = "(sct) simulation network",
          mode: int = Simulation.OVER_LOOPBACK,
-         cfg_tweak=None) -> Simulation:
-    """Fully-connected core of n validators all trusting each other."""
+         cfg_tweak=None, watchers: int = 0) -> Simulation:
+    """Fully-connected core of n validators all trusting each other.
+    `watchers` non-validating nodes (reference docs/software/admin.md:
+    NODE_IS_VALIDATOR=false, what a Horizon submits through) are built
+    FIRST, so `sim.nodes` lists them before the validators; each follows
+    the validators' own quorum set and is linked to every validator."""
     sim = Simulation(mode=mode, network_passphrase=passphrase)
     keys = _keys(n, b"core")
     qset = SCPQuorumSet(threshold=threshold,
                         validators=[k.public_key for k in keys],
                         innerSets=[])
+    followers = [sim.add_node(k, qset, cfg_tweak=cfg_tweak,
+                              is_validator=False).name
+                 for k in _keys(watchers, b"watcher")]
     names = []
     for k in keys:
         node = sim.add_node(k, qset, cfg_tweak=cfg_tweak)
@@ -36,6 +43,9 @@ def core(n: int, threshold: int,
     for i in range(n):
         for j in range(i + 1, n):
             sim.connect(names[i], names[j])
+    for w in followers:
+        for v in names:
+            sim.connect(w, v)
     return sim
 
 

@@ -237,3 +237,79 @@ def test_purge_slots():
     scp.purge_slots(3)
     assert scp.get_slot(1, False) is None
     assert scp.get_slot(3, False) is not None
+
+
+# ---------------------------------------------------------------- followers
+# A watcher's SCP (is_validator False, reference SCP.h isValidator): it
+# follows its quorum's statements to externalize and sends none.
+
+def _with_follower(n: int, threshold: int):
+    """A network of validators plus one follower, outside every quorum
+    set, that is handed every envelope the validators emit."""
+    net = TestNetwork(n, threshold)
+    q = next(iter(net.qsets.values()))
+    d = TestDriver(net, "follower")
+    net.drivers["follower"] = d
+    net.nodes["follower"] = SCP(d, nid(99), False, q)
+    return net, net.nodes["follower"], d
+
+
+def test_follower_externalizes_what_its_quorum_does_and_emits_nothing():
+    from stellar_core_tpu.scp.driver import SCPTimerID
+    net, follower, d = _with_follower(3, 2)
+    for name in ("n1", "n2", "n3"):
+        net.nodes[name].nominate(1, b"V", b"prev")
+        net.deliver_all()
+    net.deliver_all(200)
+    assert net.externalized_values(1) == [b"V"] * 4
+    slot = follower.get_slot(1, False)
+    assert not slot.fully_validated
+    assert slot.ballot.phase == 2 and slot.externalized_value() == b"V"
+    assert d.emitted == []
+    # its own statements are kept for its quorum math and never sent
+    assert slot.ballot.last_envelope is not None
+    assert slot.ballot.last_envelope_emit is None
+    assert slot.get_latest_messages_send() == []
+    # nomination never started: no round, no leader, no vote, no timer
+    nom = slot.nomination
+    assert not nom.nomination_started and nom.round_number == 0
+    assert not nom.votes and not nom.round_leaders
+    assert nom.last_envelope is None
+    assert len(nom.latest_nominations) == 3
+    assert SCPTimerID.NOMINATION not in d.timers
+    assert SCPTimerID.BALLOT not in d.timers     # cancelled on externalize
+
+
+def test_follower_may_not_nominate():
+    _net, follower, d = _with_follower(3, 2)
+    with pytest.raises(AssertionError):
+        follower.nominate(1, b"V", b"prev")
+    assert d.emitted == [] and d.timers == {}
+
+
+def test_follower_ballot_timer_bumps_its_counter_and_sends_nothing():
+    """With its quorum stuck at PREPARE(1, V) the follower hears from a
+    quorum and arms its ballot timer like any node; the fire moves its
+    own counter and reaches nobody."""
+    from stellar_core_tpu.scp.driver import SCPTimerID
+    from stellar_core_tpu.xdr import (
+        SCPBallot, SCPPledges, SCPPrepare, SCPStatement, SCPStatementType,
+    )
+    net, follower, d = _with_follower(3, 2)
+    qh = next(iter(net.qsets))
+    b1 = SCPBallot(counter=1, value=b"V")
+    for i in (1, 2):
+        st = SCPStatement(
+            nodeID=nid(i), slotIndex=1, pledges=SCPPledges(
+                SCPStatementType.SCP_ST_PREPARE,
+                SCPPrepare(quorumSetHash=qh, ballot=b1, prepared=b1,
+                           preparedPrime=None, nC=0, nH=0)))
+        assert follower.receive_envelope(
+            SCPEnvelope(statement=st, signature=b"")) == 1
+    slot = follower.get_slot(1, False)
+    assert slot.ballot.b == (1, b"V") and slot.ballot.phase == 0
+    assert d.heard_quorum and SCPTimerID.BALLOT in d.timers
+    assert d.fire_timer(SCPTimerID.BALLOT)
+    assert slot.ballot.b == (2, b"V")
+    assert d.emitted == [] and net.outbox == []
+    assert d.externalized == {}

@@ -101,7 +101,7 @@ def test_one_admitted_payment_yields_one_span_of_each(tpu_app):
     assert got["crypto.cache_probe"][0].parent == \
         got["crypto.prewarm"][0].sid
     assert got["tx.check_valid"][0].parent == got["txqueue.try_add"][0].sid
-    assert got["herder.admit"][0].tags == {"status": 0}
+    assert got["herder.admit"][0].tags == {"origin": "local", "status": 0}
     assert got["herder.admit"][0].parent == 0
     parts = sum(got[n][0].dur for n in leaves + ["crypto.stage"])
     assert parts <= got["crypto.verify_many"][0].dur
