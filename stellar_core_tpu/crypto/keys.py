@@ -77,6 +77,14 @@ def verify_cache_stats(cache: Optional[VerdictCache] = None) -> dict:
                 "size": len(cache.store)}
 
 
+def count_uncached(cache: VerdictCache, triples) -> int:
+    """Of (key32, sig, msg) triples, those `cache` holds no verdict for.
+    A look, not a probe: it counts no hit and no miss."""
+    cks = [_cache_key(k, s, m) for (k, s, m) in triples]
+    with cache.lock:
+        return sum(1 for ck in cks if ck not in cache.store)
+
+
 def flush_verify_cache() -> None:
     with _cache_lock:
         _verify_cache.clear()

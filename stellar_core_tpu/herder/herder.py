@@ -263,6 +263,11 @@ class Herder:
     OUT_OF_SYNC_RECOVERY_INTERVAL = 2.0
     # newest out-of-bracket externalize-hint slots retained while syncing
     MAX_EXT_HINT_SLOTS = 32
+    # flood-received transactions parked at the most before they drain at
+    # once, and the triples of one shared prewarm: the lanes of the bucket
+    # one admission's dispatch takes (TpuSigVerifier.BUCKETS[0]), so a
+    # drain dispatches no other shape
+    ADMIT_BATCH_LANES = 128
 
     def __init__(self, app) -> None:
         self.app = app
@@ -322,6 +327,10 @@ class Herder:
                 async_intake=cfg.INGRESS_ASYNC_INTAKE,
                 sink=self._queue_tx,
                 shed_cb=lambda h: self.tx_lifecycle.outcome(h, "shed"))
+        # flood-received transactions parked for the next drain, in
+        # arrival order: full hash -> (frame, fresh, on_verdict)
+        self._parked: Dict[bytes, tuple] = {}
+        self._drain_posted = False
         self.upgrades = Upgrades()
         self.state = HerderState.HERDER_SYNCING_STATE
         self.tracking_slot: Optional[int] = None
@@ -663,27 +672,37 @@ class Herder:
                 del t0s[min(t0s)]
 
     def recv_transaction(self, frame, origin: str = "local") -> int:
-        """HOT CALLER #2 via TransactionQueue.try_add → checkValid.
-        The ingress tier (ISSUE 18) decides first: a throttled or shed
-        tx returns TRY_AGAIN_LATER *before* any signature validation is
-        paid, with `last_retry_after` carrying the hint `cmd_tx`
-        surfaces to the submitter. `origin` is "local" (submitted to
-        this node) or "flood" (received from a peer); both take the same
-        path, on a validator and on a watcher alike."""
+        """HOT CALLER #2 via TransactionQueue.try_add → checkValid, the
+        synchronous admission: the caller reads the status (and
+        `frame.result`) from the call. The ingress tier (ISSUE 18)
+        decides first: a throttled or shed tx returns TRY_AGAIN_LATER
+        *before* any signature validation is paid, with
+        `last_retry_after` carrying the hint `cmd_tx` surfaces to the
+        submitter. `origin` is "local" (submitted to this node) or
+        "flood" (received from a peer: the overlay's entry is
+        `recv_flood_transaction`, which comes here only for what the
+        queue answers by hash)."""
         with app_span(self.app, "herder.admit", cat="herder",
                       origin=origin) as sp:
-            status = self._admit(frame, origin)
+            h = frame.full_hash()
+            status, fresh = self._gate(frame, h)
+            if status is None:
+                status = self._admit_gated(frame, h, fresh, origin)
             sp.set_tag("status", status)
             return status
 
-    def _admit(self, frame, origin: str) -> int:
+    def _mark_received(self) -> None:
         m = self._metrics()
         if m is not None:
             m.new_meter("herder.tx.received").mark()
+
+    def _gate(self, frame, h: bytes) -> Tuple[Optional[int], bool]:
+        """What admission decides before any signature is looked at:
+        (status, fresh), status None where the queue is to decide."""
+        self._mark_received()
         # lifecycle stamp: submit at entry, queue on admission — the
         # submit→queue stage is the admission (signature-check) cost. A
         # re-flooded duplicate must not clobber the original's stamps.
-        h = frame.full_hash()
         fresh = self.tx_lifecycle.submit(h)
         self.last_retry_after = None
         ing = self.ingress
@@ -697,23 +716,119 @@ class Herder:
                         h, "shed" if decision == _ing.SHED
                         else "throttled")
                 self.last_retry_after = retry_after
-                return TxQueueResult.ADD_STATUS_TRY_AGAIN_LATER
+                return TxQueueResult.ADD_STATUS_TRY_AGAIN_LATER, fresh
             if decision == _ing.PARKED:
                 # accepted into the bounded intake; the pump delivers it
                 # to the queue at the next trigger (optimistic PENDING —
                 # open-loop submitters treat it as accepted)
-                return TxQueueResult.ADD_STATUS_PENDING
+                return TxQueueResult.ADD_STATUS_PENDING, fresh
+        return None, fresh
+
+    def _admit_gated(self, frame, h: bytes, fresh: bool,
+                     origin: str) -> int:
         status = self._queue_tx(frame, h, fresh)
-        if status == TxQueueResult.ADD_STATUS_PENDING and m is not None:
+        if status == TxQueueResult.ADD_STATUS_PENDING:
             # admitted, by how it came: once a transaction, however many
             # copies of it the flood delivers
-            m.new_meter("herder.tx.received.%s" % origin).mark()
+            m = self._metrics()
+            if m is not None:
+                m.new_meter("herder.tx.received.%s" % origin).mark()
         if status == TxQueueResult.ADD_STATUS_TRY_AGAIN_LATER:
             # pool-side backpressure (source limit / fee floor): a close
             # drains the pool, so that is the honest retry horizon
             self.last_retry_after = \
                 self.app.config.EXPECTED_LEDGER_CLOSE_TIME
         return status
+
+    # -- flood-received admission: parked, then drained together --------------
+    @main_thread_only
+    def recv_flood_transaction(self, frame, on_verdict=None) -> None:
+        """A transaction a peer sent. Nobody reads a status from this
+        call, so its signatures need no dispatch of their own: the frame
+        is parked, and the frames parked in one crank are admitted
+        together by `_drain_parked` after ONE prewarm over all their
+        candidate signatures. `on_verdict(status)` is called once the
+        status is known (the overlay relays on 0), now or from the
+        drain; None for the status says the admission raised.
+
+        What is answered now and takes no lane: a hash the queue knows
+        or has banned, a frame the ingress tier throttles, sheds or
+        takes into its own intake. A copy of a parked frame is counted
+        and dropped: the first one's verdict stands for it."""
+        h = frame.full_hash()
+        if h in self._parked:
+            self._mark_received()
+            return
+        if self.tx_queue.answers_by_hash(h):
+            status = self.recv_transaction(frame, origin="flood")
+        else:
+            status, fresh = self._gate(frame, h)
+            if status is None:
+                self._parked[h] = (frame, fresh, on_verdict)
+                if len(self._parked) >= self.ADMIT_BATCH_LANES:
+                    self._drain_parked()
+                elif not self._drain_posted:
+                    # runs at the next crank, after every delivery that
+                    # is queued behind this one (the clock runs a
+                    # snapshot of its queue)
+                    self._drain_posted = True
+                    self.app.clock.post(self._posted_drain)
+                return
+        if on_verdict is not None:
+            on_verdict(status)
+
+    def _posted_drain(self) -> None:
+        self._drain_posted = False
+        self._drain_parked()
+
+    def _drain_parked(self) -> None:
+        """Admit the parked frames in arrival order, each exactly as
+        `recv_transaction` would after its gate, behind shared
+        dispatches of their candidate signatures. Correctness never
+        depends on the warm: a frame whose candidates changed since (a
+        ledger closed, an earlier frame of the drain changed its
+        account's queue) pays its own dispatch in `try_add`."""
+        parked, self._parked = self._parked, {}
+        if not parked:
+            return
+        with app_span(self.app, "herder.admit_batch", cat="herder",
+                      n=len(parked)) as sp:
+            triples = dispatched = 0
+            try:
+                triples, dispatched = self.tx_queue.prewarm_frames(
+                    [e[0] for e in parked.values()], self.ADMIT_BATCH_LANES)
+            except Exception:   # noqa: BLE001 — peer input is hostile,
+                # and the clock's crank must go on: each frame then pays
+                # its own dispatch below
+                log.warning("shared prewarm of %d parked transactions "
+                            "failed", len(parked), exc_info=True)
+            sp.set_tag("triples", triples)
+            sp.set_tag("dispatched", dispatched)
+            m = self._metrics()
+            if m is not None:
+                m.new_histogram("herder.admit_batch.size").update(
+                    len(parked))
+                if dispatched:
+                    m.new_histogram(
+                        "herder.admit_batch.dispatched").update(dispatched)
+            for h, (frame, fresh, on_verdict) in parked.items():
+                try:
+                    with app_span(self.app, "herder.admit", cat="herder",
+                                  origin="flood") as asp:
+                        status = self._admit_gated(frame, h, fresh, "flood")
+                        asp.set_tag("status", status)
+                except Exception:   # noqa: BLE001 — as Peer.recv would
+                    log.warning("admission of a flooded transaction "
+                                "raised", exc_info=True)
+                    status = None
+                if on_verdict is not None:
+                    try:
+                        on_verdict(status)
+                    except Exception:   # noqa: BLE001 — a relay or a
+                        # send that raises costs the frames parked behind
+                        # it nothing
+                        log.warning("the verdict callback of a flooded "
+                                    "transaction raised", exc_info=True)
 
     def _queue_tx(self, frame, h: bytes, fresh: bool) -> int:
         """Queue-admission tail shared by the direct path and the

@@ -6,7 +6,7 @@ applied?" by stamping each locally-received transaction at four
 boundaries, all on the injected app clock (sctlint D1 — virtual-clock
 simulations stay deterministic):
 
-    submit      Herder.recv_transaction entry (HTTP `tx` or overlay flood)
+    submit      Herder._gate (an HTTP `tx`'s call, a flooded one's receipt)
     queue       TransactionQueue.try_add admission (signature checks paid)
     include     txset construction at nomination (trigger_next_ledger)
     externalize the slot's value externalizing

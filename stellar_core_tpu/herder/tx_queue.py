@@ -86,6 +86,35 @@ class TransactionQueue:
     def pool_cap_ops(self) -> int:
         return self._ledger.header().maxTxSetSize * self.pool_multiplier
 
+    def answers_by_hash(self, tx_hash: bytes) -> bool:
+        """Would `try_add` answer from the hash alone (a duplicate, a
+        banned transaction), before any signature is paid for?"""
+        return tx_hash in self._known_hashes or self.is_banned(tx_hash)
+
+    def prewarm_frames(self, frames, lanes: int) -> Tuple[int, int]:
+        """Shared dispatches for the candidate signatures of SEVERAL
+        frames (the herder's drain of flood-received transactions): one
+        `prewarm_many` per `lanes` triples, so no shape is dispatched
+        that one admission alone would not dispatch. Each frame's own
+        prewarm in `try_add` then completes off the verdict cache; one
+        whose candidates were not among these pays its own dispatch
+        there. Returns (triples, of those not cached before); (0, 0)
+        on a verifier that wants no prewarm."""
+        v = self.verifier
+        if not getattr(v, "wants_prewarm", False):
+            return 0, 0
+        from ..crypto.keys import count_uncached
+        from ..transactions.transaction_frame import frames_sig_triples
+        ltx = LedgerTxn(self._ledger.ltx_root())
+        try:
+            triples = frames_sig_triples(ltx, frames)
+        finally:
+            ltx.rollback()
+        uncached = count_uncached(v.cache, triples)
+        for lo in range(0, len(triples), lanes):
+            v.prewarm_many(triples[lo:lo + lanes])
+        return len(triples), uncached
+
     # -- add ----------------------------------------------------------------
     @main_thread_only
     def try_add(self, frame) -> int:

@@ -254,6 +254,31 @@ class Peer:
             log.warning("error handling %d from %s: %s", t, self.id_str(), e)
             self.drop("internal error handling message")
 
+    def _tx_verdict(self, msg: StellarMessage, status) -> None:
+        """The herder's verdict on a transaction this peer sent: at
+        receipt where the queue or the ingress tier answers at once,
+        else from the herder's drain, by when this peer may be gone. A
+        transaction is never relayed before its signatures verified. A
+        relay that raises costs this peer, as it did inside `recv`."""
+        try:
+            if status == 0:
+                # the flood record made at receipt keeps it from its
+                # senders
+                self.overlay.broadcast_message(msg)
+            elif self.dropped:
+                return
+            elif status == 3:
+                # ingress backpressure on a relayed tx: not relayed
+                # further, and the sender scores a fractional flood-ban
+                # point (docs/robustness.md#ingress--overload)
+                self.overlay.flood_backpressure(self)
+            elif status is None:
+                self.drop("internal error handling message")
+        except Exception as e:       # noqa: BLE001 — as in `recv`
+            log.warning("error relaying a transaction from %s: %s",
+                        self.id_str(), e)
+            self.drop("internal error handling message")
+
     def _dispatch(self, msg: StellarMessage) -> None:
         t = msg.disc
         if t == MessageType.HELLO:
@@ -296,25 +321,18 @@ class Peer:
                 # over the per-peer flood rate: dropped before any
                 # validation or relay (docs/robustness.md#flood-control)
                 return
-            # flood-receive admission: decode, the herder's admission
-            # (`herder.admit` is this span's child) and the relay
-            with app_span(self.app, "overlay.recv_tx",
-                          cat="overlay") as sp:
+            # flood-receive: the flood record and the decode happen here;
+            # the herder parks the frame and admits what one crank
+            # delivered together (`herder.admit_batch`), then calls back
+            # with each verdict for the relay
+            with app_span(self.app, "overlay.recv_tx", cat="overlay"):
                 self.overlay.recv_flooded_msg(msg, self)
                 from ..transactions.transaction_frame import \
                     TransactionFrame
                 frame = TransactionFrame.make_from_wire(
                     self.app.config.network_id, msg.value)
-                status = herder.recv_transaction(frame, origin="flood")
-                sp.set_tag("status", status)
-                if status == 0:
-                    self.overlay.broadcast_message(msg)
-                elif status == 3:
-                    # ingress backpressure on a relayed tx: not relayed
-                    # further, and the sender scores a fractional
-                    # flood-ban point (docs/robustness.md
-                    # #ingress--overload)
-                    self.overlay.flood_backpressure(self)
+                herder.recv_flood_transaction(
+                    frame, lambda status: self._tx_verdict(msg, status))
         elif t == MessageType.GET_SCP_QUORUMSET:
             q = self._lookup_qset(msg.value)
             if q is not None:

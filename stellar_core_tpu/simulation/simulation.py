@@ -358,7 +358,7 @@ class Simulation:
             from ..transactions.transaction_frame import TransactionFrame
             frame = TransactionFrame.make_from_wire(
                 app.config.network_id, msg.value)
-            app.herder.recv_transaction(frame, origin="flood")
+            app.herder.recv_flood_transaction(frame)
             app.overlay_manager.rebroadcast(msg, frm)
         elif t == MessageType.TX_SET:
             from ..herder.txset import TxSetFrame

@@ -111,6 +111,52 @@ def test_one_admitted_payment_yields_one_span_of_each(tpu_app):
         s.cause == 0 for s in spans)
 
 
+def test_a_drain_of_flood_received_payments_yields_one_batch_span(tpu_app):
+    """ISSUE 28: the frames one crank delivered are admitted under ONE
+    `herder.admit_batch`, whose shared prewarm holds the only dispatch;
+    each `herder.admit` under it completes off the verdict cache."""
+    ledger = AppLedgerAdapter(tpu_app)
+    root = ledger.root_account()
+    seq = ledger.seq_num(root.account_id)
+    frames = [root.tx([root.op_create_account(
+        SecretKey.from_seed(bytes([0x70 + i]) * 32).public_key, 10 ** 9)],
+        seq=seq + 1 + i) for i in range(3)]
+    verdicts = []
+    sizes = tpu_app.metrics.new_histogram("herder.admit_batch.size")
+    shared = tpu_app.metrics.new_histogram("herder.admit_batch.dispatched")
+    before = (sizes.count, shared.count, shared.total)
+    tracer = fresh_trace(tpu_app)
+    try:
+        for f in frames:
+            tpu_app.herder.recv_flood_transaction(f, verdicts.append)
+        assert verdicts == [] and tracer.spans() == []      # parked
+        assert tpu_app.clock.crank_ready() == 1             # the drain
+    finally:
+        tracer.disable()
+    spans = tracer.spans()
+    tpu_app.manual_close()
+    assert verdicts == [0, 0, 0]
+    got = by_name(tracer)
+    batch, = got["herder.admit_batch"]
+    assert batch.parent == 0
+    assert batch.tags == {"n": 3, "triples": 3, "dispatched": 3}
+    admits = got["herder.admit"]
+    assert [a.parent for a in admits] == [batch.sid] * 3
+    assert all(a.tags == {"origin": "flood", "status": 0} for a in admits)
+    # one dispatch, under the batch's own prewarm and not under an admit
+    dispatch, = got["crypto.dispatch"]
+    up = ancestors(spans, dispatch)
+    assert "herder.admit_batch" in up and "herder.admit" not in up
+    assert dispatch.tags["n"] == 3
+    warm = [p for p in got["crypto.prewarm"] if p.parent == batch.sid]
+    assert len(warm) == 1 and warm[0].tags["cache_hits"] == 0
+    own = [p for p in got["crypto.prewarm"] if p.parent != batch.sid]
+    assert len(own) == 3 and all(p.tags["cache_hits"] == 1 for p in own)
+    assert len(got["txqueue.try_add"]) == len(got["tx.check_valid"]) == 3
+    assert (sizes.count, shared.count, shared.total) == \
+        (before[0] + 1, before[1] + 1, before[2] + 3)
+
+
 def test_staging_worker_names_the_drain_as_its_cause(tpu_app):
     """Chunk 0 is staged inline (`crypto.stage`, on the drain's critical
     path); chunks 1.. on the staging worker (`crypto.stage_ahead`),
@@ -348,6 +394,13 @@ def test_disabled_sites_read_no_clock_and_keep_no_state(tpu_app):
         dest = SecretKey.from_seed(b"e" * 32)
         assert tpu_app.submit_transaction(root.tx(
             [root.op_create_account(dest.public_key, 10 ** 9)])) == 0
+        # and a flood-received one: parked, then drained
+        verdicts = []
+        other = SecretKey.from_seed(b"f" * 32)
+        tpu_app.herder.recv_flood_transaction(root.tx(
+            [root.op_create_account(other.public_key, 10 ** 9)],
+            seq=ledger.seq_num(root.account_id) + 2), verdicts.append)
+        assert tpu_app.clock.crank_ready() == 1 and verdicts == [0]
         tpu_app.manual_close()
     finally:
         tracer._now = real_now
