@@ -153,17 +153,16 @@ def device_full_bench(batch: int = 8192, iters: int = 10) -> dict:
     # stage 2b: the verifier's own warmup over the two shapes stage 1
     # dispatched, through the cockpit that classifies each cache load
     from stellar_core_tpu.crypto.batch_verifier import (
-        TpuSigVerifier, VerifierStats)
-    v = TpuSigVerifier()
+        TpuSigVerifier, VerifierContext, VerifierStats)
+    v = TpuSigVerifier(VerifierContext(stats=VerifierStats()))
     v.BUCKETS = (128, batch)   # instance override; class attr untouched
-    v.stats = VerifierStats()
     jax.clear_caches()     # a fresh process's in-memory state
     v.warmup(wait=True)    # raises if any bucket fails to compile
-    w = v.stats.warmup
+    w = v.ctx.stats.warmup
     results["warmup_state"] = w["state"]
     results["warmup_buckets_s"] = {
         b: info["seconds"] for b, info in w["buckets"].items()}
-    results["compile_cache"] = dict(v.stats.compile_cache)
+    results["compile_cache"] = dict(v.ctx.stats.compile_cache)
     if not results["compile_cache"]["enabled"]:
         raise RuntimeError("no persistent compile cache: %r"
                            % results["compile_cache"])
@@ -507,8 +506,7 @@ def replay_bench(backend: str, n_checkpoints: int = 4,
             app.sig_verifier.prewarm_many = timed_prewarm
             app.sig_verifier.verify_many = counted_verify_many
             app.clock.set_virtual_time(hist.pub.clock.now() + 10.0)
-            if hasattr(app.sig_verifier, "warmup"):
-                app.sig_verifier.warmup(wait=True)   # compile off the clock
+            app.sig_verifier.warmup(wait=True)   # compile off the clock
             work = app.catchup_manager.start_catchup(
                 CatchupConfiguration.complete())
             t0 = time.perf_counter()
@@ -912,7 +910,7 @@ def fleet_verify_child(chunk: int = 8192, chunks: int = 3,
     is warm)."""
     import jax
     from stellar_core_tpu.crypto.batch_verifier import (
-        TpuSigVerifier, VerifierStats)
+        TpuSigVerifier, VerifierContext, VerifierStats)
 
     n_devices = jax.device_count()
     n = chunk * chunks
@@ -920,9 +918,9 @@ def fleet_verify_child(chunk: int = 8192, chunks: int = 3,
     triples = list(zip(pubs, sigs, msgs))
 
     t0 = time.perf_counter()
-    v = TpuSigVerifier(shard_threshold=min(chunk, 2048))
+    v = TpuSigVerifier(VerifierContext(stats=VerifierStats()),
+                       shard_threshold=min(chunk, 2048))
     v.BUCKETS = (chunk,)
-    v.stats = VerifierStats()
     v.warmup(wait=True)    # one shape: this mix is all `chunk`-sized
     first = v.verify_many(triples)
     warm_restart_s = time.perf_counter() - t0
@@ -935,7 +933,7 @@ def fleet_verify_child(chunk: int = 8192, chunks: int = 3,
         dt = time.perf_counter() - t1
         assert all(ok)
         best = max(best, n / dt)
-    j = v.stats.to_json()
+    j = v.ctx.stats.to_json()
     return {
         "devices": n_devices,
         "platform": jax.devices()[0].platform,
@@ -1803,7 +1801,7 @@ def parallel_close_bench(n_pairs: int = 300, ops_per_tx: int = 20,
 
     from stellar_core_tpu.crypto.hashing import sha256
     from stellar_core_tpu.crypto.keys import SecretKey
-    from stellar_core_tpu.crypto.batch_verifier import CpuSigVerifier
+    from stellar_core_tpu.crypto.batch_verifier import CPU_VERIFIER
     from stellar_core_tpu.herder.txset import TxSetFrame
     from stellar_core_tpu.ledger.ledger_manager import (
         LedgerCloseData, LedgerManager,
@@ -1855,7 +1853,7 @@ def parallel_close_bench(n_pairs: int = 300, ops_per_tx: int = 20,
 
         def close(frames, prewarm=True):
             if prewarm:
-                CpuSigVerifier().prewarm_many(
+                CPU_VERIFIER.prewarm_many(
                     [(f.tx.sourceAccount.account_id.key_bytes,
                       f.signatures[0].signature, f.contents_hash())
                      for f in frames])

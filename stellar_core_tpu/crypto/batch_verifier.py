@@ -1,4 +1,4 @@
-"""BatchSigVerifier: the config-gated crypto backend boundary.
+"""SigVerifier: the config-gated crypto backend boundary.
 
 North-star parity (BASELINE.json / SURVEY.md intro): the reference calls
 libsodium synchronously one signature at a time
@@ -7,61 +7,69 @@ batch-oriented service from day one:
 
     enqueue(key, sig, msg) -> VerifyFuture     (accumulate)
     flush()                                    (dispatch one device batch)
-    verify_many(triples) -> [bool]             (whole-ledger/checkpoint drain)
+    prewarm_many(triples) -> [bool]            (whole-ledger/checkpoint drain)
+    verify_many(triples) -> [bool]             (the same, past the cache)
 
-Backends:
-- CpuSigVerifier — synchronous OpenSSL; the default (reference's libsodium
+One boundary over plain engines (drawn in
+docs/architecture.md#verifier-boundary):
+
+- SigVerifier owns every decision that is not an engine's: the verdict
+  cache in front (hits never enqueue; `_cache_probe` / `_cache_store`
+  are the only code here that touches it), the one pending queue, where
+  a flush runs (inline, or on the `crypto.verify-dispatch` worker with
+  futures completing on the VirtualClock main loop, keeping the
+  single-threaded consensus invariant, docs/architecture.md:23-26), and
+  the circuit breaker between the engine and a CPU fallback: N
+  consecutive dispatch failures trip to the fallback for a cooldown
+  window with periodic reprobe, so a lost TPU degrades throughput
+  instead of killing a ledger close (docs/robustness.md; DSig-style
+  degraded operating mode).
+- CpuSigVerifier: synchronous OpenSSL engine (reference's libsodium
   role).
-- TpuSigVerifier — ships accumulated triples to the JAX ed25519 kernel in
-  one padded, fixed-shape device call (no recompiles); scales batch size
+- TpuSigVerifier: ships the triples it is given to the JAX ed25519
+  kernel in padded, fixed-shape device calls (no recompiles); scales
   from a few envelopes (live SCP) to whole checkpoints (catchup replay).
-- ThreadedBatchVerifier — wraps either backend so dispatch happens off the
-  main thread and futures complete on the VirtualClock main loop, keeping
-  the single-threaded consensus invariant (docs/architecture.md:23-26).
-- ResilientBatchVerifier — circuit breaker between a primary (device)
-  backend and a fallback: N consecutive dispatch failures trip to the
-  fallback for a cooldown window with periodic reprobe, so a lost TPU
-  degrades throughput instead of killing a ledger close
-  (docs/robustness.md; DSig-style degraded operating mode).
+- VerifierContext: what the three share by reference (verdict cache,
+  tracer, metrics, fault injector, VerifierStats cockpit, flight
+  recorder), handed to each at construction.
 
-The global verify-result cache (keys.py) sits in front of every backend;
-cache hits never enqueue.
-
-Clock/threading audit (ISSUE 5 satellite — the 9 touch points):
-1. CircuitBreaker.now_fn — injected app clock (make_verifier passes
+Clock/threading audit (ISSUE 5 satellite; the touch points):
+1. CircuitBreaker.now_fn: injected app clock (make_verifier passes
    clock.now); default is util.timer.real_monotonic for direct
    constructions. Cooldown/reprobe advance deterministically under a
    virtual clock.
-2-4. ThreadedBatchVerifier enqueue/dispatch/complete stamps — all three
-   read the injected app clock, so the queue-wait gauges and the
-   crypto.verify.latency timer are virtual-clock-deterministic in chaos
-   soaks (module-level `time` is gone from this file; the D1 static
-   rule keeps it out). The crypto.queue_wait.<class> spans are stamped
-   on the tracer's own clock, and only while tracing is on.
-5. ThreadedBatchVerifier._lock — TrackedLock, watched by the lock-order
-   checker (util/threads.py).
-6. ThreadedBatchVerifier worker thread — dispatch off-main; futures
-   complete via clock.post_to_main only (single-threaded consensus).
-7. TpuSigVerifier._warmup_thread — startup-only, touches JAX state, no
+2-4. SigVerifier enqueue/dispatch/complete stamps (a boundary built
+   with a clock): all three read the injected app clock, so the
+   queue-wait gauges and the crypto.verify.latency timer are
+   virtual-clock-deterministic in chaos soaks (module-level `time` is
+   gone from this file; the D1 static rule keeps it out). The
+   crypto.queue_wait.<class> spans are stamped on the tracer's own
+   clock, and only while tracing is on.
+5. SigVerifier._lock: TrackedLock("crypto.threaded-pending") over the
+   pending queue, watched by the lock-order checker (util/threads.py).
+6. SigVerifier dispatch worker ("crypto.verify-dispatch", only where
+   the boundary has a clock): dispatch off-main; futures complete via
+   clock.post_to_main only (single-threaded consensus).
+7. TpuSigVerifier._warmup_thread: startup-only, touches JAX state, no
    ledger/consensus objects.
-8. keys._cache_lock — TrackedLock shared with the worker thread.
-9. ResilientBatchVerifier breaker callbacks (_on_trip/_on_recover) —
-   run on whichever thread dispatched (worker under tpu-async): they
+8. keys._cache_lock: TrackedLock shared with the worker thread.
+9. SigVerifier breaker callbacks (_on_trip/_on_recover): run on
+   whichever thread dispatched (the worker under tpu-async): they
    touch only metrics/tracer/flight-recorder, which are thread-safe.
-10. VerifierStats (the ISSUE 6 cockpit) — event stamps read the
+10. VerifierStats (the ISSUE 6 cockpit): event stamps read the
     injected app clock (now_fn), compile DURATIONS read
     util.timer.real_monotonic (sanctioned: an XLA compile takes real
     time under a frozen virtual clock); recorded from the main loop,
     the dispatch worker, the staging worker and the warmup thread under
     its own TrackedLock("crypto.verifier-stats").
-11. _StagingJob worker ("crypto.verify-staging", ISSUE 11) — packs and
+11. _StagingJob worker ("crypto.verify-staging", ISSUE 11): packs and
     device_puts the next drain chunk while the fleet executes the
     current one; touches only host numpy buffers, JAX transfer APIs and
     VerifierStats (thread-safe), never ledger/consensus objects.
     Overlap DURATIONS read util.timer.real_monotonic (sanctioned: the
     host/device overlap being measured is real elapsed time).
-12. DeviceFleetHealth per-device breakers — same injected app clock as
-    the resilient layer's breaker (make_verifier passes clock.now), so
+12. DeviceFleetHealth per-device breakers: same injected app clock as
+    the boundary's breaker (make_verifier passes clock.now), so
     per-chip cooldown/reprobe advance deterministically under a
     virtual clock; callbacks touch only metrics/tracer/flight-recorder.
 
@@ -93,9 +101,9 @@ class VerifierStats:
     """Cockpit aggregation for the batch-verify boundary (ISSUE 6
     tentpole; docs/observability.md#device-cockpit).
 
-    One instance per make_verifier() stack, shared by every layer —
-    device backend, CPU fallback, resilient wrapper, threaded wrapper —
-    so drains are attributed to the backend that actually SERVED them
+    One instance per make_verifier() call, in the VerifierContext the
+    boundary, the engine and the CPU fallback share, so drains are
+    attributed to the backend that actually SERVED them
     (a fallback drain while the breaker is open counts against "cpu",
     never against the device). The same aggregate objects feed three
     consumers:
@@ -547,169 +555,66 @@ class VerifyFuture:
             cb(ok)
 
 
-class BatchSigVerifier:
-    """Abstract backend; see module docstring."""
+class VerifierContext:
+    """What the boundary and its engines share, by reference: built once
+    (make_verifier) and handed to each at construction, so no layer can
+    be left verifying against another cache or recording nowhere. Every
+    field is optional; the default context is silent (tests, the callers
+    handed no verifier) and sits behind the process-wide verdict cache
+    (keys.PROCESS_CACHE; a node's own keys.VerdictCache where
+    Config.VERIFY_CACHE_SCOPE is "node")."""
 
-    name = "abstract"
-    # True for backends where one big device dispatch beats many small
-    # ones — TxSetFrame.check_or_trim prewarms the whole set's signatures
-    # through verify_many before walking txs (two-phase validation).
-    wants_prewarm = False
-    # span tracer (util/tracing.py), metrics registry, fault injector
-    # (util/faults.py) and the shared VerifierStats cockpit, installed
-    # by make_verifier; None keeps direct constructions (tests,
-    # native-apply fallback) silent
-    tracer = None
-    metrics = None
-    faults = None
-    stats = None
-    # the verdict cache in front of the backend: the process-wide one
-    # unless make_verifier gave the stack its own (keys.VerdictCache)
-    cache = _keys.PROCESS_CACHE
+    __slots__ = ("cache", "tracer", "metrics", "faults", "stats",
+                 "flight_recorder")
 
-    def _span(self, name: str, **tags):
+    def __init__(self, cache=None, tracer=None, metrics=None, faults=None,
+                 stats=None, flight_recorder=None) -> None:
+        self.cache = cache or _keys.PROCESS_CACHE
+        self.tracer = tracer                    # util/tracing.py
+        self.metrics = metrics
+        self.faults = faults                    # util/faults.py
+        self.stats = stats                      # the VerifierStats cockpit
+        self.flight_recorder = flight_recorder
+
+    def span(self, name: str, **tags):
         return tracer_span(self.tracer, name, cat="crypto", **tags)
 
-    def enqueue(self, key: PublicKey, sig: bytes, msg: bytes,
-                cls: str = "tx") -> VerifyFuture:
-        """`cls` names the caller's verify class ("scp" envelopes, "tx"
-        signatures): the async backend's queue wait is traced per
-        class."""
-        raise NotImplementedError
 
-    def flush(self) -> None:
-        raise NotImplementedError
-
-    def verify_many(self, triples: Sequence[Triple]) -> List[bool]:
-        raise NotImplementedError
-
-    def prewarm_many(self, triples: Sequence[Triple]) -> List[bool]:
-        """Whole-ledger/checkpoint drain (SURVEY.md §2.2): verify a large
-        batch in one dispatch and seed the result cache so subsequent
-        synchronous per-signature checks all hit. Already-cached triples
-        are not re-dispatched. Cache keys for the whole drain hash in one
-        native call (prep.c sct_cache_keys) when available."""
-        with self._span("crypto.prewarm", backend=self.name,
-                        n=len(triples)) as sp:
-            out: List[Optional[bool]] = [None] * len(triples)
-            todo: List[Tuple[int, Triple, bytes]] = []  # (idx, triple, key)
-            with self._span("crypto.cache_probe", n=len(triples)):
-                cks = None
-                if len(triples) >= 256:   # below this the fixed numpy/
-                    # ctypes marshalling cost exceeds hashlib's per-triple
-                    # overhead (the native apply engine calls here once
-                    # per tx, ~20-ish triples; checkpoint drains come in
-                    # by the thousand)
-                    from ..native import cache_keys_native
-                    cks = cache_keys_native(triples)
-                if cks is None:
-                    cks = [_keys._cache_key(k, s, m)
-                           for (k, s, m) in triples]
-                with self.cache.lock:
-                    for i, (t, ck) in enumerate(zip(triples, cks)):
-                        hit = self.cache.store.maybe_get(ck)
-                        if hit is not None:
-                            out[i] = hit
-                        else:
-                            todo.append((i, t, ck))
-            sp.set_tag("cache_hits", len(triples) - len(todo))
-            if todo:
-                results = self.verify_many([t for (_i, t, _ck) in todo])
-                with self.cache.lock:
-                    for ((i, _t, ck), ok) in zip(todo, results):
-                        self.cache.store.put(ck, ok)
-                        out[i] = ok
-            return out  # type: ignore[return-value]
-
-    def pending(self) -> int:
-        return 0
-
-    # -- shared pending-queue machinery (batch backends) ---------------------
-    # TpuSigVerifier and ResilientBatchVerifier share one accumulate/
-    # dispatch protocol: cache-probe on enqueue, self-flush at
-    # _max_pending, one verify_many per flush, futures completed and the
-    # cache fed from the results; a raising dispatch re-completes the
-    # batch on the synchronous CPU path instead of stranding futures.
-
-    def _batch_enqueue(self, key: PublicKey, sig: bytes,
-                       msg: bytes) -> VerifyFuture:
-        ck = _keys._cache_key(key.key_bytes, sig, msg)
-        with self.cache.lock:
-            hit = self.cache.store.maybe_get(ck)
-        f = VerifyFuture()
-        if hit is not None:
-            f._complete(hit)
-            return f
-        self._pending.append(((key.key_bytes, sig, msg), f))
-        if self.stats is not None:
-            self.stats.set_queue_depth(len(self._pending))
-        if len(self._pending) >= self._max_pending:
-            self.flush()
-        return f
-
-    def _batch_flush(self) -> None:
-        if not self._pending:
-            return
-        batch, self._pending = self._pending, []
-        if self.stats is not None:
-            self.stats.set_queue_depth(0)
-        triples = [t for (t, _f) in batch]
-        try:
-            results = self.verify_many(triples)
-        except Exception as e:
-            log.warning("batch dispatch failed (%s); completing %d "
-                        "verifies on CPU fallback", e, len(batch))
-            results = _flush_fallback(self, triples)
-        for ((k, s, m), f), ok in zip(batch, results):
-            with self.cache.lock:
-                self.cache.store.put(_keys._cache_key(k, s, m), ok)
-            f._complete(ok)
-
-
-def _flush_fallback(verifier, triples: Sequence[Triple]) -> List[bool]:
-    """Synchronous CPU re-verify used when a backend's dispatch raises
-    mid-flush; counts the event so a silent degradation is visible."""
-    m = getattr(verifier, "metrics", None)
-    if m is not None:
-        m.new_meter("crypto.verify.flush-fallback").mark(len(triples))
-    st = getattr(verifier, "stats", None)
-    if st is not None:
-        # the CPU served this drain (the raising backend did not)
-        st.record_drain("cpu", len(triples))
-    return _keys.raw_verify_batch(triples)
-
-
-class CpuSigVerifier(BatchSigVerifier):
-    """Synchronous OpenSSL backend (libsodium role)."""
+class CpuSigVerifier:
+    """Synchronous OpenSSL engine (libsodium role). An engine is plain:
+    `verify_many` verifies the triples it is given, all of them, at the
+    call; the verdict cache, the pending queue and the futures are
+    SigVerifier's."""
 
     name = "cpu"
+    # True for engines where one big device dispatch beats many small
+    # ones: TxSetFrame.check_or_trim prewarms the whole set's signatures
+    # through prewarm_many before walking txs (two-phase validation).
+    wants_prewarm = False
+    # `GET verifier`'s device counters: nothing is dispatched to a device
+    batches_dispatched = 0
+    sigs_verified = 0
 
-    def enqueue(self, key: PublicKey, sig: bytes, msg: bytes,
-                cls: str = "tx") -> VerifyFuture:
-        f = VerifyFuture()
-        f._complete(_keys.verify_cached(self.cache, key, sig, msg))
-        return f
-
-    def flush(self) -> None:
-        pass
+    def __init__(self, ctx: Optional[VerifierContext] = None) -> None:
+        self.ctx = ctx if ctx is not None else VerifierContext()
 
     def verify_many(self, triples: Sequence[Triple]) -> List[bool]:
         # CPU drains carry the same batch-shape tags as device drains
         # (pad_waste is structurally 0: no padding on the synchronous
         # path) so bucket-selection analysis sees ALL traffic, not just
         # what happened to reach the device
-        with self._span("crypto.verify_many", backend=self.name,
-                        n=len(triples), batches=1, pad_waste=0,
-                        occupancy_pct=100.0):
+        with self.ctx.span("crypto.verify_many", backend=self.name,
+                           n=len(triples), batches=1, pad_waste=0,
+                           occupancy_pct=100.0):
             out = _keys.raw_verify_batch(triples)
             # recorded only after the verify returns: a raising drain is
-            # re-run (and counted once) by _flush_fallback instead
-            if self.stats is not None:
-                self.stats.record_drain(self.name, len(triples))
+            # re-run (and counted once) by the boundary's _flush_fallback
+            if self.ctx.stats is not None:
+                self.ctx.stats.record_drain(self.name, len(triples))
             return out
 
 
-class TpuSigVerifier(BatchSigVerifier):
+class TpuSigVerifier:
     """JAX/TPU batched backend with a device-fleet shard scheduler
     (ISSUE 11 tentpole).
 
@@ -730,8 +635,7 @@ class TpuSigVerifier(BatchSigVerifier):
     overlap-pct`). Per-device health is a ring of circuit breakers
     (DeviceFleetHealth): a sick chip drops out of the mesh and the
     drain continues on N-1 devices — the all-or-nothing CPU fallback is
-    the ResilientBatchVerifier layer above, reserved for whole-backend
-    failures.
+    the boundary's (SigVerifier), reserved for whole-engine failures.
     """
 
     name = "tpu"
@@ -746,14 +650,13 @@ class TpuSigVerifier(BatchSigVerifier):
     PLAN_AUTOSAVE_DRAINS = 32
     PLAN_BASENAME = "warmup_buckets.json"
 
-    def __init__(self, max_pending: int = 8192,
+    def __init__(self, ctx: Optional[VerifierContext] = None,
                  shard_threshold: Optional[int] = None,
                  devices: Optional[Sequence] = None,
                  now_fn: Optional[Callable[[], float]] = None,
                  device_breaker_threshold: int = 3,
                  device_breaker_cooldown: float = 30.0) -> None:
-        self._pending: List[Tuple[Triple, VerifyFuture]] = []
-        self._max_pending = max_pending
+        self.ctx = ctx if ctx is not None else VerifierContext()
         self.batches_dispatched = 0
         self.sigs_verified = 0
         # where the cockpit-derived warmup plan persists; None (direct
@@ -807,8 +710,8 @@ class TpuSigVerifier(BatchSigVerifier):
             devs, _health = self._fleet()
             mesh = make_mesh([devs[i] for i in idxs])
             got = (sharded_verify_fn(mesh), mesh)
-            if self._mesh_fns and self.metrics is not None:
-                self.metrics.new_meter("verifier.fleet.mesh-rebuild").mark()
+            if self._mesh_fns and self.ctx.metrics is not None:
+                self.ctx.metrics.new_meter("verifier.fleet.mesh-rebuild").mark()
             self._mesh_fns[idxs] = got
             if len(idxs) == len(devs):
                 self._sharded_fn = got[0]   # full-mesh alias
@@ -828,8 +731,8 @@ class TpuSigVerifier(BatchSigVerifier):
         N-1)."""
         devs, health = self._fleet()
         idxs = health.healthy() if len(devs) > 1 else [0]
-        if len(idxs) > 1 and self.faults is not None and \
-                self.faults.should_fire("verify.device-lost"):
+        if len(idxs) > 1 and self.ctx.faults is not None and \
+                self.ctx.faults.should_fire("verify.device-lost"):
             lost = idxs[0]
             health.record_failure(lost)
             idxs = [i for i in idxs if i != lost]
@@ -872,7 +775,7 @@ class TpuSigVerifier(BatchSigVerifier):
         after a stall): `crypto.stage`, on the drain's critical path. The
         staging worker's span is `crypto.stage_ahead` (_StagingJob): two
         names because a reader of spans sees names, not threads."""
-        with self._span("crypto.stage", n=len(chunk)):
+        with self.ctx.span("crypto.stage", n=len(chunk)):
             return self._stage_chunk(chunk, self._route(len(chunk)))
 
     def _device_arg(self, packed, idxs: tuple):
@@ -919,9 +822,9 @@ class TpuSigVerifier(BatchSigVerifier):
         executables only and one run cannot choose another's warm set.
         No-op until the cockpit has seen traffic — a default plan is not
         evidence worth persisting. Returns the path written, or None."""
-        if self.stats is None or self.warmup_plan_path is None:
+        if self.ctx.stats is None or self.warmup_plan_path is None:
             return None
-        buckets, info = warmup_plan(self.stats, self.BUCKETS)
+        buckets, info = warmup_plan(self.ctx.stats, self.BUCKETS)
         if info.get("source") != "cockpit":
             return None
         import json
@@ -971,7 +874,7 @@ class TpuSigVerifier(BatchSigVerifier):
         from ..parallel.device import (
             cache_hit, compile_cache_dir, compile_cache_events,
         )
-        st = self.stats
+        st = self.ctx.stats
         try:
             if st is not None:
                 st.set_compile_cache_dir(compile_cache_dir())
@@ -996,16 +899,6 @@ class TpuSigVerifier(BatchSigVerifier):
             if st is not None:
                 st.warmup_failed(repr(e))
 
-    def enqueue(self, key: PublicKey, sig: bytes, msg: bytes,
-                cls: str = "tx") -> VerifyFuture:
-        return self._batch_enqueue(key, sig, msg)
-
-    def pending(self) -> int:
-        return len(self._pending)
-
-    def flush(self) -> None:
-        self._batch_flush()
-
     def _bucket(self, n: int) -> int:
         for b in self.BUCKETS:
             if n <= b:
@@ -1021,8 +914,8 @@ class TpuSigVerifier(BatchSigVerifier):
             # run of this verifier is a fallback and must trace as one
             self._platform = jax.devices()[0].platform
         out: List[bool] = []
-        st = self.stats
-        with self._span("crypto.verify_many", backend=self.name,
+        st = self.ctx.stats
+        with self.ctx.span("crypto.verify_many", backend=self.name,
                         platform=self._platform, n=len(triples)) as sp:
             chunks: List[Sequence[Triple]] = []
             i = 0
@@ -1041,28 +934,28 @@ class TpuSigVerifier(BatchSigVerifier):
                 if k + 1 < len(chunks):
                     # Thread.start() returns once the worker runs, and the
                     # worker may keep the interpreter for a switch interval
-                    with self._span("crypto.stage_spawn"):
+                    with self.ctx.span("crypto.stage_spawn"):
                         job = _StagingJob(self, chunks[k + 1], cause=sp.sid)
                 n, b, idxs = staged["n"], staged["b"], staged["idxs"]
                 if st is not None:
                     for di in idxs:
                         st.set_device_inflight(di, True)
-                with self._span("crypto.dispatch", backend=self.name,
+                with self.ctx.span("crypto.dispatch", backend=self.name,
                                 n=n, bucket=b, pad=b - n,
                                 devices=len(idxs)):
                     try:
-                        with self._span("crypto.launch"):
+                        with self.ctx.span("crypto.launch"):
                             ok_dev = staged["fn"](staged["arg"])  # async
                         wait_t0 = real_monotonic()
-                        with self._span("crypto.device_wait"):
+                        with self.ctx.span("crypto.device_wait"):
                             ok = np.asarray(ok_dev)  # blocks on the fleet
                         wait_t1 = real_monotonic()
                     except Exception:
                         # a raising fleet dispatch counts against every
                         # participating device's breaker (attribution to
                         # ONE chip needs the fault-injection path); the
-                        # batch itself is completed by the resilient
-                        # layer above
+                        # batch itself is completed by the boundary's
+                        # fallback
                         health = self._fleet_health
                         if health is not None:
                             for di in idxs:
@@ -1072,7 +965,7 @@ class TpuSigVerifier(BatchSigVerifier):
                         if st is not None:
                             for di in idxs:
                                 st.set_device_inflight(di, False)
-                    with self._span("crypto.unpack"):
+                    with self.ctx.span("crypto.unpack"):
                         # every participant's breaker sees the success —
                         # single-device dispatches included, so transient
                         # failures spread over time never read as
@@ -1103,7 +996,7 @@ class TpuSigVerifier(BatchSigVerifier):
                                 st.record_device_dispatch(di, real,
                                                           lanes - real)
                 if job is not None:
-                    with self._span("crypto.stage_wait"):
+                    with self.ctx.span("crypto.stage_wait"):
                         staged, s_s, o_s, stalled = job.result(wait_t0,
                                                                wait_t1)
                     if stalled:
@@ -1168,9 +1061,9 @@ class _StagingJob:
     def _run(self) -> None:
         self.t0 = real_monotonic()
         try:
-            if self.v.faults is not None:
-                self.v.faults.fire_point("verify.staging-stall")
-            with self.v._span("crypto.stage_ahead", cause=self.cause,
+            if self.v.ctx.faults is not None:
+                self.v.ctx.faults.fire_point("verify.staging-stall")
+            with self.v.ctx.span("crypto.stage_ahead", cause=self.cause,
                               n=len(self.chunk)):
                 self.staged = self.v._stage_chunk(
                     self.chunk, self.v._route(len(self.chunk)))
@@ -1195,8 +1088,8 @@ class _StagingJob:
 
 class DeviceFleetHealth:
     """Per-device circuit breakers over the verify fleet (ISSUE 11
-    satellite): the ResilientBatchVerifier's single breaker treats the
-    whole backend as one unit; this ring trips and recovers per chip,
+    satellite): the boundary's single breaker (SigVerifier) treats the
+    whole engine as one unit; this ring trips and recovers per chip,
     so one sick device degrades the mesh to N-1 devices instead of
     dropping every drain to the CPU fallback. State is exported as
     `verifier.device.<i>.breaker` gauges (0 closed / 1 open / 2
@@ -1204,7 +1097,7 @@ class DeviceFleetHealth:
 
     Attribution honesty: a whole-mesh dispatch failure cannot name the
     guilty chip, so it counts against every participant (and, via the
-    resilient layer, the global breaker); single-chip attribution comes
+    boundary, the global breaker); single-chip attribution comes
     from the verify.device-lost fault point and device-identifiable
     runtime errors."""
 
@@ -1212,7 +1105,7 @@ class DeviceFleetHealth:
                  cooldown_s: float = 30.0,
                  now_fn: Optional[Callable[[], float]] = None,
                  owner=None) -> None:
-        self.owner = owner     # verifier; stats read dynamically
+        self.owner = owner     # the engine; stats read off its context
         # the ring is mutated from the dispatch thread AND the staging
         # worker (_route runs on both): one lock makes allow()/record_*
         # transitions atomic, so a just-tripped chip can never race its
@@ -1228,8 +1121,7 @@ class DeviceFleetHealth:
                 on_recover=(lambda i=i: self._on_recover(i))))
 
     def _stats(self):
-        return getattr(self.owner, "stats", None) \
-            if self.owner is not None else None
+        return self.owner.ctx.stats if self.owner is not None else None
 
     def healthy(self) -> List[int]:
         """Device indices whose breaker admits a dispatch right now
@@ -1351,287 +1243,345 @@ class CircuitBreaker:
                 "retry_at": self._retry_at}
 
 
-class ResilientBatchVerifier(BatchSigVerifier):
-    """Primary backend behind a circuit breaker, CPU fallback beside it.
+class SigVerifier:
+    """The verifier boundary: the one object the herder, the tx queue,
+    the txset check, catchup and the SignatureChecker hold. Every
+    decision that is not an engine's lives here, once:
 
-    Every dispatch-shaped call (verify_many; flush routes through it)
-    asks the breaker whether the primary may be tried; a raising primary
-    records a failure and the batch re-runs on the fallback, so callers
-    always get results. A trip emits metrics + a flight-recorder dump;
-    recovery (first successful half-open probe) emits the matching
-    recover marker — the signals the chaos soak asserts on."""
+    - the verdict cache in front (`_cache_probe` / `_cache_store`):
+      hits never enqueue and never dispatch;
+    - the pending queue: `enqueue` accumulates, `flush` dispatches ONE
+      batch. Without a clock the flush runs inline (and `enqueue`
+      self-flushes at `max_pending`); with one it runs on the
+      `crypto.verify-dispatch` worker, one batch in flight, and futures
+      complete on the main loop via clock.post_to_main: the
+      enqueue-and-continue protocol SURVEY.md §7 requires at the
+      verifyEnvelope/checkValid boundary. `max_pending=0` means no
+      queue at all: the `cpu` backend's verdict is ready at the call;
+    - the breaker: with a `fallback` engine every dispatch asks the
+      breaker whether the engine may be tried; a raising engine records
+      a failure and the batch re-runs on the fallback, so callers always
+      get results. A trip emits metrics + a flight-recorder dump;
+      recovery (first successful half-open probe) the matching recover
+      marker: the signals the chaos soak asserts on. Without a fallback
+      a dispatch is a plain call.
 
-    name = "resilient"
+    `prewarm_many` and `verify_many` are synchronous drains on every
+    backend. An engine (CpuSigVerifier, TpuSigVerifier, a test's fake) is
+    anything with `name`, `wants_prewarm`, `verify_many(triples)` and a
+    `ctx`; the boundary takes its context from the engine, so boundary,
+    engine and fallback share one by reference."""
 
-    def __init__(self, primary: BatchSigVerifier,
-                 fallback: BatchSigVerifier,
+    def __init__(self, engine, fallback=None,
                  breaker: Optional[CircuitBreaker] = None,
-                 max_pending: int = 8192) -> None:
-        self.primary = primary
+                 clock=None, max_pending: int = 8192) -> None:
+        self.engine = engine
         self.fallback = fallback
-        self.breaker = breaker or CircuitBreaker()
-        self.breaker.on_trip = self._on_trip
-        self.breaker.on_recover = self._on_recover
-        self.flight_recorder = None   # installed by make_verifier
-        self._pending: List[Tuple[Triple, VerifyFuture]] = []
+        self.ctx = engine.ctx
+        self.breaker: Optional[CircuitBreaker] = None
+        if fallback is not None:
+            self.breaker = breaker or CircuitBreaker()
+            self.breaker.on_trip = self._on_trip
+            self.breaker.on_recover = self._on_recover
+        # what `GET verifier` prints: `cpu` / `resilient` / `threaded`
+        inline = "resilient" if fallback is not None else engine.name
+        self.name = "threaded" if clock is not None else inline
+        self._batch_backend = "threaded:%s" % inline
+        self._clock = clock
         self._max_pending = max_pending
+        self._lock = TrackedLock("crypto.threaded-pending")
+        # (triple, cache key, future, enqueue app-clock stamp, class,
+        # tracer-clock stamp): the app-clock stamp (0.0 without a clock)
+        # feeds the crypto.verify.latency enqueue-to-complete timer (the
+        # p50/p99 the live SCP path actually feels); the app clock, not
+        # wall time, so chaos soaks under a virtual clock stay
+        # deterministic. The tracer-clock stamp (0.0 while tracing is
+        # off) is the start of the batch's crypto.queue_wait.<class>
+        # span.
+        self._pending: List[Tuple[Triple, bytes, VerifyFuture, float,
+                                  str, float]] = []
+        self._inflight = False
+
+    # -- what others read ----------------------------------------------------
+    @property
+    def inner(self):
+        """The engine: callers tune BUCKETS / read dispatch counters on
+        it (and benchmark/control.py replaces its verify_many)."""
+        return self.engine
+
+    @property
+    def wants_prewarm(self) -> bool:
+        return self.engine.wants_prewarm
+
+    @property
+    def stats(self):
+        return self.ctx.stats
+
+    @property
+    def cache(self):
+        return self.ctx.cache
+
+    def warmup(self, wait: bool = False) -> None:
+        """Compile the device engine's shapes; a CPU engine has none."""
+        if self.engine.wants_prewarm:
+            self.engine.warmup(wait)
+
+    def save_warmup_plan(self) -> Optional[str]:
+        return self.engine.save_warmup_plan() \
+            if self.engine.wants_prewarm else None
+
+    def pending(self) -> int:
+        with self._lock:
+            return len(self._pending)
+
+    # -- the verdict cache ---------------------------------------------------
+    def _cache_probe(self, triples: Sequence[Triple]):
+        """(verdicts, misses): each triple's cached verdict, None where
+        the cache holds none, and for those (index, cache key). Cache
+        keys for a whole drain hash in one native call (prep.c
+        sct_cache_keys) when available; one lock section a probe."""
+        cks = None
+        if len(triples) >= 256:   # below this the fixed numpy/ctypes
+            # marshalling cost exceeds hashlib's per-triple overhead
+            # (the native apply engine calls here once per tx, ~20-ish
+            # triples; checkpoint drains come in by the thousand)
+            from ..native import cache_keys_native
+            cks = cache_keys_native(triples)
+        if cks is None:
+            cks = [_keys._cache_key(k, s, m) for (k, s, m) in triples]
+        verdicts: List[Optional[bool]] = []
+        misses: List[Tuple[int, bytes]] = []
+        cache = self.ctx.cache
+        with cache.lock:
+            for i, ck in enumerate(cks):
+                hit = cache.store.maybe_get(ck)
+                verdicts.append(hit)
+                if hit is None:
+                    misses.append((i, ck))
+        return verdicts, misses
+
+    def _cache_store(self, cks: Sequence[bytes],
+                     results: Sequence[bool]) -> None:
+        cache = self.ctx.cache
+        with cache.lock:
+            for ck, ok in zip(cks, results):
+                cache.store.put(ck, ok)
+
+    # -- synchronous drains --------------------------------------------------
+    def prewarm_many(self, triples: Sequence[Triple]) -> List[bool]:
+        """Whole-ledger/checkpoint drain (SURVEY.md §2.2): verify a large
+        batch in one dispatch and seed the result cache so subsequent
+        synchronous per-signature checks all hit. Already-cached triples
+        are not re-dispatched."""
+        with self.ctx.span("crypto.prewarm", backend=self.name,
+                           n=len(triples)) as sp:
+            with self.ctx.span("crypto.cache_probe", n=len(triples)):
+                out, misses = self._cache_probe(triples)
+            sp.set_tag("cache_hits", len(triples) - len(misses))
+            if misses:
+                results = self.verify_many(
+                    [triples[i] for (i, _ck) in misses])
+                self._cache_store([ck for (_i, ck) in misses], results)
+                for (i, _ck), ok in zip(misses, results):
+                    out[i] = ok
+            return out  # type: ignore[return-value]
+
+    def verify_many(self, triples: Sequence[Triple]) -> List[bool]:
+        """One dispatch, past the cache: breaker, engine, on failure the
+        fallback. The engine's verify_many is looked up on the engine at
+        every call: benchmark/control.py's negative controls replace it
+        on the instance, and every path to the device comes through
+        here."""
+        engine, fallback, ctx = self.engine, self.fallback, self.ctx
+        if fallback is None:
+            return engine.verify_many(triples)
+        breaker = self.breaker
+        if breaker.allow():
+            try:
+                # the engine's attempt gets its own span so an injected
+                # (or real) dispatch failure is tagged on the drain it
+                # killed, not floating free on the timeline
+                with ctx.span("crypto.dispatch_primary",
+                              backend=engine.name, n=len(triples)):
+                    if ctx.faults is not None:
+                        ctx.faults.fire_point("device.dispatch")
+                    out = engine.verify_many(triples)
+                breaker.record_success()
+                return out
+            except Exception as e:
+                if ctx.metrics is not None:
+                    ctx.metrics.new_meter(
+                        "crypto.verify.dispatch-failure").mark()
+                tripped = breaker.record_failure()
+                if not tripped:
+                    log.warning("%s dispatch failed (%s): %d/%d toward "
+                                "breaker trip", engine.name, e,
+                                breaker.consecutive_failures,
+                                breaker.threshold)
+        if ctx.metrics is not None:
+            # drains served by the fallback while the engine is failing
+            # or the breaker is open: the "completed on fallback" signal
+            # the chaos soak asserts on
+            ctx.metrics.new_meter("crypto.verify.fallback-drain").mark()
+        # served_by names the engine that actually ran the drain: the
+        # fallback's own verify_many records the drain stats under its
+        # name, so cockpit attribution follows the server
+        with ctx.span("crypto.verify_fallback", backend="resilient",
+                      served_by=fallback.name,
+                      n=len(triples), breaker=breaker.state):
+            return fallback.verify_many(triples)
 
     # -- breaker events ------------------------------------------------------
     def _breaker_mark(self, event: str) -> None:
-        if self.metrics is not None:
-            self.metrics.new_meter("crypto.breaker.%s" % event).mark()
-            self.metrics.new_counter("crypto.breaker.state").set_count(
+        ctx = self.ctx
+        if ctx.metrics is not None:
+            ctx.metrics.new_meter("crypto.breaker.%s" % event).mark()
+            ctx.metrics.new_counter("crypto.breaker.state").set_count(
                 self.breaker.state_code())
-        tracer_instant(self.tracer, "crypto.breaker.%s" % event,
-                       cat="crypto", primary=self.primary.name,
+        tracer_instant(ctx.tracer, "crypto.breaker.%s" % event,
+                       cat="crypto", primary=self.engine.name,
                        failures=self.breaker.consecutive_failures)
 
     def _on_trip(self) -> None:
         log.warning("verify breaker TRIPPED: %d consecutive %s-dispatch "
                     "failures; falling back to %s for %.0fs",
-                    self.breaker.consecutive_failures, self.primary.name,
+                    self.breaker.consecutive_failures, self.engine.name,
                     self.fallback.name, self.breaker.cooldown_s)
         self._breaker_mark("trip")
-        if self.flight_recorder is not None:
-            self.flight_recorder.dump(
+        if self.ctx.flight_recorder is not None:
+            self.ctx.flight_recorder.dump(
                 "verify-breaker-trip",
-                extra={"primary": self.primary.name,
+                extra={"primary": self.engine.name,
                        "breaker": self.breaker.to_json()})
 
     def _on_recover(self) -> None:
         log.info("verify breaker recovered: %s backend healthy again",
-                 self.primary.name)
+                 self.engine.name)
         self._breaker_mark("recover")
 
-    # -- delegation ----------------------------------------------------------
-    @property
-    def wants_prewarm(self) -> bool:
-        return self.primary.wants_prewarm
-
-    @property
-    def inner(self) -> BatchSigVerifier:
-        return self.primary
-
-    @property
-    def batches_dispatched(self) -> int:
-        return getattr(self.primary, "batches_dispatched", 0)
-
-    @property
-    def sigs_verified(self) -> int:
-        return getattr(self.primary, "sigs_verified", 0)
-
-    def warmup(self, wait: bool = False) -> None:
-        w = getattr(self.primary, "warmup", None)
-        if w is not None:
-            w(wait)
-
-    def save_warmup_plan(self):
-        f = getattr(self.primary, "save_warmup_plan", None)
-        return f() if f is not None else None
-
-    @property
-    def fleet_health(self):
-        return getattr(self.primary, "_fleet_health", None)
-
-    # -- verify paths --------------------------------------------------------
-    def verify_many(self, triples: Sequence[Triple]) -> List[bool]:
-        if self.breaker.allow():
-            try:
-                # the primary attempt gets its own span so an injected
-                # (or real) dispatch failure is tagged on the drain it
-                # killed, not floating free on the timeline
-                with self._span("crypto.dispatch_primary",
-                                backend=self.primary.name,
-                                n=len(triples)):
-                    if self.faults is not None:
-                        self.faults.fire_point("device.dispatch")
-                    out = self.primary.verify_many(triples)
-                self.breaker.record_success()
-                return out
-            except Exception as e:
-                if self.metrics is not None:
-                    self.metrics.new_meter(
-                        "crypto.verify.dispatch-failure").mark()
-                tripped = self.breaker.record_failure()
-                if not tripped:
-                    log.warning("%s dispatch failed (%s): %d/%d toward "
-                                "breaker trip", self.primary.name, e,
-                                self.breaker.consecutive_failures,
-                                self.breaker.threshold)
-        if self.metrics is not None:
-            # drains served by the fallback while the primary is failing
-            # or the breaker is open — the "completed on fallback" signal
-            # the chaos soak asserts on
-            self.metrics.new_meter("crypto.verify.fallback-drain").mark()
-        # served_by names the backend that actually ran the drain — the
-        # fallback's own verify_many records the drain stats under its
-        # name, so cockpit attribution follows the server, not the wrapper
-        with self._span("crypto.verify_fallback", backend=self.name,
-                        served_by=self.fallback.name,
-                        n=len(triples), breaker=self.breaker.state):
-            return self.fallback.verify_many(triples)
-
+    # -- the pending queue ---------------------------------------------------
     def enqueue(self, key: PublicKey, sig: bytes, msg: bytes,
                 cls: str = "tx") -> VerifyFuture:
-        return self._batch_enqueue(key, sig, msg)
-
-    def pending(self) -> int:
-        return len(self._pending)
-
-    def flush(self) -> None:
-        # verify_many (almost) never raises here: a primary failure is
-        # absorbed by the breaker and the batch re-runs on the fallback —
-        # a trip mid-drain still completes every future correctly
-        self._batch_flush()
-
-
-class ThreadedBatchVerifier(BatchSigVerifier):
-    """Async wrapper: dispatch runs on a worker thread, futures complete on
-    the main loop via clock.post_to_main — the enqueue-and-continue protocol
-    SURVEY.md §7 requires at the verifyEnvelope/checkValid boundary."""
-
-    name = "threaded"
-
-    def __init__(self, inner: BatchSigVerifier, clock,
-                 metrics=None) -> None:
-        self._inner = inner
-        self._clock = clock
-        self._metrics = metrics
-        self._lock = TrackedLock("crypto.threaded-pending")
-        # (triple, future, enqueue app-clock stamp, class, tracer-clock
-        # stamp): the app-clock stamp feeds the crypto.verify.latency
-        # enqueue-to-complete timer (the p50/p99 the live SCP path
-        # actually feels); the app clock, not wall time, so chaos soaks
-        # under a virtual clock stay deterministic. The tracer-clock
-        # stamp (0.0 while tracing is off) is the start of the batch's
-        # crypto.queue_wait.<class> span.
-        self._pending: List[Tuple[Triple, VerifyFuture, float, str,
-                                  float]] = []
-        self._inflight = False
-
-    @property
-    def wants_prewarm(self) -> bool:
-        return self._inner.wants_prewarm
-
-    @property
-    def inner(self) -> BatchSigVerifier:
-        """The DEVICE verifier (unwrapping a resilient layer): callers
-        tune BUCKETS / read dispatch counters on the actual backend."""
-        return getattr(self._inner, "inner", self._inner)
-
-    @property
-    def breaker(self):
-        return getattr(self._inner, "breaker", None)
-
-    def warmup(self, wait: bool = False) -> None:
-        w = getattr(self._inner, "warmup", None)
-        if w is not None:
-            w(wait)
-
-    def save_warmup_plan(self):
-        f = getattr(self._inner, "save_warmup_plan", None)
-        return f() if f is not None else None
-
-    @property
-    def fleet_health(self):
-        return getattr(self._inner, "fleet_health", None)
-
-    def enqueue(self, key: PublicKey, sig: bytes, msg: bytes,
-                cls: str = "tx") -> VerifyFuture:
-        ck = _keys._cache_key(key.key_bytes, sig, msg)
-        with self.cache.lock:
-            hit = self.cache.store.maybe_get(ck)
+        """`cls` names the caller's verify class ("scp" envelopes, "tx"
+        signatures): the worker's queue wait is traced per class."""
         f = VerifyFuture()
-        if hit is not None:
-            f._complete(hit)
+        if not self._max_pending:
+            f._complete(_keys.verify_cached(self.ctx.cache, key, sig, msg))
             return f
-        tr = enabled_tracer(self.tracer)
-        t_trace = tr.now() if tr is not None else 0.0
+        triple = (key.key_bytes, sig, msg)
+        verdicts, misses = self._cache_probe((triple,))
+        if not misses:
+            f._complete(verdicts[0])
+            return f
+        tr = enabled_tracer(self.ctx.tracer)
+        entry = (triple, misses[0][1], f,
+                 self._clock.now() if self._clock is not None else 0.0,
+                 cls, tr.now() if tr is not None else 0.0)
         with self._lock:
-            self._pending.append(((key.key_bytes, sig, msg), f,
-                                  self._clock.now(), cls, t_trace))
+            self._pending.append(entry)
             depth = len(self._pending)
-        if self.stats is not None:
-            self.stats.set_queue_depth(depth)
+        if self.ctx.stats is not None:
+            self.ctx.stats.set_queue_depth(depth)
+        if self._clock is None and depth >= self._max_pending:
+            self.flush()
         return f
-
-    def pending(self) -> int:
-        with self._lock:
-            return len(self._pending)
 
     def flush(self) -> None:
         with self._lock:
             if not self._pending or self._inflight:
                 return
             batch, self._pending = self._pending, []
-            self._inflight = True
-        st = self.stats
+            self._inflight = self._clock is not None
+        st = self.ctx.stats
         if st is not None:
             st.set_queue_depth(0)
+        if self._clock is None:
+            self._complete(batch, self._verify_batch(batch))
+            return
+        if st is not None:
             st.set_inflight(True)
-        tr = enabled_tracer(self.tracer)
+        tr = enabled_tracer(self.ctx.tracer)
         cause = tr.current_sid() if tr is not None else 0
 
         def work() -> None:
-            triples = [t for (t, _f, _ta, _c, _tt) in batch]
             # queue-wait: enqueue → dispatch start, per batch; dispatch
-            # time is the span's own duration (inner verify_many nests)
+            # time is the span's own duration (the engine's verify_many
+            # nests)
             if st is not None:
                 t_disp = self._clock.now()
-                waits = [t_disp - ta for (_t, _f, ta, _c, _tt) in batch]
+                waits = [t_disp - ta for (_t, _ck, _f, ta, _c, _tt)
+                         in batch]
                 st.record_queue_wait(sum(waits) / len(waits), max(waits))
             if tr is not None:
                 # one span per class in the batch, from its oldest
                 # enqueue (stamped while tracing was on) to here
                 t_disp = tr.now()
                 oldest: dict = {}
-                for (_t, _f, _ta, c, tt) in batch:
+                for (_t, _ck, _f, _ta, c, tt) in batch:
                     if tt and tt < oldest.get(c, t_disp):
                         oldest[c] = tt
                 for c, t0 in oldest.items():
                     tr.record("crypto.queue_wait.%s" % c, "crypto", t0,
                               t_disp - t0, cause=cause, n=len(batch))
-            with self._span("crypto.batch_dispatch", cause=cause,
-                            n=len(batch)) as bsp:
+            with self.ctx.span("crypto.batch_dispatch", cause=cause,
+                               n=len(batch)) as bsp:
                 if bsp.live:
-                    bsp.set_tag("backend",
-                                "threaded:%s" % self._inner.name)
-                try:
-                    results = self._inner.verify_many(triples)
-                except Exception as e:
-                    # the worker thread must neither die with futures
-                    # pending nor leave _inflight latched (that would
-                    # no-op every later flush — a permanent wedge)
-                    log.warning("threaded dispatch failed (%s); completing "
-                                "%d verifies on CPU fallback", e, len(batch))
-                    results = _flush_fallback(self, triples)
-
-            def complete() -> None:
-                done = self._clock.now()
-                lat = (self._metrics.new_timer("crypto.verify.latency")
-                       if self._metrics is not None else None)
-                for ((k, s, m), f, t0, _c, _tt), ok in zip(batch, results):
-                    with self.cache.lock:
-                        self.cache.store.put(_keys._cache_key(k, s, m), ok)
-                    if lat is not None:
-                        lat.update(done - t0)
-                    f._complete(ok)
-                with self._lock:
-                    self._inflight = False
-                    more = bool(self._pending)
-                if st is not None:
-                    st.set_inflight(False)
-                if more:
-                    # verifies enqueued while the batch was in flight form
-                    # the next batch immediately
-                    self.flush()
-
-            self._clock.post_to_main(complete)
+                    bsp.set_tag("backend", self._batch_backend)
+                results = self._verify_batch(batch)
+            self._clock.post_to_main(
+                lambda: self._complete(batch, results))
 
         spawn_worker("crypto.verify-dispatch", work)
 
-    def verify_many(self, triples: Sequence[Triple]) -> List[bool]:
-        return self._inner.verify_many(triples)
+    def _verify_batch(self, batch) -> List[bool]:
+        """One dispatch for a flushed batch. verify_many (almost) never
+        raises behind a breaker: an engine failure is absorbed and the
+        batch re-runs on the fallback. When it does raise, the batch is
+        verified on the synchronous CPU path: a flush must neither
+        strand futures nor (on the worker) die with `_inflight` latched,
+        which would no-op every later flush, a permanent wedge."""
+        triples = [e[0] for e in batch]
+        try:
+            return self.verify_many(triples)
+        except Exception as e:
+            log.warning("batch dispatch failed (%s); completing %d "
+                        "verifies on CPU fallback", e, len(batch))
+            return self._flush_fallback(triples)
+
+    def _flush_fallback(self, triples: Sequence[Triple]) -> List[bool]:
+        """Synchronous CPU re-verify used when a dispatch raises
+        mid-flush; counts the event so a silent degradation is visible."""
+        if self.ctx.metrics is not None:
+            self.ctx.metrics.new_meter(
+                "crypto.verify.flush-fallback").mark(len(triples))
+        if self.ctx.stats is not None:
+            # the CPU served this drain (the raising engine did not)
+            self.ctx.stats.record_drain("cpu", len(triples))
+        return _keys.raw_verify_batch(triples)
+
+    def _complete(self, batch, results: Sequence[bool]) -> None:
+        """Feed the cache and complete the batch's futures: inline, or
+        on the main loop where the worker posted it."""
+        self._cache_store([e[1] for e in batch], results)
+        lat = None
+        if self._clock is not None and self.ctx.metrics is not None:
+            lat = self.ctx.metrics.new_timer("crypto.verify.latency")
+            done = self._clock.now()
+        for (_t, _ck, f, t0, _c, _tt), ok in zip(batch, results):
+            if lat is not None:
+                lat.update(done - t0)
+            f._complete(ok)
+        if self._clock is None:
+            return
+        with self._lock:
+            self._inflight = False
+            more = bool(self._pending)
+        if self.ctx.stats is not None:
+            self.ctx.stats.set_inflight(False)
+        if more:
+            # verifies enqueued while the batch was in flight form
+            # the next batch immediately
+            self.flush()
 
 
 def make_verifier(backend: str = "cpu", clock=None,
@@ -1640,76 +1590,53 @@ def make_verifier(backend: str = "cpu", clock=None,
                   flight_recorder=None,
                   breaker_threshold: int = 3,
                   breaker_cooldown: float = 30.0,
-                  cache=None) -> BatchSigVerifier:
-    """Config-gated backend selection (Config.SIG_VERIFY_BACKEND).
-    `cache` (keys.VerdictCache) is the stack's own verdict cache where
-    Config.VERIFY_CACHE_SCOPE is "node"; None keeps the process-wide one.
+                  cache=None) -> SigVerifier:
+    """Config-gated backend selection (Config.SIG_VERIFY_BACKEND): which
+    engine, whether a CPU fallback stands beside it behind a breaker,
+    and whether a flush runs on the worker. `cache` (keys.VerdictCache)
+    is the node's own verdict cache where Config.VERIFY_CACHE_SCOPE is
+    "node"; None keeps the process-wide one.
 
-    Device backends ("tpu", "tpu-async") are always wrapped in a
-    ResilientBatchVerifier with a CPU fallback; "cpu-resilient" wraps the
-    CPU backend in the same breaker machinery so chaos runs exercise the
-    device failure domain on device-less containers.
+    Device backends ("tpu", "tpu-async") always have the CPU fallback;
+    "cpu-resilient" puts the CPU engine behind the same breaker so chaos
+    runs exercise the device failure domain on device-less containers.
+    "tpu-async" alone flushes on the worker and completes on `clock`.
 
-    Every layer of the stack shares ONE VerifierStats cockpit
-    (`<verifier>.stats`), so fallback drains are attributed to the
-    backend that served them and the admin `verifier` endpoint sees the
-    whole boundary regardless of wrapping."""
+    Boundary, engine and fallback share ONE context, so one
+    VerifierStats cockpit (`<verifier>.stats`): fallback drains are
+    attributed to the engine that served them and the admin `verifier`
+    endpoint sees the whole boundary."""
     now_fn = clock.now if clock is not None else None
-    stats = VerifierStats(metrics=metrics, tracer=tracer, now_fn=now_fn,
-                          flight_recorder=flight_recorder)
-    cache = cache or _keys.PROCESS_CACHE
-
-    def resilient(primary: BatchSigVerifier) -> ResilientBatchVerifier:
-        primary.cache = cache
-        primary.tracer = tracer
-        primary.metrics = metrics
-        primary.stats = stats
-        primary.faults = faults   # verify.device-lost / .staging-stall
-        # fire inside the device backend's route/staging, not just the
-        # resilient layer's device.dispatch point
-        fb = CpuSigVerifier()
-        fb.cache = cache
-        fb.tracer = tracer
-        fb.metrics = metrics
-        fb.stats = stats
-        r = ResilientBatchVerifier(
-            primary, fb,
-            CircuitBreaker(threshold=breaker_threshold,
-                           cooldown_s=breaker_cooldown, now_fn=now_fn),
-            max_pending=max_pending)
-        r.cache = cache
-        r.tracer = tracer
-        r.flight_recorder = flight_recorder
-        r.stats = stats
-        return r
-
-    def device() -> TpuSigVerifier:
-        # the per-device breaker ring shares the resilient layer's
+    ctx = VerifierContext(
+        cache=cache, tracer=tracer, metrics=metrics, faults=faults,
+        stats=VerifierStats(metrics=metrics, tracer=tracer, now_fn=now_fn,
+                            flight_recorder=flight_recorder),
+        flight_recorder=flight_recorder)
+    if backend == "cpu":
+        return SigVerifier(CpuSigVerifier(ctx), max_pending=0)
+    if backend == "cpu-resilient":
+        engine = CpuSigVerifier(ctx)
+    elif backend in ("tpu", "tpu-async"):
+        # the per-device breaker ring shares the boundary breaker's
         # threshold/cooldown knobs and the injected app clock, so a
         # chip's trip/reprobe schedule is as deterministic under a
-        # virtual clock as the whole-backend breaker's
-        return TpuSigVerifier(max_pending=max_pending,
-                              now_fn=now_fn,
-                              device_breaker_threshold=breaker_threshold,
-                              device_breaker_cooldown=breaker_cooldown)
-
-    if backend == "cpu":
-        v: BatchSigVerifier = CpuSigVerifier()
-    elif backend == "cpu-resilient":
-        v = resilient(CpuSigVerifier())
-    elif backend == "tpu":
-        v = resilient(device())
-    elif backend == "tpu-async":
-        assert clock is not None
-        inner = resilient(device())
-        inner.metrics = metrics
-        inner.faults = faults
-        v = ThreadedBatchVerifier(inner, clock, metrics=metrics)
+        # virtual clock as the whole-engine breaker's
+        engine = TpuSigVerifier(ctx, now_fn=now_fn,
+                                device_breaker_threshold=breaker_threshold,
+                                device_breaker_cooldown=breaker_cooldown)
     else:
         raise ValueError("unknown sig verify backend %r" % backend)
-    v.cache = cache
-    v.tracer = tracer
-    v.metrics = metrics
-    v.faults = faults
-    v.stats = stats
-    return v
+    if backend == "tpu-async":
+        assert clock is not None
+    return SigVerifier(
+        engine, fallback=CpuSigVerifier(ctx),
+        breaker=CircuitBreaker(threshold=breaker_threshold,
+                               cooldown_s=breaker_cooldown, now_fn=now_fn),
+        clock=clock if backend == "tpu-async" else None,
+        max_pending=max_pending)
+
+
+# for the callers handed no verifier (a SignatureChecker or a frame
+# checked on its own, the native-apply fallback): the `cpu` boundary
+# over the process-wide cache, silent, with no queue to share
+CPU_VERIFIER = SigVerifier(CpuSigVerifier(), max_pending=0)

@@ -5,7 +5,7 @@ Role parity: reference `src/herder/HerderImpl.{h,cpp}` +
 - slot = ledger sequence, value = XDR StellarValue(txset hash, closeTime,
   upgrades)
 - envelope signature verify/sign (verifyEnvelope HerderImpl.cpp:1474 —
-  TPU batch hot caller #1, routed through the injected BatchSigVerifier)
+  TPU batch hot caller #1, routed through the injected SigVerifier)
 - tracking / not-tracking state machine with a consensus-stuck watchdog
   (herder/readme.md)
 - triggerNextLedger (HerderImpl.cpp:743-832): queue → txset → trim →

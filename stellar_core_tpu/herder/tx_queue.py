@@ -13,6 +13,7 @@ from __future__ import annotations
 
 from typing import Dict, List, Optional, Tuple
 
+from ..crypto.batch_verifier import CPU_VERIFIER
 from ..ledger.ledgertxn import LedgerTxn
 from ..util.log import get_logger
 from ..util.threads import main_thread_only
@@ -43,7 +44,7 @@ class TransactionQueue:
         self.pending_depth = pending_depth
         self.ban_depth = ban_depth
         self.pool_multiplier = pool_ledger_multiplier
-        self.verifier = verifier
+        self.verifier = verifier or CPU_VERIFIER
         self.metrics = metrics
         # tx-lifecycle cockpit (ISSUE 10): evict/expire/ban/replace
         # outcomes complete the submit→apply funnel
@@ -101,7 +102,7 @@ class TransactionQueue:
         there. Returns (triples, of those not cached before); (0, 0)
         on a verifier that wants no prewarm."""
         v = self.verifier
-        if not getattr(v, "wants_prewarm", False):
+        if not v.wants_prewarm:
             return 0, 0
         from ..crypto.keys import count_uncached
         from ..transactions.transaction_frame import frames_sig_triples
@@ -158,7 +159,7 @@ class TransactionQueue:
         # full validity check against current ledger — hot verify site
         ltx = LedgerTxn(self._ledger.ltx_root())
         try:
-            if getattr(self.verifier, "wants_prewarm", False):
+            if self.verifier.wants_prewarm:
                 # ONE batched dispatch for every candidate signature pair
                 # of this tx; the per-signer walk inside check_valid then
                 # completes off the warm verify cache (hot caller #2,

@@ -278,7 +278,7 @@ class TxSetFrame:
         one device dispatch; the per-tx walk below then completes entirely
         off the warm verify cache. Reference walks tx-by-tx
         (TxSetFrame.cpp:277-359); batching is the TPU-native reshape."""
-        if verifier is None or not getattr(verifier, "wants_prewarm", False):
+        if verifier is None or not verifier.wants_prewarm:
             return
         if len(self.frames) <= 1:
             return

@@ -10,7 +10,7 @@ checkpoint N+1's download with checkpoint N's apply).
 
 TPU batch site (SURVEY.md §3.4): before replaying a checkpoint, every
 (source-key, signature, payload) triple in its txsets is drained through
-`BatchSigVerifier.verify_many` in one padded device batch, pre-warming
+`SigVerifier.prewarm_many` in one padded device batch, pre-warming
 the verify cache so the synchronous per-tx checks during apply all hit.
 """
 

@@ -7,7 +7,7 @@ Role parity: reference `src/ledger/LedgerManagerImpl.cpp`:
   ledgerClosed (bucket batch + header hash) → commit → publish queue
 - startNewLedger / loadLastKnownLedger for genesis and restart.
 
-Design note (TPU): closeLedger takes an optional BatchSigVerifier; during
+Design note (TPU): closeLedger takes an optional SigVerifier; during
 catchup replay the caller pre-warms the verify cache with a whole ledger's
 (or checkpoint's) signatures in one device batch, so the per-tx checks here
 become cache hits.

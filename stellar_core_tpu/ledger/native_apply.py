@@ -112,8 +112,8 @@ def native_apply_txset(lm, ltx, frames, base_fee: Optional[int],
     if get_blob is None or book is None or acct_offers is None:
         return _bail(stats, "no-blob-lookup")
     if verifier is None:
-        from ..crypto.batch_verifier import CpuSigVerifier
-        verifier = CpuSigVerifier()
+        from ..crypto.batch_verifier import CPU_VERIFIER
+        verifier = CPU_VERIFIER
     params = {
         "ledgerVersion": header.ledgerVersion,
         "ledgerSeq": header.ledgerSeq,

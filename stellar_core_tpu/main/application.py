@@ -352,8 +352,7 @@ class Application:
         # AOT kernel warmup on a background thread: every bucket shape is
         # compiled (or loaded from the persistent cache) before the first
         # envelope can trigger a lazy compile on the consensus path
-        if self.config.SIG_VERIFY_WARMUP and \
-                getattr(self.sig_verifier, "wants_prewarm", False):
+        if self.config.SIG_VERIFY_WARMUP:
             self.sig_verifier.warmup(wait=False)
         # the hash kernel warms beside the verify kernel: same
         # no-lazy-compile-on-consensus rule
@@ -413,9 +412,7 @@ class Application:
         # bucket directory (ISSUE 11): the next start warms only the
         # shapes this run's real traffic used. Best-effort no-op on CPU
         # backends, without buckets or when the cockpit saw no traffic.
-        save_plan = getattr(self.sig_verifier, "save_warmup_plan", None)
-        if save_plan is not None:
-            save_plan()
+        self.sig_verifier.save_warmup_plan()
         # interrupt any background quorum-intersection enumeration first:
         # joining that worker can otherwise take minutes (reference
         # HerderImpl.cpp:140-144)
@@ -465,8 +462,8 @@ class Application:
         bucket_dir = bucket_dir or self.config.BUCKET_DIR_PATH
         # node state a device verifier keeps across restarts lives
         # beside the bucket directory, never in the compile cache
-        dev = getattr(self.sig_verifier, "inner", self.sig_verifier)
-        if hasattr(dev, "warmup_plan_path"):
+        dev = self.sig_verifier.inner
+        if dev.wants_prewarm:
             dev.warmup_plan_path = os.path.join(
                 os.path.dirname(os.path.abspath(bucket_dir)),
                 dev.PLAN_BASENAME)

@@ -133,14 +133,13 @@ class CommandHandler:
         v = getattr(self.app, "sig_verifier", None)
         # the cache in front of this node's verifier stack: the
         # process-wide one unless VERIFY_CACHE_SCOPE is "node"
-        cache = _keys.verify_cache_stats(getattr(v, "cache", None))
+        cache = _keys.verify_cache_stats(v.cache if v is not None else None)
         out["crypto.verify.cache-hit"] = {"count": cache["hits"]}
         out["crypto.verify.cache-miss"] = {"count": cache["misses"]}
-        inner = getattr(v, "inner", v)
-        if inner is not None and hasattr(inner, "batches_dispatched"):
+        if v is not None and v.wants_prewarm:   # a device engine counts
             out["crypto.verify.batch-dispatch"] = {
-                "count": inner.batches_dispatched}
-            out["crypto.verify.sigs"] = {"count": inner.sigs_verified}
+                "count": v.inner.batches_dispatched}
+            out["crypto.verify.sigs"] = {"count": v.inner.sigs_verified}
         if prefix:
             out = {k: v2 for k, v2 in out.items() if k.startswith(prefix)}
         if params.get("format") == "prometheus":
@@ -174,24 +173,22 @@ class CommandHandler:
             # start-up; null on a node with no device backend
             "device": getattr(self.app, "device", None),
         }
-        stats = getattr(v, "stats", None)
+        stats = v.stats
         if stats is not None:
             out.update(stats.to_json())
-        breaker = getattr(v, "breaker", None)
-        if breaker is not None:
-            out["breaker"] = breaker.to_json()
-        inner = getattr(v, "inner", v)
+        if v.breaker is not None:
+            out["breaker"] = v.breaker.to_json()
+        engine = v.inner
         # fleet rows (ISSUE 11): per-device breaker ring of the device
-        # backend, read without forcing a jax device resolve — the
+        # engine, read without forcing a jax device resolve — the
         # per-device drain/inflight attribution itself rides in
         # stats.to_json()["devices"] above
-        fleet = getattr(inner, "_fleet_health", None)
-        if fleet is not None:
-            out["fleet"] = fleet.to_json()
+        if engine.wants_prewarm and engine._fleet_health is not None:
+            out["fleet"] = engine._fleet_health.to_json()
         out["counters"] = {
-            "batches_dispatched": getattr(inner, "batches_dispatched", 0),
-            "sigs_verified": getattr(inner, "sigs_verified", 0),
-            "h2d_bytes": getattr(stats, "h2d_bytes", 0),
+            "batches_dispatched": engine.batches_dispatched,
+            "sigs_verified": engine.sigs_verified,
+            "h2d_bytes": stats.h2d_bytes if stats is not None else 0,
             "pending": v.pending(),
         }
         from ..crypto import keys as _keys

@@ -13,7 +13,7 @@ Semantics matched to the reference:
 - success as soon as accumulated weight >= needed_weight (weights capped
   at 255); needed_weight 0 still requires one valid signer.
 
-The verify call goes through the injected BatchSigVerifier: all
+The verify call goes through the injected SigVerifier: all
 hint-matching (signature, signer) pairs are enqueued and flushed in ONE
 batch before accumulation — under the TPU backend this is a single device
 dispatch per check.
@@ -24,7 +24,7 @@ from __future__ import annotations
 import hashlib
 from typing import Dict, List, Optional, Sequence, Tuple
 
-from ..crypto.batch_verifier import BatchSigVerifier, CpuSigVerifier
+from ..crypto.batch_verifier import CPU_VERIFIER, SigVerifier
 from ..xdr import (
     DecoratedSignature, PublicKey, Signer, SignerKey, SignerKeyType,
 )
@@ -44,11 +44,11 @@ def _hint_of(b32: bytes) -> bytes:
 class SignatureChecker:
     def __init__(self, network_hash_contents: bytes,
                  signatures: Sequence[DecoratedSignature],
-                 verifier: Optional[BatchSigVerifier] = None) -> None:
+                 verifier: Optional[SigVerifier] = None) -> None:
         self._contents_hash = network_hash_contents
         self._sigs = list(signatures)
         self._used = [False] * len(self._sigs)
-        self._verifier = verifier or CpuSigVerifier()
+        self._verifier = verifier or CPU_VERIFIER
         # hint → signature indices: each check then probes one bucket per
         # signer instead of scanning the sigs × signers cross-product (a
         # 20-sig 20-signer multisig tx is 400 hint compares per check)

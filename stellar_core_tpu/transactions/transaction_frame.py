@@ -9,7 +9,7 @@ Role parity: reference `src/transactions/TransactionFrame.cpp`:
   all-or-nothing rollback.
 Plus FeeBumpTransactionFrame (reference FeeBumpTransactionFrame.cpp).
 
-The SignatureChecker receives the injected BatchSigVerifier: under the TPU
+The SignatureChecker receives the injected SigVerifier: under the TPU
 backend every checkValid/apply becomes a batched device call site
 (SURVEY.md hot callers #2/#3).
 """
@@ -19,7 +19,7 @@ from __future__ import annotations
 from typing import List, Optional, Tuple
 
 from ..crypto.hashing import sha256
-from ..crypto.batch_verifier import BatchSigVerifier, CpuSigVerifier
+from ..crypto.batch_verifier import CPU_VERIFIER, SigVerifier
 from ..xdr import (
     EnvelopeType, FeeBumpTransactionEnvelope, LedgerKey, OperationResult,
     OperationResultCode, PublicKey, Transaction, TransactionEnvelope,
@@ -394,11 +394,11 @@ class TransactionFrame:
                                        account_threshold(acc, level))
 
     def check_valid(self, ltx_parent, current_seq: int = 0,
-                    verifier: Optional[BatchSigVerifier] = None) -> bool:
+                    verifier: Optional[SigVerifier] = None) -> bool:
         """Full validity check against (a temporary child of) ltx_parent.
         Never mutates state. Reference TransactionFrame::checkValid:594."""
         from ..ledger.ledgertxn import LedgerTxn
-        verifier = verifier or CpuSigVerifier()
+        verifier = verifier or CPU_VERIFIER
         checker = SignatureChecker(self.contents_hash(), self.signatures,
                                    verifier)
         ltx = LedgerTxn(ltx_parent)
@@ -525,7 +525,7 @@ class TransactionFrame:
         return ok
 
     def apply(self, ltx_parent,
-              verifier: Optional[BatchSigVerifier] = None,
+              verifier: Optional[SigVerifier] = None,
               stats=None) -> bool:
         """Apply under a child txn of ltx_parent; on any op failure roll back
         every op's effects (fees/seqnums were already consumed).
@@ -535,7 +535,7 @@ class TransactionFrame:
         apply latency to its wire type — the close cockpit's Python-path
         per-op histograms."""
         from ..ledger.ledgertxn import LedgerTxn
-        verifier = verifier or CpuSigVerifier()
+        verifier = verifier or CPU_VERIFIER
         checker = SignatureChecker(self.contents_hash(), self.signatures,
                                    verifier)
         self._native_meta_b = None   # this apply owns the meta again
@@ -877,7 +877,7 @@ class FeeBumpTransactionFrame:
     def check_valid(self, ltx_parent, current_seq: int = 0,
                     verifier=None) -> bool:
         from ..ledger.ledgertxn import LedgerTxn
-        verifier = verifier or CpuSigVerifier()
+        verifier = verifier or CPU_VERIFIER
         ltx = LedgerTxn(ltx_parent)
         try:
             checker = SignatureChecker(self.contents_hash(),
@@ -930,7 +930,7 @@ class FeeBumpTransactionFrame:
         # since validation, and every outer signature must be used
         from ..ledger.ledgertxn import LedgerTxn
         checker = SignatureChecker(self.contents_hash(), self.signatures,
-                                   verifier or CpuSigVerifier())
+                                   verifier or CPU_VERIFIER)
         self._native_meta_b = None   # this apply owns the meta again
         ltx = LedgerTxn(ltx_parent)
         try:

@@ -62,7 +62,8 @@ MAIN_THREAD_REGISTRY: Dict[str, str] = {}
 # line); tests/test_threads.py pins the set.
 WORKER_THREAD_REGISTRY: Dict[str, str] = {
     "crypto.verify-dispatch":
-        "ThreadedBatchVerifier batch dispatch; completes futures via "
+        "SigVerifier batch dispatch (a boundary with a clock); "
+        "completes futures via "
         "clock.post_to_main only",
     "crypto.verify-staging":
         "TpuSigVerifier double-buffer staging: packs + device_puts "

@@ -5,7 +5,7 @@ accumulate into few device dispatches —
 - TxSetFrame.check_or_trim is two-phase: one prewarm dispatch for the
   whole set, then the per-tx walk off the warm cache;
 - envelope verifies park in PendingEnvelopes' 'verifying' state and
-  complete on the main loop via ThreadedBatchVerifier;
+  complete on the main loop (a SigVerifier with a clock);
 - a multi-node simulation closes ledgers with the async backend enabled;
 - AOT warmup removes lazy kernel compiles from the consensus path.
 """
@@ -14,7 +14,7 @@ import pytest
 
 from stellar_core_tpu.crypto import keys as K
 from stellar_core_tpu.crypto.batch_verifier import (
-    ThreadedBatchVerifier, TpuSigVerifier,
+    SigVerifier, TpuSigVerifier,
 )
 from stellar_core_tpu.herder.txset import TxSetFrame
 from stellar_core_tpu.simulation import topologies
@@ -46,14 +46,14 @@ def test_txset_100_txs_at_most_2_dispatches():
     txset = TxSetFrame(ledger.network_id, b"\x00" * 32, frames)
 
     _clear_verify_cache()
-    v = TpuSigVerifier()
-    v.BUCKETS = (128,)
+    v = SigVerifier(TpuSigVerifier())
+    v.inner.BUCKETS = (128,)
     ok, removed = txset.check_or_trim(ledger.root, v, trim=False)
     assert ok and not removed
-    assert v.batches_dispatched <= 2, (
+    assert v.inner.batches_dispatched <= 2, (
         "expected <=2 device dispatches for 100-tx txset, got %d"
-        % v.batches_dispatched)
-    assert v.sigs_verified >= 100
+        % v.inner.batches_dispatched)
+    assert v.inner.sigs_verified >= 100
 
 
 def test_txset_prewarm_correct_rejections():
@@ -73,8 +73,8 @@ def test_txset_prewarm_correct_rejections():
     txset = TxSetFrame(ledger.network_id, b"\x00" * 32, frames)
 
     _clear_verify_cache()
-    v = TpuSigVerifier()
-    v.BUCKETS = (128,)
+    v = SigVerifier(TpuSigVerifier())
+    v.inner.BUCKETS = (128,)
     ok, removed = txset.check_or_trim(ledger.root, v, trim=True)
     assert not ok
     assert removed == [bad]
@@ -114,7 +114,7 @@ def test_envelope_verifies_accumulate_one_dispatch():
         innerSets=[])
     clock = VirtualClock(ClockMode.VIRTUAL_TIME)
     app = Application(clock, cfg)
-    assert isinstance(app.sig_verifier, ThreadedBatchVerifier)
+    assert app.sig_verifier.name == "threaded"    # flushes on the worker
     app.sig_verifier.inner.BUCKETS = (32,)
     app.start()
 
@@ -299,7 +299,7 @@ def test_crank_until_flushes_pending_verifies():
     cfg.CONSENSUS_STUCK_TIMEOUT_SECONDS = 10000.0
     clock = VirtualClock(ClockMode.VIRTUAL_TIME)
     app = Application(clock, cfg)
-    assert isinstance(app.sig_verifier, ThreadedBatchVerifier)
+    assert app.sig_verifier.name == "threaded"    # flushes on the worker
     app.sig_verifier.inner.BUCKETS = (32,)
     app.start()
 
@@ -425,3 +425,121 @@ def test_live_path_latency_slo():
     st, m = cmd(target, "metrics")
     assert st == 200
     assert m["crypto.verify.latency"]["count"] > 0
+
+
+# ------------------------------------------- one boundary, every backend (PR 29)
+
+BACKENDS = ("cpu", "cpu-resilient", "tpu", "tpu-async")
+
+
+def _mixed_triples(tag: bytes, n: int = 9, bad=(2, 5)):
+    """n signatures over `tag`, those at `bad` corrupted in the last byte."""
+    from stellar_core_tpu.crypto.keys import SecretKey
+    out = []
+    for i in range(n):
+        sk = SecretKey.from_seed(bytes([i + 1]) * 32)
+        msg = tag + b"-%d" % i
+        sig = sk.sign(msg)
+        if i in bad:
+            sig = sig[:-1] + bytes([sig[-1] ^ 1])
+        out.append((sk.public_key, sig, msg))
+    return out
+
+
+def _flushed(v, clock, keyed) -> list:
+    """enqueue + flush, cranked until every future is complete."""
+    import time
+    futs = [v.enqueue(k, s, m) for (k, s, m) in keyed]
+    v.flush()
+    deadline = time.time() + 180
+    while not all(f.done() for f in futs) and time.time() < deadline:
+        clock.crank_ready()     # the worker posts completions to the clock
+        time.sleep(0.002)
+    return [f.result() for f in futs]
+
+
+def _drains(v) -> int:
+    return sum(d["drains"] for d in
+               v.stats.to_json()["drains"]["by_backend"].values())
+
+
+def _built(backend):
+    from stellar_core_tpu.crypto.batch_verifier import make_verifier
+    from stellar_core_tpu.util.timer import ClockMode, VirtualClock
+    clock = VirtualClock(ClockMode.VIRTUAL_TIME)
+    v = make_verifier(backend, clock, cache=K.VerdictCache())
+    if v.wants_prewarm:
+        v.inner.BUCKETS = (32,)     # jax on the CPU, as the other tests here
+    return v, clock
+
+
+@pytest.mark.parametrize("backend", BACKENDS)
+def test_every_backend_answers_as_raw_verify_on_every_path(backend):
+    """A mixed batch gives raw_verify's verdicts through enqueue + flush,
+    prewarm_many and verify_many; a second pass of the first two is
+    served from the verdict cache with no dispatch, and verify_many goes
+    past the cache every time (the catchup driver's negative control
+    calls it to reach the device)."""
+    v, clock = _built(backend)
+    store = v.cache.store
+    assert v.name == {"cpu": "cpu", "cpu-resilient": "resilient",
+                      "tpu": "resilient", "tpu-async": "threaded"}[backend]
+
+    keyed = _mixed_triples(b"enqueue-" + backend.encode())
+    want = [K.raw_verify(k.key_bytes, s, m) for (k, s, m) in keyed]
+    assert want.count(False) == 2
+    assert _flushed(v, clock, keyed) == want
+    assert v.pending() == 0 and store.misses == len(keyed)
+    drains = _drains(v)
+    again = [v.enqueue(k, s, m) for (k, s, m) in keyed]
+    assert all(f.done() for f in again)         # before any flush
+    assert [f.result() for f in again] == want
+    assert (store.misses, store.hits, _drains(v)) == \
+        (len(keyed), len(keyed), drains)
+
+    triples = [(k.key_bytes, s, m)
+               for (k, s, m) in _mixed_triples(b"prewarm-" + backend.encode())]
+    assert v.prewarm_many(triples) == want
+    assert _drains(v) == drains + 1
+    assert v.prewarm_many(triples) == want
+    assert _drains(v) == drains + 1 and store.hits == 2 * len(keyed)
+
+    triples = [(k.key_bytes, s, m)
+               for (k, s, m) in _mixed_triples(b"drain-" + backend.encode())]
+    probes = (store.hits, store.misses)
+    assert v.verify_many(triples) == want
+    assert v.verify_many(triples) == want
+    assert _drains(v) == drains + 3 and (store.hits, store.misses) == probes
+    if v.wants_prewarm:
+        assert v.inner.batches_dispatched == 4      # every drain, the engine
+        assert v.inner.sigs_verified == 4 * len(keyed)
+
+
+@pytest.mark.parametrize("backend", ["tpu", "tpu-async"])
+@pytest.mark.parametrize("control", ["accept_all", "half_batch"])
+def test_a_replaced_engine_verify_many_answers_on_every_path(backend,
+                                                              control):
+    """benchmark/control.py's negative controls replace
+    `app.sig_verifier.inner.verify_many` on the instance: every path to
+    the device must then return the replacement's verdicts, or a control
+    run would read `correct`."""
+    from types import SimpleNamespace
+    from benchmark import control as controls
+    v, clock = _built(backend)
+    getattr(controls, control)(SimpleNamespace(sig_verifier=v))
+    n = 8
+    every = tuple(range(n))
+    # all corrupted: accept-all answers True for each, half-batch for
+    # the half of each batch that it leaves out
+    want = [True] * n if control == "accept_all" \
+        else [False] * (n // 2) + [True] * (n // 2)
+    keyed = _mixed_triples(b"ctl-enqueue-" + backend.encode(), n, every)
+    assert _flushed(v, clock, keyed) == want
+    triples = [(k.key_bytes, s, m) for (k, s, m) in
+               _mixed_triples(b"ctl-prewarm-" + backend.encode(), n, every)]
+    assert v.prewarm_many(triples) == want
+    triples = [(k.key_bytes, s, m) for (k, s, m) in
+               _mixed_triples(b"ctl-drain-" + backend.encode(), n, every)]
+    assert v.verify_many(triples) == want
+    assert v.breaker.state == "closed"      # and nothing fell back
+    assert "cpu" not in v.stats.to_json()["drains"]["by_backend"]

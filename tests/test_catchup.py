@@ -320,10 +320,12 @@ def test_prewarm_batches_checkpoint_sigs(publisher):
     the verifier (SURVEY.md §3.4 TPU batch site)."""
     app_a, tmp_path, archive_root = publisher
 
-    from stellar_core_tpu.crypto.batch_verifier import CpuSigVerifier
+    from stellar_core_tpu.crypto.batch_verifier import (
+        CpuSigVerifier, SigVerifier)
 
-    class CountingVerifier(CpuSigVerifier):
+    class CountingVerifier(SigVerifier):
         def __init__(self):
+            super().__init__(CpuSigVerifier(), max_pending=0)
             self.batches = []
             self.distinct = set()
 

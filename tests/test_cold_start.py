@@ -24,17 +24,16 @@ from stellar_core_tpu.parallel.device import (
 cache = configure_compile_cache()
 assert cache == os.environ["SCT_TEST_CACHE"], cache
 from stellar_core_tpu.crypto.batch_verifier import (
-    TpuSigVerifier, VerifierStats)
+    TpuSigVerifier, VerifierContext, VerifierStats)
 from stellar_core_tpu.crypto.keys import SecretKey
 before = compile_cache_entries(cache)
-v = TpuSigVerifier()
+v = TpuSigVerifier(VerifierContext(stats=VerifierStats()))
 v.BUCKETS = (32,)
-v.stats = VerifierStats()
 v.warmup(wait=True)
 sk = SecretKey.from_seed(b"\x31" * 32)
 assert v.verify_many([(sk.public_key.key_bytes, sk.sign(b"m"), b"m")]) \
     == [True]
-j = v.stats.to_json()
+j = v.ctx.stats.to_json()
 print("COLD_JSON " + json.dumps(
     {"cache": j["warmup"]["buckets"]["32"]["cache"],
      "dir": j["compile_cache"]["dir"],

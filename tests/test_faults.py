@@ -12,7 +12,7 @@ ArchivePool failover policy, and the admin `faults` endpoint.
 import pytest
 
 from stellar_core_tpu.crypto.batch_verifier import (
-    CircuitBreaker, CpuSigVerifier, ResilientBatchVerifier, make_verifier,
+    CircuitBreaker, CpuSigVerifier, SigVerifier, make_verifier,
 )
 from stellar_core_tpu.crypto.keys import SecretKey
 from stellar_core_tpu.main.config import Config
@@ -149,10 +149,10 @@ def test_trip_during_drain_returns_correct_results():
     from stellar_core_tpu.crypto import keys as _keys
     _keys.flush_verify_cache()
     clock = VirtualClock(ClockMode.VIRTUAL_TIME)
-    v = make_verifier("cpu-resilient", clock,
+    faults = FaultInjector()
+    faults.configure("device.dispatch", count=1)
+    v = make_verifier("cpu-resilient", clock, faults=faults,
                       breaker_threshold=1, breaker_cooldown=5.0)
-    v.faults = FaultInjector()
-    v.faults.configure("device.dispatch", count=1)
     triples = _signed_triples(6, bad={2, 4})
     futs = [v.enqueue(k, s, m) for (k, s, m) in triples]
     v.flush()                                      # dispatch fails, trips
@@ -181,12 +181,12 @@ def test_tpu_flush_recompletes_futures_on_dispatch_exception():
     from stellar_core_tpu.crypto import keys as _keys
     from stellar_core_tpu.crypto.batch_verifier import TpuSigVerifier
     _keys.flush_verify_cache()
-    v = TpuSigVerifier()
+    v = SigVerifier(TpuSigVerifier())
 
     def boom(triples):
         raise RuntimeError("device gone")
 
-    v.verify_many = boom
+    v.inner.verify_many = boom
     triples = _signed_triples(4, bad={1})
     futs = [v.enqueue(k, s, m) for (k, s, m) in triples]
     v.flush()
@@ -199,10 +199,10 @@ def test_resilient_prewarm_routes_through_breaker():
     _keys.flush_verify_cache()
     clock = VirtualClock(ClockMode.VIRTUAL_TIME)
     m = MetricsRegistry(now_fn=clock.now)
-    v = make_verifier("cpu-resilient", clock, metrics=m,
+    faults = FaultInjector(metrics=m)
+    faults.configure("device.dispatch", count=1)
+    v = make_verifier("cpu-resilient", clock, metrics=m, faults=faults,
                       breaker_threshold=1, breaker_cooldown=5.0)
-    v.faults = FaultInjector(metrics=m)
-    v.faults.configure("device.dispatch", count=1)
     triples = [(k.key_bytes, s, msg)
                for (k, s, msg) in _signed_triples(5, bad={0})]
     out = v.prewarm_many(triples)

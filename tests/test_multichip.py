@@ -11,7 +11,7 @@ import jax
 import pytest
 
 from stellar_core_tpu.crypto.batch_verifier import (
-    TpuSigVerifier, VerifierStats)
+    TpuSigVerifier, VerifierContext, VerifierStats)
 from stellar_core_tpu.crypto.keys import SecretKey
 from stellar_core_tpu.ops.ed25519 import L, verify_oracle
 from stellar_core_tpu.parallel.mesh import (
@@ -125,13 +125,13 @@ def test_production_size_sharded_batch_with_uneven_tail():
     bad = {i for i in range(n) if i % 997 == 1}   # spread across both chunks
     for i in bad:
         sigs[i] = bytes([sigs[i][0] ^ 1]) + sigs[i][1:]
-    v = TpuSigVerifier(shard_threshold=1)
-    v.stats = VerifierStats()
+    v = TpuSigVerifier(VerifierContext(stats=VerifierStats()),
+                       shard_threshold=1)
     got = v.verify_many(list(zip(pubs, sigs, msgs)))
     assert got == [i not in bad for i in range(n)]
     assert v.batches_dispatched == 2          # 8192 bucket + 147-tail bucket
     # one packed array a dispatch, 128 bytes a lane of its bucket
-    assert v.stats.h2d_bytes == 128 * (8192 + 512)
+    assert v.ctx.stats.h2d_bytes == 128 * (8192 + 512)
     assert v.sigs_verified == n
     assert v._sharded_fn is not None          # mesh path actually taken
     for i in (0, 1, 8191, 8192, n - 1):       # sampled oracle agreement

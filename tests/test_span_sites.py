@@ -10,7 +10,6 @@ import time
 import pytest
 
 from stellar_core_tpu.crypto import keys as K
-from stellar_core_tpu.crypto.batch_verifier import ThreadedBatchVerifier
 from stellar_core_tpu.crypto.keys import SecretKey
 from stellar_core_tpu.main.application import Application
 from stellar_core_tpu.main.config import Config
@@ -193,7 +192,7 @@ def test_threaded_batch_records_a_queue_wait_per_class():
     app = device_app("tpu-async")
     try:
         v = app.sig_verifier
-        assert isinstance(v, ThreadedBatchVerifier)
+        assert v.name == "threaded"     # flushes on the worker
         triples = signed_triples(3)
         tracer = fresh_trace(app)
         futs = []
@@ -239,7 +238,7 @@ def test_enqueue_stamps_the_tracer_clock_only_while_tracing():
         (k, s, m), = signed_triples(1)
         K.flush_verify_cache()
         v.enqueue(PublicKey.ed25519(k), s, m, cls="scp")
-        assert calls == [] and v._pending[0][3:] == ("scp", 0.0)
+        assert calls == [] and v._pending[0][4:] == ("scp", 0.0)
     finally:
         app.stop()
 
@@ -373,6 +372,35 @@ def test_span_sites_pass_only_names_and_len_as_tags():
                                               node.lineno, kw.arg))
     assert sites >= 30, sites
     assert bad == []
+
+
+def test_only_the_boundarys_two_cache_methods_touch_the_verdict_store():
+    """The verdict-cache policy lives in one place: in batch_verifier.py
+    `cache.store.maybe_get` / `.put` appear in SigVerifier._cache_probe
+    and ._cache_store and nowhere else, so no engine and no second queue
+    can probe or feed the cache its own way."""
+    import ast
+    from stellar_core_tpu.crypto import batch_verifier
+    tree = ast.parse(open(batch_verifier.__file__).read())
+    sites = {}
+
+    def walk(node, where):
+        for child in ast.iter_child_nodes(node):
+            inside = where
+            if isinstance(child, (ast.ClassDef, ast.FunctionDef)):
+                inside = where + (child.name,)
+            if isinstance(child, ast.Attribute) and \
+                    child.attr in ("maybe_get", "put", "store"):
+                sites.setdefault(".".join(inside), set()).add(child.attr)
+            walk(child, inside)
+
+    walk(tree, ())
+    assert sites == {"SigVerifier._cache_probe": {"store", "maybe_get"},
+                     "SigVerifier._cache_store": {"store", "put"}}
+    holders = [c.name for c in tree.body if isinstance(c, ast.ClassDef)
+               and any(isinstance(n, ast.Attribute) and n.attr == "_pending"
+                       for n in ast.walk(c))]
+    assert holders == ["SigVerifier"]       # and one pending queue
 
 
 def test_disabled_sites_read_no_clock_and_keep_no_state(tpu_app):

@@ -15,9 +15,8 @@ import pytest
 
 from stellar_core_tpu.crypto import keys as K
 from stellar_core_tpu.crypto.batch_verifier import (
-    BatchSigVerifier, CircuitBreaker, CpuSigVerifier,
-    ResilientBatchVerifier, ThreadedBatchVerifier, TpuSigVerifier,
-    VerifierStats, make_verifier)
+    CircuitBreaker, CpuSigVerifier, SigVerifier,
+    TpuSigVerifier, VerifierContext, VerifierStats, make_verifier)
 from stellar_core_tpu.crypto.keys import SecretKey
 from stellar_core_tpu.util.metrics import MetricsRegistry, render_prometheus
 from stellar_core_tpu.util.tracing import FlightRecorder, Tracer
@@ -87,10 +86,10 @@ def test_bucket_dispatch_histograms_and_occupancy():
 def test_fallback_drain_attributed_to_serving_backend():
     """A drain served by the CPU fallback (primary raising) is
     attributed to "cpu", never to the device backend — and the fallback
-    span names the server (ISSUE 6 satellite: the ResilientBatchVerifier
-    attributes drains to the backend that actually served them)."""
+    span names the server (ISSUE 6 satellite: the boundary attributes
+    drains to the engine that actually served them)."""
 
-    class _FailingDevice(BatchSigVerifier):
+    class _FailingDevice(CpuSigVerifier):
         name = "tpu"
 
         def verify_many(self, triples):
@@ -100,16 +99,9 @@ def test_fallback_drain_attributed_to_serving_backend():
     tr = Tracer()
     tr.enable()
     stats = VerifierStats(metrics=reg, tracer=tr)
-    primary = _FailingDevice()
-    primary.stats = stats
-    fb = CpuSigVerifier()
-    fb.stats = stats
-    fb.tracer = tr
-    r = ResilientBatchVerifier(primary, fb,
-                               CircuitBreaker(threshold=2))
-    r.stats = stats
-    r.tracer = tr
-    r.metrics = reg
+    ctx = VerifierContext(stats=stats, tracer=tr, metrics=reg)
+    r = SigVerifier(_FailingDevice(ctx), fallback=CpuSigVerifier(ctx),
+                    breaker=CircuitBreaker(threshold=2))
     _clear_verify_cache()
     res = r.verify_many(_triples(3))
     assert all(res)
@@ -132,11 +124,9 @@ def test_threaded_queue_depth_inflight_and_wait(monkeypatch):
     _clear_verify_cache()
     reg = MetricsRegistry()
     clock = VirtualClock(ClockMode.VIRTUAL_TIME)
-    inner = CpuSigVerifier()
-    v = ThreadedBatchVerifier(inner, clock, metrics=reg)
     stats = VerifierStats(metrics=reg, now_fn=clock.now)
-    inner.stats = stats
-    v.stats = stats
+    v = SigVerifier(CpuSigVerifier(
+        VerifierContext(stats=stats, metrics=reg)), clock=clock)
     triples = _triples(4, tag=b"queue")
     futs = []
     for i, (k, s, m) in enumerate(triples):
@@ -184,14 +174,14 @@ def test_warmup_instants_stamps_and_cache_classification(tmp_path):
     reg = MetricsRegistry()
     tr = Tracer()
     tr.enable()
-    v = TpuSigVerifier()
+    v = TpuSigVerifier(VerifierContext(
+        stats=VerifierStats(metrics=reg, tracer=tr, now_fn=clock.now)))
     v.BUCKETS = (128, 512)
-    v.stats = VerifierStats(metrics=reg, tracer=tr, now_fn=clock.now)
     _stub_warmup(v, {128: ["compile_requests_use_cache", "cache_misses"],
                      512: ["compile_requests_use_cache", "cache_hits"]})
     v.warmup(wait=True)
     assert v._warmed
-    w = v.stats.warmup_json()
+    w = v.ctx.stats.warmup_json()
     assert w["state"] == "done"
     assert w["planned"] == [128, 512]
     assert w["buckets"]["128"]["cache"] == "miss"
@@ -199,7 +189,7 @@ def test_warmup_instants_stamps_and_cache_classification(tmp_path):
     # app-clock stamps, not wall-clock
     assert w["begun_t"] == 1000.0
     assert all(b["t"] == 1000.0 for b in w["buckets"].values())
-    cc = v.stats.compile_cache
+    cc = v.ctx.stats.compile_cache
     assert cc["enabled"] is True and cc["hits"] == 1 and cc["misses"] == 1
     names = [s.name for s in tr.spans()]
     assert names.count("verifier.warmup.bucket") == 2
@@ -224,15 +214,14 @@ def test_warmup_fast_compile_classifies_unknown_not_hit():
     compile-cache hit counter (a node silently re-paying sub-threshold
     compiles every restart must not read as a healthy cache)."""
     reg = MetricsRegistry()
-    v = TpuSigVerifier()
+    v = TpuSigVerifier(VerifierContext(stats=VerifierStats(metrics=reg)))
     v.BUCKETS = (128,)
-    v.stats = VerifierStats(metrics=reg)
     _stub_warmup(v, {128: ["compile_requests_use_cache"]})
     v.warmup(wait=True)
-    w = v.stats.warmup_json()
+    w = v.ctx.stats.warmup_json()
     assert w["state"] == "done"
     assert w["buckets"]["128"]["cache"] == "unknown"
-    cc = v.stats.compile_cache
+    cc = v.ctx.stats.compile_cache
     assert cc["hits"] == 0 and cc["misses"] == 0 and cc["unknown"] == 1
     m = reg.to_json()
     assert m["verifier.compile-cache.hit"]["count"] == 0
@@ -245,9 +234,9 @@ def test_warmup_failure_dumps_flight(tmp_path):
     tr = Tracer()
     tr.enable()
     fr = FlightRecorder(tr, metrics=reg, out_dir=str(tmp_path))
-    v = TpuSigVerifier()
+    v = TpuSigVerifier(VerifierContext(stats=VerifierStats(
+        metrics=reg, tracer=tr, flight_recorder=fr)))
     v.BUCKETS = (128,)
-    v.stats = VerifierStats(metrics=reg, tracer=tr, flight_recorder=fr)
 
     def boom(b):
         raise RuntimeError("no device")
@@ -256,8 +245,8 @@ def test_warmup_failure_dumps_flight(tmp_path):
     with pytest.raises(RuntimeError, match="no device"):
         v.warmup(wait=True)
     assert not v._warmed
-    assert v.stats.warmup["state"] == "failed"
-    assert "no device" in v.stats.warmup["error"]
+    assert v.ctx.stats.warmup["state"] == "failed"
+    assert "no device" in v.ctx.stats.warmup["error"]
     m = reg.to_json()
     assert m["verifier.warmup.failure"]["count"] == 1
     assert m["verifier.warmup.state"]["value"] == 3      # failed

@@ -22,7 +22,8 @@ import numpy as np
 import pytest
 
 from stellar_core_tpu.crypto.batch_verifier import (
-    DeviceFleetHealth, TpuSigVerifier, VerifierStats, warmup_plan)
+    DeviceFleetHealth, TpuSigVerifier, VerifierContext, VerifierStats,
+    warmup_plan)
 from stellar_core_tpu.crypto.keys import SecretKey
 from stellar_core_tpu.ops.ed25519 import verify_oracle
 from stellar_core_tpu.util.faults import FaultInjector
@@ -69,7 +70,7 @@ def _fleet_verifier(devices, ndev, stats=None):
         v = TpuSigVerifier(shard_threshold=1, devices=devices[:ndev])
         v.BUCKETS = (128,)
         _FLEET_CACHE[ndev] = v
-    v.stats = stats
+    v.ctx.stats = stats     # the cached engine's context, this test's cockpit
     return v
 
 
@@ -229,9 +230,9 @@ def test_staging_overlap_double_buffer():
     windows genuinely ran concurrently)."""
     reg = MetricsRegistry()
     st = VerifierStats(metrics=reg)
-    v = _StubbedFleet(1, dispatch_sleep_s=0.05, stage_sleep_s=0.03)
+    v = _StubbedFleet(1, dispatch_sleep_s=0.05, stage_sleep_s=0.03,
+                      ctx=VerifierContext(stats=st))
     v.BUCKETS = (128,)
-    v.stats = st
     triples = _batch(128 * 3)           # 3 chunks -> 2 staged overlaps
     assert all(v.verify_many(triples))
     j = st.to_json()
@@ -252,11 +253,10 @@ def test_staging_stall_fault_degrades_to_synchronous():
     the stall is counted."""
     reg = MetricsRegistry()
     st = VerifierStats(metrics=reg)
-    v = _StubbedFleet(1)
+    faults = FaultInjector(seed=7, metrics=reg)
+    faults.configure("verify.staging-stall", count=1)
+    v = _StubbedFleet(1, ctx=VerifierContext(stats=st, faults=faults))
     v.BUCKETS = (128,)
-    v.stats = st
-    v.faults = FaultInjector(seed=7, metrics=reg)
-    v.faults.configure("verify.staging-stall", count=1)
     triples = _batch(128 * 2)
     assert all(v.verify_many(triples))
     j = st.to_json()
@@ -274,14 +274,14 @@ def test_device_lost_trips_per_device_and_degrades_to_n_minus_1():
     reg = MetricsRegistry()
     st = VerifierStats(metrics=reg)
     clock = {"t": 1000.0}
-    v = _StubbedFleet(4, now_fn=lambda: clock["t"],
+    faults = FaultInjector(seed=7, metrics=reg)
+    faults.configure("verify.device-lost", count=2)
+    v = _StubbedFleet(4, ctx=VerifierContext(stats=st, faults=faults),
+                      now_fn=lambda: clock["t"],
                       device_breaker_threshold=2,
                       device_breaker_cooldown=30.0)
     v.BUCKETS = (128,)
     v.SHARD_MIN_BATCH = 1
-    v.stats = st
-    v.faults = FaultInjector(seed=7, metrics=reg)
-    v.faults.configure("verify.device-lost", count=2)
     triples = _batch(64)
     for _ in range(3):
         assert all(v.verify_many(triples))
@@ -318,12 +318,10 @@ def test_device_lost_trips_per_device_and_degrades_to_n_minus_1():
 def test_fleet_dispatch_failure_counts_every_participant():
     """A whole-mesh dispatch failure cannot name the guilty chip: every
     participating device's breaker counts it, and the exception still
-    reaches the resilient layer above."""
-    st = VerifierStats()
-    v = _StubbedFleet(2)
+    reaches the boundary above."""
+    v = _StubbedFleet(2, ctx=VerifierContext(stats=VerifierStats()))
     v.BUCKETS = (128,)
     v.SHARD_MIN_BATCH = 1
-    v.stats = st
 
     def boom(idxs):
         def fn(*args):
@@ -393,9 +391,8 @@ def test_warmup_plan_persisted_and_used(tmp_path):
     st = VerifierStats()
     for _ in range(4):
         st.record_bucket_dispatch(512, 512, 0)
-    v = TpuSigVerifier()
+    v = TpuSigVerifier(VerifierContext(stats=st))
     v.warmup_plan_path = plan_path
-    v.stats = st
     assert v.save_warmup_plan() == plan_path
     path = plan_path
     with open(path) as fh:
@@ -404,37 +401,35 @@ def test_warmup_plan_persisted_and_used(tmp_path):
     assert blob["traffic"] == {"512": 4}
 
     # fresh process analog: same plan path, no cockpit history
-    v2 = TpuSigVerifier()
+    v2 = TpuSigVerifier(VerifierContext(stats=VerifierStats()))
     v2.warmup_plan_path = plan_path
-    v2.stats = VerifierStats()
     compiled = []
     v2._compile_bucket = compiled.append
     v2.warmup(wait=True)
     assert compiled == [512]
-    w = v2.stats.warmup_json()
+    w = v2.ctx.stats.warmup_json()
     assert w["state"] == "done"
     assert w["source"] == "cockpit"
     assert w["planned"] == [512]
 
     # a plan that no longer fits the candidate ladder is rejected
-    v3 = TpuSigVerifier()
+    v3 = TpuSigVerifier(VerifierContext(stats=VerifierStats()))
     v3.warmup_plan_path = plan_path
     v3.BUCKETS = (128, 2048)
-    v3.stats = VerifierStats()
     compiled3 = []
     v3._compile_bucket = compiled3.append
     v3.warmup(wait=True)
     assert compiled3 == [128, 2048]
-    assert v3.stats.warmup_json()["source"] == "default"
+    assert v3.ctx.stats.warmup_json()["source"] == "default"
 
 
 def test_warmup_plan_not_saved_without_evidence_or_path(tmp_path):
     v = TpuSigVerifier()
     v.warmup_plan_path = str(tmp_path / "plan.json")
     assert v.save_warmup_plan() is None          # no stats at all
-    v.stats = VerifierStats()
+    v.ctx.stats = VerifierStats()
     assert v.save_warmup_plan() is None          # stats but no traffic
-    v.stats.record_bucket_dispatch(512, 512, 0)
+    v.ctx.stats.record_bucket_dispatch(512, 512, 0)
     v.warmup_plan_path = None                    # no node state dir
     assert v.save_warmup_plan() is None
 
@@ -452,7 +447,7 @@ def test_warmup_plan_lives_beside_the_bucket_directory(tmp_path):
     app.enable_buckets(str(tmp_path / "node" / "buckets"))
     assert dev.warmup_plan_path == \
         str(tmp_path / "node" / "warmup_buckets.json")
-    dev.stats.record_bucket_dispatch(512, 512, 0)
+    dev.ctx.stats.record_bucket_dispatch(512, 512, 0)
     app.stop()                                   # persists the plan
     assert os.path.exists(dev.warmup_plan_path)
 
@@ -479,7 +474,7 @@ def test_device_fleet_health_gauge_sync_and_json():
     st = VerifierStats(metrics=reg)
 
     class _Owner:
-        stats = st
+        ctx = VerifierContext(stats=st)
 
     h = DeviceFleetHealth(2, threshold=1, cooldown_s=5.0,
                           now_fn=lambda: 0.0, owner=_Owner())

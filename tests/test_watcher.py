@@ -403,7 +403,8 @@ def test_a_stack_with_its_own_cache_trusts_no_other_verdict():
     shared = make_verifier("cpu")
     own = make_verifier("cpu-resilient", cache=K.VerdictCache())
     assert shared.cache is K.PROCESS_CACHE
-    assert own.cache is own.primary.cache is own.fallback.cache
+    assert own.ctx is own.engine.ctx is own.fallback.ctx
+    assert own.cache is own.ctx.cache
     hits = K.verify_cache_stats()["hits"]
     assert shared.enqueue(sk.public_key, sig, msg).result()
     assert K.verify_cache_stats()["hits"] == hits + 1
