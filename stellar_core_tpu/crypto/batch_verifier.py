@@ -81,6 +81,7 @@ like any Thread(target=...) site.
 
 from __future__ import annotations
 
+import bisect
 import threading
 from typing import Callable, List, Optional, Sequence, Tuple
 
@@ -152,6 +153,10 @@ class VerifierStats:
                         "last_overlap_pct": None, "stalls": 0}
         self.queue = {"depth": 0, "inflight": 0,
                       "wait_last_mean_ms": None, "wait_last_max_ms": None}
+        # streamed checkpoint drains (DrainStream): chunks handed to the
+        # worker, chunks landed in the verdict cache, closes that found
+        # their chunk not yet landed
+        self.drain_stream = {"chunks": 0, "landed": 0, "gated": 0}
         self.warmup = {"state": "idle", "planned": [], "source": None,
                        "begun_t": None, "done_t": None, "error": None,
                        "buckets": {}}
@@ -306,6 +311,11 @@ class VerifierStats:
             self.staging["stalls"] += 1
         self.metrics.new_meter("verifier.staging.stall").mark()
         tracer_instant(self.tracer, "verifier.staging.stall", cat="crypto")
+
+    def record_drain_stream(self, event: str) -> None:
+        """One `chunks` / `landed` / `gated` event of a streamed drain."""
+        with self._lock:
+            self.drain_stream[event] += 1
 
     # -- cockpit-driven bucket selection -------------------------------------
     def bucket_traffic(self, candidates) -> dict:
@@ -467,6 +477,7 @@ class VerifierStats:
             devices = {str(i): dict(d)
                        for i, d in sorted(self.devices.items())}
             staging = dict(self.staging)
+            drain_stream = dict(self.drain_stream)
             queue = dict(self.queue)
             cc = dict(self.compile_cache)
         return {
@@ -478,6 +489,7 @@ class VerifierStats:
             "buckets": buckets,
             "devices": devices,
             "staging": staging,
+            "drain_stream": drain_stream,
             "warmup": self.warmup_json(),
             "compile_cache": cc,
             "queue": queue,
@@ -565,7 +577,14 @@ class VerifierContext:
     Config.VERIFY_CACHE_SCOPE is "node")."""
 
     __slots__ = ("cache", "tracer", "metrics", "faults", "stats",
-                 "flight_recorder")
+                 "flight_recorder", "ahead")
+
+    # what a span is called on a streamed drain's worker thread
+    # (DrainStream): its stage and its wait for the device run beside
+    # the thread that replays, not in its way, and a reader of spans
+    # sees names, not threads
+    AHEAD_NAMES = {"crypto.stage": "crypto.stage_ahead",
+                   "crypto.device_wait": "crypto.device_wait_ahead"}
 
     def __init__(self, cache=None, tracer=None, metrics=None, faults=None,
                  stats=None, flight_recorder=None) -> None:
@@ -575,8 +594,17 @@ class VerifierContext:
         self.faults = faults                    # util/faults.py
         self.stats = stats                      # the VerifierStats cockpit
         self.flight_recorder = flight_recorder
+        # `.cause` on a streamed drain's worker thread: the span that
+        # handed the chunk over
+        self.ahead = threading.local()
 
     def span(self, name: str, **tags):
+        if name in self.AHEAD_NAMES and \
+                enabled_tracer(self.tracer) is not None:
+            cause = getattr(self.ahead, "cause", None)
+            if cause is not None:
+                return tracer_span(self.tracer, self.AHEAD_NAMES[name],
+                                   cat="crypto", cause=cause, **tags)
         return tracer_span(self.tracer, name, cat="crypto", **tags)
 
 
@@ -1301,6 +1329,7 @@ class SigVerifier:
         self._pending: List[Tuple[Triple, bytes, VerifyFuture, float,
                                   str, float]] = []
         self._inflight = False
+        self._drain: Optional[DrainStream] = None   # the one open_drain gave
 
     # -- what others read ----------------------------------------------------
     @property
@@ -1385,6 +1414,21 @@ class SigVerifier:
                 for (i, _ck), ok in zip(misses, results):
                     out[i] = ok
             return out  # type: ignore[return-value]
+
+    def open_drain(self) -> "DrainStream":
+        """A streamed drain (catchup's checkpoint replay): one at a
+        time, so whatever a former one still holds is cancelled and its
+        chunk in flight joined first."""
+        self.stop()
+        self._drain = DrainStream(self)
+        return self._drain
+
+    def stop(self) -> None:
+        """Application.stop(): no worker of this boundary outlives the
+        node, and none writes the verdict cache after it."""
+        drain, self._drain = self._drain, None
+        if drain is not None:
+            drain.close()
 
     def verify_many(self, triples: Sequence[Triple]) -> List[bool]:
         """One dispatch, past the cache: breaker, engine, on failure the
@@ -1582,6 +1626,157 @@ class SigVerifier:
             # verifies enqueued while the batch was in flight form
             # the next batch immediately
             self.flush()
+
+
+class DrainStream:
+    """A checkpoint drain that streams (ISSUE 30): the caller feeds
+    triples in frame order as it collects them and goes on with its own
+    work; the misses leave for the device a top bucket at a time on one
+    worker (`catchup.prewarm-pipeline`), one chunk in flight, in order,
+    and land in the verdict cache chunk by chunk. `feed` returns a gate
+    position and `wait(position)` returns once every miss fed up to
+    there has landed, so a ledger closes as soon as its own chunk has
+    landed while later chunks still run.
+
+    Each chunk goes through SigVerifier.verify_many (breaker, fault
+    point, fallback, the engine's verify_many looked up at the call)
+    and SigVerifier._cache_store, as prewarm_many's misses do, cut at
+    the same places: the dispatches are those of one prewarm_many over
+    the same triples. A chunk that raises lands with no verdicts stored
+    (cache warm only: the closes verify what it held). The probe runs on
+    the feeding thread at every `feed`, so a key is a miss until its
+    chunk has landed: a second feed of keys that may be in flight waits
+    for `position` first.
+
+    `submit` is the ungated use of the same worker (the cpu + native
+    path): a whole prewarm_many beside the closes, which verify inline
+    whatever it has not reached."""
+
+    def __init__(self, verifier: SigVerifier) -> None:
+        self._v = verifier
+        self._cv = threading.Condition()
+        self._jobs: list = []       # in-order work for the one worker
+        self._misses: List[Tuple[Triple, bytes]] = []   # fed, not handed
+        self._ends: List[int] = []  # position at each handed chunk's end
+        self.position = 0           # misses fed so far
+        self._landed = 0            # position landed through
+        self._closed = False
+        self._thread: Optional[threading.Thread] = None
+
+    # -- the feeding thread --------------------------------------------------
+    def feed(self, triples: Sequence[Triple]) -> int:
+        """Probe `triples`, buffer the misses, hand every full top
+        bucket to the worker. Returns the gate position of the last."""
+        v = self._v
+        if triples:
+            with v.ctx.span("crypto.cache_probe", n=len(triples)):
+                _verdicts, misses = v._cache_probe(triples)
+            self._misses.extend((triples[i], ck) for (i, ck) in misses)
+            self.position += len(misses)
+            lanes = v.engine.BUCKETS[-1]
+            while len(self._misses) >= lanes:
+                self._hand_over(self._misses[:lanes])
+                del self._misses[:lanes]
+        return self.position
+
+    def end(self) -> None:
+        """The feed is over: the tail goes as it is."""
+        if self._misses:
+            self._hand_over(self._misses)
+            self._misses = []
+
+    def submit(self, triples: Sequence[Triple]) -> None:
+        """Ungated: one prewarm_many of `triples` on the worker."""
+        self._run_on_worker(lambda: self._v.prewarm_many(triples))
+
+    def wait(self, position: int, seq: int = 0) -> float:
+        """The gate: block until the drain has landed through
+        `position` (or was closed). This is the replaying thread blocked
+        on the device, `crypto.device_wait`; returns the seconds it
+        was, 0.0 where the chunk had landed."""
+        self.end()
+        waited_s = 0.0
+        with self._v.ctx.span("crypto.device_wait", seq=seq) as sp:
+            with self._cv:
+                if self._landed < position and not self._closed:
+                    t0 = real_monotonic()
+                    while self._landed < position and not self._closed:
+                        self._cv.wait()
+                    waited_s = real_monotonic() - t0
+            if sp.live:
+                sp.set_tag("chunk", bisect.bisect_left(self._ends, position))
+                sp.set_tag("waited", waited_s > 0.0)
+        if waited_s and self._v.ctx.stats is not None:
+            self._v.ctx.stats.record_drain_stream("gated")
+        return waited_s
+
+    def close(self) -> None:
+        """Cancel what is queued and join what is in flight: after this
+        nothing of the drain runs, and nothing of it writes the cache."""
+        with self._cv:
+            self._closed = True
+            del self._jobs[:]
+            self._cv.notify_all()
+            thread = self._thread
+        if thread is not None and thread is not threading.current_thread():
+            thread.join()
+
+    def _hand_over(self, chunk: List[Tuple[Triple, bytes]]) -> None:
+        end = (self._ends[-1] if self._ends else 0) + len(chunk)
+        self._ends.append(end)
+        ctx = self._v.ctx
+        if ctx.stats is not None:
+            ctx.stats.record_drain_stream("chunks")
+        tr = enabled_tracer(ctx.tracer)
+        cause = tr.current_sid() if tr is not None else 0
+        self._run_on_worker(lambda: self._run_chunk(chunk, end, cause))
+
+    def _run_on_worker(self, job: Callable[[], None]) -> None:
+        with self._cv:
+            if self._closed:
+                return
+            self._jobs.append(job)
+            if self._thread is None:
+                self._thread = spawn_worker("catchup.prewarm-pipeline",
+                                            self._run)
+            self._cv.notify_all()
+
+    # -- the worker ----------------------------------------------------------
+    def _run(self) -> None:
+        try:
+            while True:
+                with self._cv:
+                    while not self._jobs and not self._closed:
+                        self._cv.wait()
+                    if self._closed:
+                        return
+                    job = self._jobs.pop(0)
+                try:
+                    job()
+                except Exception as e:  # cache warm only: never fail catchup
+                    log.warning("streamed drain: a job failed (%s); the "
+                                "closes verify what it held", e)
+        finally:
+            with self._cv:      # a worker that is gone gates nothing
+                self._closed = True
+                self._cv.notify_all()
+
+    def _run_chunk(self, chunk: List[Tuple[Triple, bytes]], end: int,
+                   cause: int) -> None:
+        v, ctx = self._v, self._v.ctx
+        ctx.ahead.cause = cause
+        try:
+            with ctx.span("crypto.prewarm", backend=v.name, n=len(chunk),
+                          cause=cause):
+                results = v.verify_many([t for (t, _ck) in chunk])
+                v._cache_store([ck for (_t, ck) in chunk], results)
+        finally:
+            ctx.ahead.cause = None
+            if ctx.stats is not None:
+                ctx.stats.record_drain_stream("landed")
+            with self._cv:
+                self._landed = end
+                self._cv.notify_all()
 
 
 def make_verifier(backend: str = "cpu", clock=None,

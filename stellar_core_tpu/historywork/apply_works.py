@@ -10,8 +10,11 @@ checkpoint N+1's download with checkpoint N's apply).
 
 TPU batch site (SURVEY.md §3.4): before replaying a checkpoint, every
 (source-key, signature, payload) triple in its txsets is drained through
-`SigVerifier.prewarm_many` in one padded device batch, pre-warming
-the verify cache so the synchronous per-tx checks during apply all hit.
+the verifier in padded device batches, pre-warming the verify cache so
+the synchronous per-tx checks during apply all hit. On a device engine
+the drain streams (`SigVerifier.open_drain`, ISSUE 30): chunks leave for
+the device while the rest is still being collected, and a ledger closes
+as soon as its own chunk has landed.
 """
 
 from __future__ import annotations
@@ -124,53 +127,20 @@ def checkpoint_verify_triples(frames, ltx) -> List[Tuple]:
     return frames_sig_triples(ltx, frames)
 
 
-class _PrewarmPipeline:
-    """Pipelined catchup (ISSUE 13): ledger N+1's signature verification
-    overlaps ledger N's apply. The MAIN thread collects the candidate
-    triples (ledger reads stay single-threaded); the worker only runs
-    `verifier.prewarm_many` — pure crypto whose native batch call drops
-    the GIL, so it genuinely runs underneath the (also GIL-free) native
-    apply. A prewarm is cache-warming only: stale or extra triples can
-    never change an accept/reject decision, the apply path re-derives
-    candidates against live state."""
-
-    def __init__(self, verifier) -> None:
-        import queue
-        from ..util.threads import spawn_worker
-        self._verifier = verifier
-        self._q: "queue.Queue" = queue.Queue()
-        self._closed = False
-        self._thread = spawn_worker("catchup.prewarm-pipeline", self._run)
-
-    def submit(self, seq: int, triples) -> None:
-        del seq
-        self._q.put(triples)
-
-    def close(self) -> None:
-        # cancel flag first: queued-but-unstarted batches are stale
-        # work the worker must skip (a reset/abort mid-checkpoint would
-        # otherwise leave it verifying a whole checkpoint for nothing)
-        self._closed = True
-        self._q.put(None)
-
-    def _run(self) -> None:
-        while True:
-            triples = self._q.get()
-            if triples is None or self._closed:
-                return
-            try:
-                self._verifier.prewarm_many(triples)
-            except Exception as e:  # cache warm only: never fail catchup
-                log.warning("pipelined prewarm failed: %s", e)
-
-
 class ApplyCheckpointWork(BasicWork):
     """Replay one checkpoint's ledgers through LedgerManager.close_ledger,
     one ledger per crank (reference ApplyCheckpointWork.cpp:244 →
     ApplyLedgerWork.cpp:22-24). First crank drains the checkpoint's
-    signatures through the batch verifier; on the cpu+native path the
-    checkpoint-wide drain is replaced by the per-ledger prewarm
-    pipeline (ledger N+1 verifies while N applies)."""
+    signatures through the batch verifier. One pipeline, the
+    boundary's DrainStream, overlaps that verification with the closes
+    on both backends: a device engine's drain streams and every close
+    is gated on its own chunk (a close never dispatches, and never
+    falls back, for a signature the drain holds); on the cpu+native
+    path the checkpoint-wide drain is replaced by an ungated prewarm on
+    the same worker (ledger N+1 verifies while N applies, a miss
+    verifies inline). A prewarm is cache-warming only: stale or extra
+    triples can never change an accept/reject decision, the apply path
+    re-derives candidates against live state."""
 
     def __init__(self, app, download_dir: str, checkpoint: int,
                  first_seq: int, last_seq: int) -> None:
@@ -188,7 +158,10 @@ class ApplyCheckpointWork(BasicWork):
         self._next: int = first_seq
         self._sig_state_dirty = False   # a signer set changed mid-checkpoint
         self._prefetch_summary: Optional[dict] = None
-        self._pipeline: Optional[_PrewarmPipeline] = None
+        self._pipeline = None   # the boundary's DrainStream, once opened
+        # seq -> the streamed drain's position after that ledger's
+        # triples: what its close waits for
+        self._gate: Dict[int, int] = {}
 
     def on_reset(self) -> None:
         self._loaded = False
@@ -198,6 +171,7 @@ class ApplyCheckpointWork(BasicWork):
         self._next = self.first_seq
         self._sig_state_dirty = False
         self._prefetch_summary = None
+        self._gate.clear()
         self._close_pipeline()
 
     def _close_pipeline(self) -> None:
@@ -223,15 +197,17 @@ class ApplyCheckpointWork(BasicWork):
             return False
         return getattr(self.app, "sig_verifier", None) is not None
 
+    def _range_groups(self, first: int, last: int) -> List[Tuple]:
+        """(seq, frames) of every parsed txset in a ledger range."""
+        return [(seq, self._frames[seq].frames)
+                for seq in range(first, last + 1) if seq in self._frames]
+
     def _range_triples(self, first: int, last: int):
         """Candidate triples for a ledger range, collected on the MAIN
         thread against current state (one ltx + one signer cache for
         the whole batch)."""
-        frames = []
-        for seq in range(first, last + 1):
-            fr = self._frames.get(seq)
-            if fr is not None:
-                frames.extend(fr.frames)
+        frames = [f for _seq, fs in self._range_groups(first, last)
+                  for f in fs]
         if not frames:
             return []
         from ..ledger.ledgertxn import LedgerTxn
@@ -260,10 +236,10 @@ class ApplyCheckpointWork(BasicWork):
             self.app.sig_verifier.prewarm_many(triples)
             return
         if self._pipeline is None:
-            self._pipeline = _PrewarmPipeline(self.app.sig_verifier)
+            self._pipeline = self.app.sig_verifier.open_drain()
         if metrics is not None:
             metrics.new_meter("catchup.pipeline.prewarm").mark()
-        self._pipeline.submit(first, triples)
+        self._pipeline.submit(triples)
 
     def _load(self) -> bool:
         lpath = os.path.join(self.download_dir,
@@ -297,30 +273,84 @@ class ApplyCheckpointWork(BasicWork):
         from ..native import apply_engine
         return apply_engine() is not None
 
-    def _prewarm_frames(self, frames) -> None:
-        """Collect candidate triples against CURRENT ledger state and
-        drain them through the batch verifier (cached triples are skipped
-        inside prewarm_many — a fully-covered call dispatches nothing)."""
+    def _stream_for_feed(self):
+        """The streamed drain a device engine's feed goes through, or
+        None where this feed drains synchronously: another engine, or
+        the `apply.pipeline-stall` fault (which degrades to the whole
+        drain at once, before the first close)."""
+        from ..util.faults import check_faults
+        verifier = self.app.sig_verifier
+        if not verifier.wants_prewarm:
+            return None
+        if self._pipeline is not None:
+            # a re-collection probes keys an earlier feed may still have
+            # in flight, and a key goes to the device once: land them
+            self._pipeline.wait(self._pipeline.position, self._next)
+        if check_faults(self.app, "apply.pipeline-stall"):
+            metrics = getattr(self.app, "metrics", None)
+            if metrics is not None:
+                metrics.new_meter("catchup.pipeline.stall").mark()
+            return None
+        if self._pipeline is None:
+            self._pipeline = verifier.open_drain()
+        return self._pipeline
+
+    def _prewarm_groups(self, groups) -> None:
+        """Collect candidate triples against CURRENT ledger state,
+        ledger by ledger, and drain them through the batch verifier
+        (cached triples are skipped at the probe: a fully-covered feed
+        dispatches nothing). On a device engine each ledger's triples
+        are fed to the streamed drain as they are collected and the
+        drain's position after them is that ledger's gate; elsewhere
+        the whole collection drains in one prewarm_many."""
+        from ..transactions.transaction_frame import iter_sig_triples
         from ..util.tracing import app_span
         verifier = getattr(self.app, "sig_verifier", None)
-        if verifier is None or not frames or self._prewarm_redundant():
+        n_frames = sum(len(fs) for _seq, fs in groups)
+        if verifier is None or not n_frames or self._prewarm_redundant():
             return
         from ..ledger.ledgertxn import LedgerTxn
+        stream = self._stream_for_feed()
+        triples: List[Tuple] = []
         # sig-batch prep (triple collection + signer-set resolution) and
         # the verify drain trace separately: prep is host CPU, the drain
-        # is the backend-attributed phase
+        # is the backend-attributed phase (a streamed feed's probe is
+        # the prep span's child)
         with app_span(self.app, "catchup.sig_prep", cat="catchup",
-                      frames=len(frames)):
+                      frames=n_frames):
             ltx = LedgerTxn(self.app.ledger_manager.ltx_root())
             try:
-                triples = checkpoint_verify_triples(frames, ltx)
+                fresh_by_group = iter_sig_triples(
+                    ltx, (fs for _seq, fs in groups))
+                for (seq, _fs), fresh in zip(groups, fresh_by_group):
+                    if stream is None:
+                        triples.extend(fresh)
+                    else:
+                        self._gate[seq] = stream.feed(fresh)
+                if stream is not None:
+                    stream.end()
             finally:
                 ltx.rollback()
         if triples:
             verifier.prewarm_many(triples)
 
+    def _await_drain(self, seq: int) -> None:
+        """The close gate: ledger `seq` waits until the streamed drain
+        has landed through its own triples, and never longer."""
+        position = self._gate.pop(seq, None)
+        if position is None or self._pipeline is None:
+            return
+        waited_s = self._pipeline.wait(position, seq)
+        metrics = getattr(self.app, "metrics", None)
+        if metrics is not None:
+            metrics.new_meter("catchup.drain.ledgers").mark()
+            if waited_s:
+                metrics.new_meter("catchup.drain.ledgers_gated").mark()
+                metrics.new_histogram("catchup.drain.gate_wait_ms").update(
+                    waited_s * 1e3)
+
     def _prewarm(self) -> None:
-        """One device batch for the whole checkpoint's signatures."""
+        """The whole checkpoint's signatures, batched for the device."""
         from ..herder.txset import TxSetFrame
         from ..util.tracing import app_span
         net = self.app.config.network_id
@@ -337,7 +367,8 @@ class ApplyCheckpointWork(BasicWork):
                     f.freeze_signatures()    # skip per-serialize fp checks
                 frames.extend(fr.frames)
             psp.set_tag("txs", len(frames))
-        self._prewarm_frames(frames)
+        self._prewarm_groups(self._range_groups(self.first_seq,
+                                                self.last_seq))
         if self._pipeline_enabled():
             # cpu+native: the whole checkpoint's signature verification
             # rides the pipeline worker underneath the apply loop
@@ -413,12 +444,7 @@ class ApplyCheckpointWork(BasicWork):
             # re-collect the remaining range against post-mutation state
             self._pipeline_submit(self._next, self.last_seq)
             return
-        frames = []
-        for seq in range(self._next, self.last_seq + 1):
-            fr = self._frames.get(seq)
-            if fr is not None:
-                frames.extend(fr.frames)
-        self._prewarm_frames(frames)
+        self._prewarm_groups(self._range_groups(self._next, self.last_seq))
 
     def on_run(self) -> State:
         from ..herder.txset import TxSetFrame
@@ -454,6 +480,7 @@ class ApplyCheckpointWork(BasicWork):
             txset = (TxSetFrame.from_wire(net, ts) if ts is not None else
                      TxSetFrame(net, entry.header.previousLedgerHash, []))
         self._prewarm_ledger(txset)
+        self._await_drain(seq)
         lcd = LedgerCloseData(seq, txset, entry.header.scpValue)
         from ..util.tracing import app_span
         with app_span(self.app, "catchup.apply_ledger", cat="catchup",

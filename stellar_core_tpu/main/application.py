@@ -413,6 +413,9 @@ class Application:
         # shapes this run's real traffic used. Best-effort no-op on CPU
         # backends, without buckets or when the cockpit saw no traffic.
         self.sig_verifier.save_warmup_plan()
+        # a catchup cut short leaves a streamed drain open: cancel its
+        # queued chunks and join the one in flight
+        self.sig_verifier.stop()
         # interrupt any background quorum-intersection enumeration first:
         # joining that worker can otherwise take minutes (reference
         # HerderImpl.cpp:140-144)

@@ -81,17 +81,29 @@ def collect_sig_triples(ltx, account_ids, signatures,
     return out
 
 
+def iter_sig_triples(ltx, groups):
+    """Deduped candidate triples of a batch of frames, group by group
+    (catchup hands over a ledger's frames as a group and streams what
+    each yields to the verifier): for every group the triples no earlier
+    group held, in first-seen order. One signer-set resolution per
+    distinct account across the whole batch."""
+    seen: set = set()
+    signer_cache: dict = {}
+    for frames in groups:
+        fresh = []
+        for f in frames:
+            for t in f.candidate_sig_triples(ltx, signer_cache):
+                if t not in seen:
+                    seen.add(t)
+                    fresh.append(t)
+        yield fresh
+
+
 def frames_sig_triples(ltx, frames) -> List[Tuple[bytes, bytes, bytes]]:
     """Deduped candidate triples for a BATCH of frames — the shared
     collection step of both prewarm sites (TxSetFrame.check_or_trim and
-    catchup's whole-checkpoint drain). One signer-set resolution per
-    distinct account across the whole batch."""
-    seen: dict = {}
-    signer_cache: dict = {}
-    for f in frames:
-        for t in f.candidate_sig_triples(ltx, signer_cache):
-            seen[t] = None
-    return list(seen)
+    catchup's whole-checkpoint drain)."""
+    return next(iter_sig_triples(ltx, (frames,)))
 
 
 def _make_result(fee_charged: int, code: int,

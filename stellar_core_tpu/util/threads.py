@@ -79,10 +79,16 @@ WORKER_THREAD_REGISTRY: Dict[str, str] = {
     "crypto.hash-warmup":
         "TpuBatchHasher AOT shape warmup; touches JAX state only",
     "catchup.prewarm-pipeline":
-        "Pipelined catchup (ISSUE 13): verifies ledger N+1's signature "
-        "triples (verifier.prewarm_many — pure crypto, GIL-releasing) "
-        "while the main thread applies ledger N; triples are collected "
-        "on the MAIN thread (no cross-thread ledger reads)",
+        "Pipelined catchup (ISSUE 13, ISSUE 30), the worker of the "
+        "boundary's DrainStream, on both backends: verifies ledger "
+        "N+1's signature triples while the main thread applies ledger "
+        "N. Device engine: one chunk of a streamed checkpoint drain at "
+        "a time (SigVerifier.verify_many + _cache_store), every close "
+        "gated on its own chunk; cpu + native: a whole "
+        "verifier.prewarm_many, ungated (pure crypto, GIL-releasing). "
+        "Triples are collected and probed on the MAIN thread (no "
+        "cross-thread ledger reads); close() cancels what is queued "
+        "and joins what is in flight",
     "crypto.cpu-verify-shard":
         "CPU verify sharding (crypto/keys.raw_verify_batch): one chunk "
         "of a large ed25519 batch per thread through the native "
