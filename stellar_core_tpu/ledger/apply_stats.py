@@ -184,7 +184,15 @@ class ApplyStats:
         self._m_cl_parallel = m.new_meter(
             "ledger.apply.cluster.parallel-close")
         self._m_cl_serial = m.new_meter("ledger.apply.cluster.serial-close")
+        # of the serial closes, those an order-book op forced: the
+        # engine applies them on one thread with the GIL held
+        self._m_cl_dynamic = m.new_meter(
+            "ledger.apply.cluster.dynamic-close")
         self._m_cl_degrade = m.new_meter("ledger.apply.cluster.degraded")
+        # the native engine's order-book callbacks: calls, and offer
+        # rows handed over (a whole book side, or one seller's offers)
+        self._m_book_loads = m.new_meter("ledger.apply.book.loads")
+        self._m_book_rows = m.new_meter("ledger.apply.book.rows")
         # per-entry-type / per-op-type metrics, resolved once — the hot
         # read and apply loops must not pay a name format + registry
         # lookup per event (both name spaces are small and bounded)
@@ -214,7 +222,9 @@ class ApplyStats:
                              "hits": 0, "misses": 0},
             }
             self.buckets = {"levels": {}, "merges": 0, "merge_seconds": 0.0}
+            self.book = {"loads": 0, "rows": 0}
             self.clusters = {"parallel_closes": 0, "serial_closes": 0,
+                             "dynamic_closes": 0,
                              "degraded": 0, "last_count": 0,
                              "last_width": 0, "last_workers": 0,
                              "last_apply_ms": 0.0}
@@ -285,8 +295,10 @@ class ApplyStats:
             bucket_reads = cur["bucket_reads"] - base["bucket_reads"]
             read_set = sum(lookups.values()) + bucket_reads + \
                 (cur["cache_hits"] - base["cache_hits"])
+            cl = c.get("clusters")
             blob = {
                 "seq": c["seq"], "path": path, "bail": c["bail"],
+                "mode": cl["mode"] if cl else None,
                 "wall_ms": round(wall_s * 1e3, 3),
                 "ops": {n: {"count": d["count"],
                             "ms": round(d["seconds"] * 1e3, 3)}
@@ -365,19 +377,29 @@ class ApplyStats:
             self._m_muxed.mark(muxed)
 
     def record_clusters(self, count: int, width: int, workers: int,
-                        parallel: bool, apply_ns: int = 0) -> None:
+                        parallel: bool, apply_ns: int = 0,
+                        dynamic: bool = False) -> None:
         """One native close's conflict-graph shape: cluster count, max
         cluster width (txs), worker count, whether the engine actually
         ran the clusters concurrently, and the engine's tx-execution
         wall (the phase the parallelism accelerates — parse/verify/
-        fees/emission excluded)."""
+        fees/emission excluded). `dynamic`: a transaction of the close
+        walks the order book, so the engine built no clusters and
+        applied serially with the GIL held; such a close counts under
+        `serial_closes` AND `dynamic_closes`."""
         self._g_cl_count.set(count)
         self._g_cl_width.set(width)
         self._g_cl_workers.set(workers)
         (self._m_cl_parallel if parallel else self._m_cl_serial).mark()
+        if dynamic:
+            self._m_cl_dynamic.mark()
+        mode = "dynamic" if dynamic else \
+            "parallel" if parallel else "serial"
         with self._lock:
             key = "parallel_closes" if parallel else "serial_closes"
             self.clusters[key] += 1
+            if dynamic:
+                self.clusters["dynamic_closes"] += 1
             self.clusters["last_count"] = count
             self.clusters["last_width"] = width
             self.clusters["last_workers"] = workers
@@ -385,8 +407,17 @@ class ApplyStats:
             if self._close is not None:
                 self._close["clusters"] = {
                     "count": count, "width": width, "workers": workers,
-                    "parallel": parallel,
+                    "parallel": parallel, "mode": mode,
                     "apply_ms": round(apply_ns / 1e6, 3)}
+
+    def record_book_load(self, rows: int) -> None:
+        """One call of the native engine's `book` or `acct_offers`
+        callback that handed it `rows` offer blobs."""
+        self._m_book_loads.mark()
+        self._m_book_rows.mark(rows)
+        with self._lock:
+            self.book["loads"] += 1
+            self.book["rows"] += rows
 
     def record_cluster_degrade(self) -> None:
         """apply.cluster-fail fired: this close runs serial instead of
@@ -574,6 +605,7 @@ class ApplyStats:
                         self.buckets["levels"].items())},
                 },
                 "clusters": dict(self.clusters),
+                "book": dict(self.book),
                 "last_close": self.last_close,
             }
 

@@ -569,6 +569,8 @@ class LedgerManager:
             apply_sp.set_tag("op_mix", {
                 n: d["count"] for n, d in close_blob["ops"].items()})
             apply_sp.set_tag("reads", close_blob["reads"])
+            if close_blob["mode"]:
+                apply_sp.set_tag("mode", close_blob["mode"])
             if close_blob.get("bail"):
                 apply_sp.set_tag("native_bail", close_blob["bail"])
 

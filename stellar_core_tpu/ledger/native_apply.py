@@ -25,7 +25,11 @@ statically-touched entries and applies disjoint clusters on worker
 threads with the GIL released; the differential oracle asserts
 serial-equivalence for every schedule. `apply.cluster-fail`
 (util.faults) degrades a would-be-parallel close to serial — the same
-close, one thread. Gate: SCT_NATIVE_APPLY=0 disables; Config
+close, one thread. A close with any transaction whose key set depends
+on the order book (offers, path payments, allow-trust revokes) is
+`dynamic`: no clusters, one thread, the GIL held throughout
+(`ledger.apply.cluster.dynamic-close`, beside `serial-close`, which
+goes on counting it). Gate: SCT_NATIVE_APPLY=0 disables; Config
 NATIVE_PARALLEL_APPLY / NATIVE_PARALLEL_WORKERS size the worker pool
 (SCT_PARALLEL_APPLY=0 forces serial).
 """
@@ -73,6 +77,26 @@ def parallel_workers(lm) -> int:
     if n > 0:
         return n
     return min(16, os.cpu_count() or 1)
+
+
+def _book_loader(lm, stats, load, kind: str):
+    """The engine's order-book callback `load` (`kind` "book": one whole
+    book side; "account": one seller's offers) under a `close.book_load`
+    span, counted into `ledger.apply.book.*`."""
+    from ..util.tracing import app_span
+    app = getattr(lm, "app", None)
+
+    def loader(*key) -> list:
+        with app_span(app, "close.book_load", cat="ledger",
+                      kind=kind) as sp:
+            rows = load(*key)
+            if sp.live:
+                sp.set_tag("rows", len(rows))
+        if stats is not None:
+            stats.record_book_load(len(rows))
+        return rows
+
+    return loader
 
 
 def native_apply_txset(lm, ltx, frames, base_fee: Optional[int],
@@ -148,7 +172,10 @@ def native_apply_txset(lm, ltx, frames, base_fee: Optional[int],
                 stats.record_cluster_degrade()
     opts = {"workers": workers, "mode": mode}
     out = eng.apply_close(params, envs, hashes, get_blob,
-                          verifier.prewarm_many, book, acct_offers, opts)
+                          verifier.prewarm_many,
+                          _book_loader(lm, stats, book, "book"),
+                          _book_loader(lm, stats, acct_offers, "account"),
+                          opts)
     if out is None:
         return _bail(stats, "engine-ineligible")
     if "bail" in out:
@@ -166,5 +193,6 @@ def native_apply_txset(lm, ltx, frames, base_fee: Optional[int],
         if cl:
             stats.record_clusters(cl["count"], cl["max_txs"],
                                   cl["workers"], bool(cl["parallel"]),
-                                  apply_ns=cl.get("apply_ns", 0))
+                                  apply_ns=cl.get("apply_ns", 0),
+                                  dynamic=bool(cl.get("dynamic")))
     return True

@@ -5537,14 +5537,14 @@ static PyObject *apply_close(PyObject *self, PyObject *args)
         }
         out = Py_BuildValue(
             "{s:L,s:L,s:O,s:O,s:O,s:O,s:O,"
-            "s:{s:i,s:i,s:i,s:i,s:L}}",
+            "s:{s:i,s:i,s:i,s:i,s:L,s:i}}",
             "feePool", (long long)c.feePool, "idPool",
             (long long)c.idPool, "changes", changes, "results", results,
             "fee_changes", fee_changes, "meta", metas, "op_stats",
             op_stats, "clusters", "count", nclusters, "max_txs",
             max_cluster, "parallel", used_parallel, "workers",
             used_parallel ? nworkers_used : 1, "apply_ns",
-            (long long)apply_phase_ns);
+            (long long)apply_phase_ns, "dynamic", any_dynamic);
         Py_DECREF(op_stats);
         if (!out)
             c.pyerr = 1;
