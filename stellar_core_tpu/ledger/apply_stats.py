@@ -193,6 +193,11 @@ class ApplyStats:
         # rows handed over (a whole book side, or one seller's offers)
         self._m_book_loads = m.new_meter("ledger.apply.book.loads")
         self._m_book_rows = m.new_meter("ledger.apply.book.rows")
+        # the engine's price-ordered index of a book side: best-offer
+        # queries, and index records they examined to answer
+        self._m_book_queries = m.new_meter(
+            "ledger.apply.book.best_queries")
+        self._m_book_steps = m.new_meter("ledger.apply.book.best_steps")
         # per-entry-type / per-op-type metrics, resolved once — the hot
         # read and apply loops must not pay a name format + registry
         # lookup per event (both name spaces are small and bounded)
@@ -222,7 +227,8 @@ class ApplyStats:
                              "hits": 0, "misses": 0},
             }
             self.buckets = {"levels": {}, "merges": 0, "merge_seconds": 0.0}
-            self.book = {"loads": 0, "rows": 0}
+            self.book = {"loads": 0, "rows": 0,
+                         "best_queries": 0, "best_steps": 0}
             self.clusters = {"parallel_closes": 0, "serial_closes": 0,
                              "dynamic_closes": 0,
                              "degraded": 0, "last_count": 0,
@@ -299,6 +305,7 @@ class ApplyStats:
             blob = {
                 "seq": c["seq"], "path": path, "bail": c["bail"],
                 "mode": cl["mode"] if cl else None,
+                "book": c.get("book"),
                 "wall_ms": round(wall_s * 1e3, 3),
                 "ops": {n: {"count": d["count"],
                             "ms": round(d["seconds"] * 1e3, 3)}
@@ -418,6 +425,19 @@ class ApplyStats:
         with self._lock:
             self.book["loads"] += 1
             self.book["rows"] += rows
+
+    def record_book_index(self, queries: int, steps: int) -> None:
+        """One native close's use of the order-book index: `queries`
+        best-offer lookups that examined `steps` index records (a walk
+        of the side would read its length a query)."""
+        self._m_book_queries.mark(queries)
+        self._m_book_steps.mark(steps)
+        with self._lock:
+            self.book["best_queries"] += queries
+            self.book["best_steps"] += steps
+            if self._close is not None:
+                self._close["book"] = {"best_queries": queries,
+                                       "best_steps": steps}
 
     def record_cluster_degrade(self) -> None:
         """apply.cluster-fail fired: this close runs serial instead of

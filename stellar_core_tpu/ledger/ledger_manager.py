@@ -571,6 +571,11 @@ class LedgerManager:
             apply_sp.set_tag("reads", close_blob["reads"])
             if close_blob["mode"]:
                 apply_sp.set_tag("mode", close_blob["mode"])
+            if close_blob["book"]:
+                apply_sp.set_tag("best_queries",
+                                 close_blob["book"]["best_queries"])
+                apply_sp.set_tag("best_steps",
+                                 close_blob["book"]["best_steps"])
             if close_blob.get("bail"):
                 apply_sp.set_tag("native_bail", close_blob["bail"])
 

@@ -195,4 +195,7 @@ def native_apply_txset(lm, ltx, frames, base_fee: Optional[int],
                                   cl["workers"], bool(cl["parallel"]),
                                   apply_ns=cl.get("apply_ns", 0),
                                   dynamic=bool(cl.get("dynamic")))
+        bk = out.get("book")
+        if bk and bk["best_queries"]:
+            stats.record_book_index(bk["best_queries"], bk["best_steps"])
     return True

@@ -47,7 +47,15 @@
  * acct_offers, opts) -> dict | None. `book(selling, buying)` and
  * `acct_offers(account)` return root-state offer blobs for the order
  * book and per-seller offer scans; the overlay merges its own
- * created/modified/erased offers on top. `hashes[i]` is the tx
+ * created/modified/erased offers on top: each (selling, buying) pair
+ * has one Book, whose price-ordered index holds every live offer of the
+ * pair the overlay knows (root rows, offers fetched by key, offers
+ * created or re-quoted in this close), so the best offer is the index's
+ * head and no query walks a side; a root row is indexed as a blob and
+ * becomes an overlay entry only when it reaches the head or an op names
+ * its key. The index takes no lock: order-book
+ * ops run only in `dynamic` closes, serial with the GIL held (section
+ * "order books"). `hashes[i]` is the tx
  * contents hash — 64 bytes (outer||inner) for fee bumps. opts:
  * {"workers": N, "mode": "auto"|"serial"|"parallel"}.
  */
@@ -403,6 +411,8 @@ static int rd_skip_padded(Rd *r, Py_ssize_t n)
 
 /* ------------------------------------------------------------- entries */
 
+struct Book;
+
 /* The COMPLETE mutable state of one ledger entry under the supported
    ops, snapshotted whole per savepoint level. One struct for all four
    entry kinds keeps the journal a single struct copy; at ~1KB per
@@ -423,9 +433,13 @@ typedef struct {
     int64_t liab_buying, liab_selling;
     /* trustline */
     int64_t tl_limit;
-    /* offer */
+    /* offer: amount, price, and the (selling, buying) pair as its
+       interned Book (order-book section). The pair is state, not
+       identity: an update by offerID may move an offer to another pair,
+       and a rollback has to move it back */
     int64_t o_amount;
     int32_t o_pn, o_pd;
+    struct Book *o_book;
     /* data */
     int d_len;
     /* lastModifiedLedgerSeq this state serializes with (the base
@@ -482,10 +496,6 @@ typedef struct Entry {
     uint8_t acc_key[32];
     /* offers only: */
     int64_t offer_id;
-    uint8_t o_sell[MAX_ASSET];
-    int o_sell_len;
-    uint8_t o_buy[MAX_ASSET];
-    int o_buy_len;
     /* patch offsets into base blob: */
     int off_balance, off_seq;
     EntrySave save[MAXLEVEL];
@@ -508,7 +518,8 @@ static int mut_struct_eq(const MutState *a, const MutState *b)
         a->liab_buying != b->liab_buying ||
         a->liab_selling != b->liab_selling ||
         a->tl_limit != b->tl_limit || a->o_amount != b->o_amount ||
-        a->o_pn != b->o_pn || a->o_pd != b->o_pd || a->d_len != b->d_len)
+        a->o_pn != b->o_pn || a->o_pd != b->o_pd ||
+        a->o_book != b->o_book || a->d_len != b->d_len)
         return 0;
     if (a->has_infl && memcmp(a->infl, b->infl, 32) != 0)
         return 0;
@@ -554,11 +565,36 @@ static int elist_push(EList *l, Entry *e)
     return 0;
 }
 
-/* order-book cache: one root fetch per (selling, buying) pair per close */
+/* one record of a book side's price index: an offer and the price it
+   was filed under. With an entry (`e`): live only while the entry
+   exists, is of this pair and still has this price. Without one: a root
+   row of the side (`blob`) that no op has named yet, which becomes an
+   entry when it reaches the head (order-book section) */
 typedef struct {
+    int32_t pn, pd;
+    int64_t offer_id;
+    struct Entry *e;
+    const uint8_t *blob; /* e == NULL: the row, borrowed from Book.rows */
+    int bloblen;
+} BookRec;
+
+/* a root row of a loaded side, findable by offer key until an op names
+   it: (seller, offerID) are blob[12..52) */
+typedef struct {
+    const uint8_t *blob;
+    int len;
+} ColdRow;
+
+/* one (selling, buying) pair of the close: interned at the first offer
+   of the pair the overlay sees, its root rows fetched once, at the
+   first best-offer query (`loaded`) */
+typedef struct Book {
     uint8_t sell[MAX_ASSET], buy[MAX_ASSET];
     int sell_len, buy_len;
-    EList offers; /* root-order Entry views (overlay state is live) */
+    int loaded;     /* the root's rows of the pair are indexed */
+    PyObject *rows; /* the `book` callback's list: owns the rows' blobs */
+    BookRec *heap;  /* binary min-heap by (pn/pd, offer_id) */
+    int nheap, capheap;
 } Book;
 
 typedef struct {
@@ -579,7 +615,9 @@ typedef struct {
     int nall, capall;
     EList closed0;          /* global level-0 first-touch order (fee
                                phase + serial apply) */
-    EList created_offers;   /* offers created this close, creation order */
+    EList created_offers;   /* offers created this close, creation order
+                               (the revoke's walk; best_offer reads the
+                               books' indexes) */
     PyObject *lookup, *verify, *book_cb, *acct_cb;
     int64_t feePool, idPool;
     uint32_t ledgerVersion, ledgerSeq, inflationSeq;
@@ -589,12 +627,16 @@ typedef struct {
     int pyerr; /* a Python exception is set: propagate */
     const char *bailmsg;
     char bailbuf[48];
-    Book *books;
+    Book **books; /* each its own allocation: MutState.o_book points in */
     int nbooks, capbooks;
+    ColdRow *cold; /* open addressing over the loaded sides' rows */
+    int ncold, capcold; /* capcold a power of two, >= 2 * ncold */
     AcctBook *abooks;
     int nabooks, capabooks;
     StaticSigner *sadds;
     int nsadds, capsadds;
+    int64_t best_queries; /* best_offer calls */
+    int64_t best_steps;   /* index records those calls examined */
     int nopy; /* GIL released: any Python need is an engine bug -> bail */
     int abort_flag; /* parallel: some cluster bailed/oomed. Written by
         any worker, polled by the rest with no lock in between, so
@@ -649,6 +691,12 @@ static void ctx_bail(Ctx *c, const char *msg)
     c->bail = 1;
 }
 
+/* order-book section */
+static Book *book_intern(AEnv *env, const uint8_t *sell, int sell_len,
+                         const uint8_t *buy, int buy_len);
+static int book_file(AEnv *env, Entry *e);
+static const ColdRow *cold_find(const Ctx *c, const uint8_t *seller_id);
+
 static int64_t now_ns(void)
 {
     struct timespec ts;
@@ -696,9 +744,13 @@ static void ctx_free(Ctx *c)
     free(c->all);
     free(c->closed0.v);
     free(c->created_offers.v);
-    for (i = 0; i < c->nbooks; i++)
-        free(c->books[i].offers.v);
+    for (i = 0; i < c->nbooks; i++) {
+        Py_XDECREF(c->books[i]->rows); /* GIL held: apply_close's exit */
+        free(c->books[i]->heap);
+        free(c->books[i]);
+    }
     free(c->books);
+    free(c->cold);
     for (i = 0; i < c->nabooks; i++)
         free(c->abooks[i].offers.v);
     free(c->abooks);
@@ -871,36 +923,42 @@ static int rd_asset_raw(Rd *r, uint8_t *out)
     return n;
 }
 
-static int parse_offer(Ctx *c, Entry *e, const uint8_t *blob, int len)
+/* the fields of an OfferEntry blob, checked exactly as far as an entry's
+   parse checks them */
+typedef struct {
+    uint32_t lm, flags;
+    const uint8_t *seller; /* 32 bytes inside the blob */
+    int64_t offer_id, amount;
+    int32_t pn, pd;
+    uint8_t sell[MAX_ASSET], buy[MAX_ASSET];
+    int sell_len, buy_len;
+} OfferView;
+
+static int offer_view(const uint8_t *blob, int len, OfferView *v)
 {
     Rd r = {blob, len, 0};
-    MutState *st = &e->st;
-    uint32_t u;
-    (void)c;
-    if (rd_u32(&r, &st->lm) < 0)
+    uint32_t u, pn, pd;
+    if (rd_u32(&r, &v->lm) < 0)
         return -1;
     if (rd_u32(&r, &u) < 0 || u != LET_OFFER)
         return -1;
-    const uint8_t *acct;
-    if (rd_u32(&r, &u) < 0 || u != 0 || !(acct = rd_take(&r, 32)))
+    if (rd_u32(&r, &u) < 0 || u != 0 || !(v->seller = rd_take(&r, 32)))
         return -1;
-    memcpy(e->acc_key, acct, 32);
-    if (rd_i64(&r, &e->offer_id) < 0)
+    if (rd_i64(&r, &v->offer_id) < 0)
         return -1;
-    e->o_sell_len = rd_asset_raw(&r, e->o_sell);
-    if (e->o_sell_len < 0)
+    v->sell_len = rd_asset_raw(&r, v->sell);
+    if (v->sell_len < 0)
         return -1;
-    e->o_buy_len = rd_asset_raw(&r, e->o_buy);
-    if (e->o_buy_len < 0)
+    v->buy_len = rd_asset_raw(&r, v->buy);
+    if (v->buy_len < 0)
         return -1;
-    if (rd_i64(&r, &st->o_amount) < 0)
+    if (rd_i64(&r, &v->amount) < 0)
         return -1;
-    uint32_t pn, pd;
     if (rd_u32(&r, &pn) < 0 || rd_u32(&r, &pd) < 0)
         return -1;
-    st->o_pn = (int32_t)pn;
-    st->o_pd = (int32_t)pd;
-    if (rd_u32(&r, &st->flags) < 0)
+    v->pn = (int32_t)pn;
+    v->pd = (int32_t)pd;
+    if (rd_u32(&r, &v->flags) < 0)
         return -1;
     if (rd_u32(&r, &u) < 0 || u != 0) /* OfferEntry ext */
         return -1;
@@ -908,9 +966,29 @@ static int parse_offer(Ctx *c, Entry *e, const uint8_t *blob, int len)
         return -1;
     if (r.pos != r.len)
         return -1;
+    return 0;
+}
+
+static int parse_offer(AEnv *env, Entry *e, const uint8_t *blob, int len)
+{
+    OfferView v;
+    MutState *st = &e->st;
+    if (offer_view(blob, len, &v) < 0)
+        return -1;
+    st->lm = v.lm;
+    memcpy(e->acc_key, v.seller, 32);
+    e->offer_id = v.offer_id;
+    st->o_amount = v.amount;
+    st->o_pn = v.pn;
+    st->o_pd = v.pd;
+    st->flags = v.flags;
+    st->o_book = book_intern(env, v.sell, v.sell_len, v.buy, v.buy_len);
+    if (!st->o_book)
+        return -1;
     st->exists = 1;
     e->base_st = *st;
-    return 0;
+    /* a live offer enters the overlay: file it under its pair */
+    return book_file(env, e);
 }
 
 static int parse_data(Ctx *c, Entry *e, const uint8_t *blob, int len)
@@ -1032,7 +1110,7 @@ static int entry_adopt_blob(AEnv *env, Entry *e, const uint8_t *blob,
         rc = parse_trustline(c, e, e->base, len);
         break;
     case LET_OFFER:
-        rc = parse_offer(c, e, e->base, len);
+        rc = parse_offer(env, e, e->base, len);
         break;
     case LET_DATA:
         rc = parse_data(c, e, e->base, len);
@@ -1041,6 +1119,8 @@ static int entry_adopt_blob(AEnv *env, Entry *e, const uint8_t *blob,
         rc = -1;
     }
     if (rc < 0) {
+        if (env->oom)
+            return -1;
         if (!c->bailmsg)
             ctx_bail(c, "entry-kind");
         env->bail = 1;
@@ -1065,6 +1145,16 @@ static Entry *get_entry(AEnv *env, const uint8_t *keyb, int keylen)
     if (c->nopy) {
         env_bail(env, "prefetch-miss");
         return NULL;
+    }
+    if (c->ncold && keylen == 48 && keyb[3] == LET_OFFER) {
+        /* an offer of a side this close has loaded: the row is here */
+        const ColdRow *row = cold_find(c, keyb + 8);
+        if (row) {
+            e = insert_entry(env, keyb, keylen, h);
+            if (!e || entry_adopt_blob(env, e, row->blob, row->len) < 0)
+                return NULL;
+            return e;
+        }
     }
 
     PyObject *kb = PyBytes_FromStringAndSize((const char *)keyb, keylen);
@@ -1145,6 +1235,15 @@ static void offer_key(uint8_t *keyb, const uint8_t *seller, int64_t oid)
     wr_i64_at(keyb + 40, oid);
 }
 
+/* the key of the offer an OfferEntry blob holds: lastModified(4) type(4)
+   keytype(4) seller(32) offerID(8, big-endian already) */
+static void offer_key_of_blob(uint8_t *keyb, const uint8_t *blob)
+{
+    wr_u32_at(keyb, LET_OFFER);
+    wr_u32_at(keyb + 4, 0);
+    memcpy(keyb + 8, blob + 12, 40);
+}
+
 /* ----------------------------------------------------- savepoint journal */
 
 static int touch(AEnv *env, Entry *e, int lv)
@@ -1210,8 +1309,16 @@ static void rollback_level(AEnv *env, int lv)
     EList *from = &env->lv[lv];
     for (i = 0; i < from->n; i++) {
         Entry *e = from->v[i];
-        mut_copy(&e->st, &e->save[lv].st);
+        const MutState *to = &e->save[lv].st;
+        /* an offer that comes back to life, to another price or to
+           another pair has no live record in its book's index */
+        int refile = e->type == LET_OFFER && to->exists &&
+                     (!e->st.exists || e->st.o_book != to->o_book ||
+                      e->st.o_pn != to->o_pn || e->st.o_pd != to->o_pd);
+        mut_copy(&e->st, to);
         e->save[lv].seen = 0;
+        if (refile)
+            book_file(env, e); /* oom: flagged on env, the close aborts */
     }
     from->n = 0;
 }
@@ -1275,8 +1382,8 @@ static int ser_entry(Entry *e, const MutState *st, Buf *out)
     case LET_OFFER:
         if (buf_u32(out, 0) < 0 || buf_put(out, e->acc_key, 32) < 0 ||
             buf_i64(out, e->offer_id) < 0 ||
-            buf_put(out, e->o_sell, e->o_sell_len) < 0 ||
-            buf_put(out, e->o_buy, e->o_buy_len) < 0 ||
+            buf_put(out, st->o_book->sell, st->o_book->sell_len) < 0 ||
+            buf_put(out, st->o_book->buy, st->o_book->buy_len) < 0 ||
             buf_i64(out, st->o_amount) < 0 ||
             buf_i32(out, st->o_pn) < 0 || buf_i32(out, st->o_pd) < 0 ||
             buf_u32(out, st->flags) < 0 ||
@@ -2327,26 +2434,66 @@ static __int128 max_amount_receive(Entry *e)
 
 /* ---------------------------------------------------------- order books */
 
-/* fetch + index the root's offers for one (selling, buying) pair (the
-   Python `book` callback); GIL required. Entries already in the overlay
-   keep their live state — dedupe by key. */
-static Book *get_book(AEnv *env, const uint8_t *sell, int sell_len,
-                      const uint8_t *buy, int buy_len)
+/* A Book is one (selling, buying) pair of the close, and owns a
+   price-ordered index of that side: a binary min-heap of BookRec keyed
+   exactly as ledgertxn.price_less orders offers (o_pn/o_pd by
+   cross-multiplication, then offer id), so the best offer is the head
+   and best_offer finds it without walking the side.
+
+   What the index holds: a record for EVERY live offer of the pair the
+   close knows, wherever it came from — a root row of the `book`
+   callback, an offer an op fetched by key (before or after the side was
+   loaded), one of a seller's rows (`acct_offers`), an offer created or
+   re-quoted in this close. A record is filed (book_file) whenever an
+   offer entry comes to a live (pair, price): when its blob is adopted
+   (parse_offer), when the manage-offer op writes it, and when
+   rollback_level restores it to a pair or price it had left. Nothing is
+   taken out when an offer dies, moves or is re-priced: a record is
+   live only while its entry exists, is of this pair and still has the
+   record's price (rec_live), and best_offer drops the dead ones it
+   meets at the head. An offer that was filed twice under one price has
+   two live records for one entry, which reads the same. The pair is
+   part of an entry's journalled state (MutState.o_book), so a rollback
+   restores pair, price and existence together, and the record filed
+   under the restored key — the old one if it was never at the head
+   since, else the one rollback_level files — is live again.
+
+   A root row is indexed as it is (get_book): its record carries the
+   blob, not an entry, and costs a parse of its fixed fields. It becomes
+   an entry — allocated, adopted, filed again under the same key — only
+   when its record reaches the head, or when an op names its key
+   (get_entry finds the row in Ctx.cold before it would ask Python). If
+   the overlay already had the offer's entry when its row's record
+   reaches the head, that entry's own records speak for the offer, at
+   whatever pair and price it has by then, and the row's is dropped. So a
+   close allocates entries for the offers it meets, not for the sides it
+   loads.
+
+   No lock: an op that reads or writes an offer makes its transaction
+   `dynamic`, a close with one dynamic transaction applies serially on
+   the calling thread with the GIL held (`any_dynamic`), and no other
+   close touches an offer entry. A close that released the GIL over
+   order-book ops would have to give each Book to one cluster. */
+
+/* find or make the close's Book of a pair; its root rows are fetched
+   later, by get_book */
+static Book *book_intern(AEnv *env, const uint8_t *sell, int sell_len,
+                         const uint8_t *buy, int buy_len)
 {
     Ctx *c = env->c;
     int i;
     for (i = 0; i < c->nbooks; i++)
-        if (asset_eq(c->books[i].sell, c->books[i].sell_len, sell,
+        if (asset_eq(c->books[i]->sell, c->books[i]->sell_len, sell,
                      sell_len) &&
-            asset_eq(c->books[i].buy, c->books[i].buy_len, buy, buy_len))
-            return &c->books[i];
+            asset_eq(c->books[i]->buy, c->books[i]->buy_len, buy, buy_len))
+            return c->books[i];
     if (c->nopy) {
         env_bail(env, "prefetch-miss");
         return NULL;
     }
     if (c->nbooks == c->capbooks) {
         int cap = c->capbooks ? c->capbooks * 2 : 8;
-        Book *p = realloc(c->books, cap * sizeof(Book));
+        Book **p = realloc(c->books, cap * sizeof(Book *));
         if (!p) {
             env->oom = 1;
             return NULL;
@@ -2354,116 +2501,232 @@ static Book *get_book(AEnv *env, const uint8_t *sell, int sell_len,
         c->books = p;
         c->capbooks = cap;
     }
-    Book *bk = &c->books[c->nbooks];
-    memset(bk, 0, sizeof(*bk));
+    Book *bk = calloc(1, sizeof(Book));
+    if (!bk) {
+        env->oom = 1;
+        return NULL;
+    }
     memcpy(bk->sell, sell, sell_len);
     bk->sell_len = sell_len;
     memcpy(bk->buy, buy, buy_len);
     bk->buy_len = buy_len;
-
-    PyObject *sb = PyBytes_FromStringAndSize((const char *)sell, sell_len);
-    PyObject *bb = PyBytes_FromStringAndSize((const char *)buy, buy_len);
-    PyObject *res = NULL, *seq = NULL;
-    if (!sb || !bb)
-        goto pyfail;
-    res = PyObject_CallFunctionObjArgs(c->book_cb, sb, bb, NULL);
-    if (!res)
-        goto pyfail;
-    seq = PySequence_Fast(res, "book() must return a sequence");
-    if (!seq)
-        goto pyfail;
-    for (Py_ssize_t k = 0; k < PySequence_Fast_GET_SIZE(seq); k++) {
-        PyObject *blob = PySequence_Fast_GET_ITEM(seq, k);
-        if (!PyBytes_Check(blob)) {
-            ctx_bail(c, "lookup-type");
-            env->bail = 1;
-            goto out;
-        }
-        /* derive the offer key from the blob: lastModified(4) type(4)
-           keytype(4) seller(32) offerID(8) */
-        const uint8_t *p = (const uint8_t *)PyBytes_AS_STRING(blob);
-        Py_ssize_t bl = PyBytes_GET_SIZE(blob);
-        if (bl < 52) {
-            ctx_bail(c, "lookup-type");
-            env->bail = 1;
-            goto out;
-        }
-        uint8_t keyb[48];
-        wr_u32_at(keyb, LET_OFFER);
-        wr_u32_at(keyb + 4, 0);
-        memcpy(keyb + 8, p + 12, 32);  /* seller */
-        memcpy(keyb + 40, p + 44, 8);  /* offerID (big-endian already) */
-        uint32_t h;
-        Entry *e = find_entry(c, keyb, 48, &h);
-        if (!e) {
-            e = insert_entry(env, keyb, 48, h);
-            if (!e)
-                goto out;
-            if (entry_adopt_blob(env, e, p, (int)bl) < 0)
-                goto out;
-        }
-        if (elist_push(&bk->offers, e) < 0) {
-            env->oom = 1;
-            goto out;
-        }
-    }
-    Py_DECREF(seq);
-    Py_DECREF(res);
-    Py_DECREF(sb);
-    Py_DECREF(bb);
-    c->nbooks++;
+    c->books[c->nbooks++] = bk;
     return bk;
-pyfail:
-    c->pyerr = 1;
-out:
-    Py_XDECREF(seq);
-    Py_XDECREF(res);
-    Py_XDECREF(sb);
-    Py_XDECREF(bb);
-    free(bk->offers.v);
-    return NULL;
 }
 
 /* exact fraction compare: a.price < b.price, tie-break by offerID
    (ledgertxn.price_less) */
-static int price_less(const Entry *a, const Entry *b)
+static int rec_less(const BookRec *a, const BookRec *b)
 {
-    int64_t lhs = (int64_t)a->st.o_pn * b->st.o_pd;
-    int64_t rhs = (int64_t)b->st.o_pn * a->st.o_pd;
+    int64_t lhs = (int64_t)a->pn * b->pd;
+    int64_t rhs = (int64_t)b->pn * a->pd;
     if (lhs != rhs)
         return lhs < rhs;
     return a->offer_id < b->offer_id;
 }
 
-/* best (lowest-price) live offer selling `sell` for `buy`, merged view:
-   the root book plus overlay-created offers for the pair */
+static int rec_live(const Book *bk, const BookRec *r)
+{
+    const MutState *st = &r->e->st;
+    return st->exists && st->o_book == bk && st->o_pn == r->pn &&
+           st->o_pd == r->pd;
+}
+
+static int book_push(AEnv *env, Book *bk, BookRec r)
+{
+    if (bk->nheap == bk->capheap) {
+        int cap = bk->capheap ? bk->capheap * 2 : 64;
+        BookRec *p = realloc(bk->heap, cap * sizeof(BookRec));
+        if (!p) {
+            env->oom = 1;
+            ctx_abort(env->c);
+            return -1;
+        }
+        bk->heap = p;
+        bk->capheap = cap;
+    }
+    int i = bk->nheap++;
+    while (i > 0) {
+        int up = (i - 1) / 2;
+        if (!rec_less(&r, &bk->heap[up]))
+            break;
+        bk->heap[i] = bk->heap[up];
+        i = up;
+    }
+    bk->heap[i] = r;
+    return 0;
+}
+
+/* file live offer e under its pair at its price */
+static int book_file(AEnv *env, Entry *e)
+{
+    BookRec r = {e->st.o_pn, e->st.o_pd, e->offer_id, e, NULL, 0};
+    return book_push(env, e->st.o_book, r);
+}
+
+static void book_pop(Book *bk)
+{
+    BookRec r = bk->heap[--bk->nheap];
+    int i = 0, n = bk->nheap;
+    for (;;) {
+        int kid = 2 * i + 1;
+        if (kid >= n)
+            break;
+        if (kid + 1 < n && rec_less(&bk->heap[kid + 1], &bk->heap[kid]))
+            kid++;
+        if (!rec_less(&bk->heap[kid], &r))
+            break;
+        bk->heap[i] = bk->heap[kid];
+        i = kid;
+    }
+    if (n)
+        bk->heap[i] = r;
+}
+
+/* the cold rows: (seller, offerID) -> the row's blob */
+static uint32_t cold_slot(const Ctx *c, const uint8_t *seller_id)
+{
+    return fnv1a(seller_id, 40) & (uint32_t)(c->capcold - 1);
+}
+
+static const ColdRow *cold_find(const Ctx *c, const uint8_t *seller_id)
+{
+    uint32_t i = cold_slot(c, seller_id);
+    for (; c->cold[i].blob; i = (i + 1) & (uint32_t)(c->capcold - 1))
+        if (memcmp(c->cold[i].blob + 12, seller_id, 40) == 0)
+            return &c->cold[i];
+    return NULL;
+}
+
+static void cold_put(Ctx *c, const uint8_t *blob, int len)
+{
+    uint32_t i = cold_slot(c, blob + 12);
+    while (c->cold[i].blob)
+        i = (i + 1) & (uint32_t)(c->capcold - 1);
+    c->cold[i].blob = blob;
+    c->cold[i].len = len;
+    c->ncold++;
+}
+
+/* room for `more` rows at half load at most */
+static int cold_reserve(AEnv *env, int more)
+{
+    Ctx *c = env->c;
+    int cap = c->capcold ? c->capcold : 256;
+    while (cap < 2 * (c->ncold + more))
+        cap *= 2;
+    if (cap == c->capcold)
+        return 0;
+    ColdRow *old = c->cold;
+    int i, oldcap = c->capcold;
+    ColdRow *p = calloc(cap, sizeof(ColdRow));
+    if (!p) {
+        env->oom = 1;
+        return -1;
+    }
+    c->cold = p;
+    c->capcold = cap;
+    c->ncold = 0;
+    for (i = 0; i < oldcap; i++)
+        if (old[i].blob)
+            cold_put(c, old[i].blob, old[i].len);
+    free(old);
+    return 0;
+}
+
+/* the pair's Book with the root's offers indexed: one fetch a pair a
+   close (the Python `book` callback); GIL required. Every row gets a
+   record and a place among the cold rows; none gets an entry here. The
+   Book keeps the callback's list, which owns the blobs, to the end of
+   the close. */
+static Book *get_book(AEnv *env, const uint8_t *sell, int sell_len,
+                      const uint8_t *buy, int buy_len)
+{
+    Ctx *c = env->c;
+    Book *bk = book_intern(env, sell, sell_len, buy, buy_len);
+    if (!bk || bk->loaded)
+        return bk;
+    if (c->nopy) {
+        env_bail(env, "prefetch-miss");
+        return NULL;
+    }
+
+    PyObject *sb = PyBytes_FromStringAndSize((const char *)sell, sell_len);
+    PyObject *bb = PyBytes_FromStringAndSize((const char *)buy, buy_len);
+    PyObject *res = NULL;
+    if (sb && bb)
+        res = PyObject_CallFunctionObjArgs(c->book_cb, sb, bb, NULL);
+    if (res)
+        bk->rows = PySequence_Fast(res, "book() must return a sequence");
+    Py_XDECREF(res);
+    Py_XDECREF(sb);
+    Py_XDECREF(bb);
+    if (!bk->rows) {
+        c->pyerr = 1;
+        return NULL;
+    }
+    Py_ssize_t n = PySequence_Fast_GET_SIZE(bk->rows);
+    if (cold_reserve(env, (int)n) < 0)
+        return NULL;
+    for (Py_ssize_t k = 0; k < n; k++) {
+        PyObject *blob = PySequence_Fast_GET_ITEM(bk->rows, k);
+        if (!PyBytes_Check(blob)) {
+            ctx_bail(c, "lookup-type");
+            env->bail = 1;
+            return NULL;
+        }
+        const uint8_t *p = (const uint8_t *)PyBytes_AS_STRING(blob);
+        int bl = (int)PyBytes_GET_SIZE(blob);
+        OfferView v;
+        if (offer_view(p, bl, &v) < 0) {
+            ctx_bail(c, "entry-kind");
+            env->bail = 1;
+            return NULL;
+        }
+        BookRec r = {v.pn, v.pd, v.offer_id, NULL, p, bl};
+        if (book_push(env, bk, r) < 0)
+            return NULL;
+        cold_put(c, p, bl);
+    }
+    bk->loaded = 1;
+    return bk;
+}
+
+/* best (lowest-price) live offer selling `sell` for `buy`: the head of
+   the pair's index, past the dead records above it. `best_steps` counts
+   the records looked at, `best_queries` the calls. */
 static Entry *best_offer(AEnv *env, const uint8_t *sell, int sell_len,
                          const uint8_t *buy, int buy_len)
 {
     Ctx *c = env->c;
+    if (c->nopy) { /* an order-book op makes its close dynamic */
+        env_bail(env, "prefetch-miss");
+        return NULL;
+    }
     Book *bk = get_book(env, sell, sell_len, buy, buy_len);
     if (!bk)
         return NULL;
-    Entry *best = NULL;
-    int i;
-    for (i = 0; i < bk->offers.n; i++) {
-        Entry *e = bk->offers.v[i];
-        if (!e->st.exists)
+    c->best_queries++;
+    while (bk->nheap) {
+        BookRec r = bk->heap[0];
+        c->best_steps++;
+        if (r.e && rec_live(bk, &r))
+            return r.e;
+        book_pop(bk);
+        if (r.e)
             continue;
-        if (!best || price_less(e, best))
-            best = e;
-    }
-    for (i = 0; i < c->created_offers.n; i++) {
-        Entry *e = c->created_offers.v[i];
-        if (!e->st.exists || e->base)
-            continue; /* base offers are already in the book list */
-        if (!asset_eq(e->o_sell, e->o_sell_len, sell, sell_len) ||
-            !asset_eq(e->o_buy, e->o_buy_len, buy, buy_len))
+        /* a root row at the head: an entry now, unless the overlay has
+           the offer's already; its adoption files it at the head again */
+        uint8_t keyb[48];
+        uint32_t h;
+        offer_key_of_blob(keyb, r.blob);
+        if (find_entry(c, keyb, 48, &h))
             continue;
-        if (!best || price_less(e, best))
-            best = e;
+        Entry *e = insert_entry(env, keyb, 48, h);
+        if (!e || entry_adopt_blob(env, e, r.blob, r.bloblen) < 0)
+            return NULL;
     }
-    return best;
+    return NULL;
 }
 
 /* the root's per-seller offer list (the `acct_offers` callback),
@@ -2512,10 +2775,7 @@ static AcctBook *get_acct_book(AEnv *env, const uint8_t *acct)
         const uint8_t *p = (const uint8_t *)PyBytes_AS_STRING(blob);
         Py_ssize_t bl = PyBytes_GET_SIZE(blob);
         uint8_t keyb[48];
-        wr_u32_at(keyb, LET_OFFER);
-        wr_u32_at(keyb + 4, 0);
-        memcpy(keyb + 8, p + 12, 32);
-        memcpy(keyb + 40, p + 44, 8);
+        offer_key_of_blob(keyb, p);
         uint32_t h;
         Entry *e = find_entry(c, keyb, 48, &h);
         if (!e) {
@@ -2733,8 +2993,9 @@ static int apply_offer_liab(AEnv *env, Entry *offer, __int128 amount,
     offer_liabilities(offer->st.o_pn, offer->st.o_pd, amount, &buying,
                       &selling);
     const uint8_t *seller = offer->acc_key;
+    const Book *bk = offer->st.o_book;
     int ok = 1;
-    if (asset_is_native(offer->o_buy, offer->o_buy_len)) {
+    if (asset_is_native(bk->buy, bk->buy_len)) {
         Entry *a = get_account(env, seller);
         if (!a) {
             *err = 1;
@@ -2749,10 +3010,9 @@ static int apply_offer_liab(AEnv *env, Entry *offer, __int128 amount,
             }
             ok = add_buying_liab(a, sign * buying);
         }
-    } else if (memcmp(seller, asset_issuer(offer->o_buy, offer->o_buy_len),
+    } else if (memcmp(seller, asset_issuer(bk->buy, bk->buy_len),
                       32) != 0) {
-        Entry *tl = get_trustline(env, seller, offer->o_buy,
-                                  offer->o_buy_len);
+        Entry *tl = get_trustline(env, seller, bk->buy, bk->buy_len);
         if (!tl) {
             *err = 1;
             return 0;
@@ -2769,7 +3029,7 @@ static int apply_offer_liab(AEnv *env, Entry *offer, __int128 amount,
     }
     if (!ok)
         return 0;
-    if (asset_is_native(offer->o_sell, offer->o_sell_len)) {
+    if (asset_is_native(bk->sell, bk->sell_len)) {
         Entry *a = get_account(env, seller);
         if (!a) {
             *err = 1;
@@ -2785,10 +3045,9 @@ static int apply_offer_liab(AEnv *env, Entry *offer, __int128 amount,
             ok = add_selling_liab(env->c, a, sign * selling);
         }
     } else if (memcmp(seller,
-                      asset_issuer(offer->o_sell, offer->o_sell_len),
+                      asset_issuer(bk->sell, bk->sell_len),
                       32) != 0) {
-        Entry *tl = get_trustline(env, seller, offer->o_sell,
-                                  offer->o_sell_len);
+        Entry *tl = get_trustline(env, seller, bk->sell, bk->sell_len);
         if (!tl) {
             *err = 1;
             return 0;
@@ -3499,8 +3758,10 @@ static int apply_allow_trust(AEnv *env, Op *op, const uint8_t *src_id,
             Entry *e = ab->offers.v[i];
             if (!e->st.exists)
                 continue;
-            if (!asset_eq(e->o_sell, e->o_sell_len, asset, assetlen) &&
-                !asset_eq(e->o_buy, e->o_buy_len, asset, assetlen))
+            if (!asset_eq(e->st.o_book->sell, e->st.o_book->sell_len, asset,
+                          assetlen) &&
+                !asset_eq(e->st.o_book->buy, e->st.o_book->buy_len, asset,
+                          assetlen))
                 continue;
             if (elist_push(&matched, e) < 0) {
                 env->oom = 1;
@@ -3514,8 +3775,10 @@ static int apply_allow_trust(AEnv *env, Op *op, const uint8_t *src_id,
                 continue;
             if (memcmp(e->acc_key, op->at_trustor, 32) != 0)
                 continue;
-            if (!asset_eq(e->o_sell, e->o_sell_len, asset, assetlen) &&
-                !asset_eq(e->o_buy, e->o_buy_len, asset, assetlen))
+            if (!asset_eq(e->st.o_book->sell, e->st.o_book->sell_len, asset,
+                          assetlen) &&
+                !asset_eq(e->st.o_book->buy, e->st.o_book->buy_len, asset,
+                          assetlen))
                 continue;
             if (elist_push(&matched, e) < 0) {
                 env->oom = 1;
@@ -3787,6 +4050,10 @@ static int apply_manage_offer(AEnv *env, Op *op, const uint8_t *src_id,
         Entry *e = get_entry(env, keyb, 48);
         if (!e)
             goto out;
+        Book *bk = book_intern(env, op->o_sell, op->o_sell_len, op->o_buy,
+                               op->o_buy_len);
+        if (!bk)
+            goto out;
         if (touch(env, e, 3) < 0)
             goto out;
         MutState *st = &e->st;
@@ -3795,15 +4062,14 @@ static int apply_manage_offer(AEnv *env, Op *op, const uint8_t *src_id,
         e->type = LET_OFFER;
         memcpy(e->acc_key, src_id, 32);
         e->offer_id = new_id;
-        memcpy(e->o_sell, op->o_sell, op->o_sell_len);
-        e->o_sell_len = op->o_sell_len;
-        memcpy(e->o_buy, op->o_buy, op->o_buy_len);
-        e->o_buy_len = op->o_buy_len;
+        st->o_book = bk;
         st->o_amount = (int64_t)remaining;
         st->o_pn = op->o_pn;
         st->o_pd = op->o_pd;
         st->flags = flags;
         st->lm = c->ledgerSeq;
+        if (book_file(env, e) < 0)
+            goto out;
         if (!e->base && !e->in_created) {
             if (elist_push(&c->created_offers, e) < 0) {
                 env->oom = 1;
@@ -4380,7 +4646,10 @@ static int apply_tx_v1(AEnv *env, Tx *t, int64_t fee_for_result)
                 return -1;
         } else {
             rollback_level(env, 3);
-            c->idPool = op_idpool;
+            /* only an offer op moves it, and only in a dynamic (serial)
+               close: parallel clusters must not even store it back */
+            if (c->idPool != op_idpool)
+                c->idPool = op_idpool;
             ok = 0;
         }
         if (op->optype >= 0 && op->optype < MAX_OPTYPES) {
@@ -4394,7 +4663,8 @@ static int apply_tx_v1(AEnv *env, Tx *t, int64_t fee_for_result)
             return -1;
     } else {
         rollback_level(env, 2);
-        c->idPool = tx_idpool;
+        if (c->idPool != tx_idpool)
+            c->idPool = tx_idpool;
     }
     t->out_have = 1;
     t->out_code = ok ? txSUCCESS : txFAILED;
@@ -5537,14 +5807,16 @@ static PyObject *apply_close(PyObject *self, PyObject *args)
         }
         out = Py_BuildValue(
             "{s:L,s:L,s:O,s:O,s:O,s:O,s:O,"
-            "s:{s:i,s:i,s:i,s:i,s:L,s:i}}",
+            "s:{s:i,s:i,s:i,s:i,s:L,s:i},s:{s:L,s:L}}",
             "feePool", (long long)c.feePool, "idPool",
             (long long)c.idPool, "changes", changes, "results", results,
             "fee_changes", fee_changes, "meta", metas, "op_stats",
             op_stats, "clusters", "count", nclusters, "max_txs",
             max_cluster, "parallel", used_parallel, "workers",
             used_parallel ? nworkers_used : 1, "apply_ns",
-            (long long)apply_phase_ns, "dynamic", any_dynamic);
+            (long long)apply_phase_ns, "dynamic", any_dynamic, "book",
+            "best_queries", (long long)c.best_queries, "best_steps",
+            (long long)c.best_steps);
         Py_DECREF(op_stats);
         if (!out)
             c.pyerr = 1;

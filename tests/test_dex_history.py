@@ -193,6 +193,46 @@ def test_no_span_and_the_same_counts_with_tracing_off(dep):
     assert count(app, "ledger.apply.book.loads") > 0
 
 
+def test_best_offer_counts_ride_the_apply_span_and_repeat(dep):
+    """ISSUE 32: the engine's best-offer lookups and the index records
+    they examined are exact counts: the `close.apply` tags of the
+    dynamic closes sum to the meters, `GET applystats` says the same,
+    a second replay (tracing off) counts the same, and a lookup reads
+    the head of a side, not the side (60 offers here)."""
+    app, _ = replay(dep, trace=True)
+    queries = count(app, "ledger.apply.book.best_queries")
+    steps = count(app, "ledger.apply.book.best_steps")
+    applies = [s for s in app.tracer.spans()
+               if s.name == "close.apply" and s.dur is not None]
+    asked = [s for s in applies if "best_queries" in s.tags]
+    assert len(asked) == dep.hist.book_ledgers
+    assert all(s.tags["mode"] == "dynamic" for s in asked)
+    assert sum(s.tags["best_queries"] for s in asked) == queries > 0
+    assert sum(s.tags["best_steps"] for s in asked) == steps
+    # every offer op and every hop of a path payment asks at least once
+    ops = app.ledger_manager.apply_stats.to_json()["ops"]
+    assert queries >= sum(d["count"] for n, d in ops.items()
+                          if "offer" in n or "path" in n)
+    assert queries <= steps < 2 * queries
+    blob = app.ledger_manager.apply_stats.to_json()
+    assert blob["book"]["best_queries"] == queries
+    assert blob["book"]["best_steps"] == steps
+    assert blob["last_close"]["book"] == {
+        "best_queries": asked[-1].tags["best_queries"],
+        "best_steps": asked[-1].tags["best_steps"]}
+    again, _ = replay(dep, trace=False)
+    assert not again.tracer.enabled
+    assert count(again, "ledger.apply.book.best_queries") == queries
+    assert count(again, "ledger.apply.book.best_steps") == steps
+
+
+def test_the_python_oracle_asks_the_index_nothing(dep):
+    app, _ = replay(dep, native=False)
+    assert count(app, "ledger.apply.book.best_queries") == 0
+    assert app.ledger_manager.apply_stats.to_json()["book"] == {
+        "loads": 0, "rows": 0, "best_queries": 0, "best_steps": 0}
+
+
 def test_every_ledger_with_a_book_op_is_a_dynamic_close(dep):
     app, _ = replay(dep)
     closes = dep.hist.tip - 1
@@ -224,6 +264,7 @@ def test_a_payments_only_archive_has_no_dynamic_close(tmp_path):
         app = d.first
         assert count(app, "ledger.apply.cluster.dynamic-close") == 0
         assert count(app, "ledger.apply.book.loads") == 0
+        assert count(app, "ledger.apply.book.best_queries") == 0
         assert count(app, "ledger.apply.cluster.serial-close") + \
             count(app, "ledger.apply.cluster.parallel-close") == d.hist.tip - 1
         assert app.ledger_manager.apply_stats.to_json()[

@@ -764,8 +764,9 @@ def test_native_apply_parallel_seeded(seed):
     assert h.parallel.apply_stats.clusters["parallel_closes"] >= 1
 
 
-def _random_full_frames(rng, h, world, fresh_counter):
-    """One close worth of random frames over ALL op types."""
+def _random_full_frames(rng, h, world, fresh_counter, max_offer_id=9):
+    """One close worth of random frames over ALL op types; an offer op
+    that names an offer draws its id below `max_offer_id`."""
     root, users, ix, ir, X, R = world
     frames = []
     sources = list(users) + [ix]
@@ -790,12 +791,14 @@ def _random_full_frames(rng, h, world, fresh_counter):
                     rng.choice([Asset.native(), X]),
                     rng.choice([0, 10, 500]),
                     rng.randrange(1, 4), rng.randrange(1, 4),
-                    offer_id=rng.choice([0, 0, rng.randrange(1, 9)]))]
+                    offer_id=rng.choice(
+                        [0, 0, rng.randrange(1, max_offer_id)]))]
             else:
                 ops = [src.op_manage_buy_offer(
                     Asset.native(), X, rng.choice([0, 25, 400]),
                     rng.randrange(1, 4), rng.randrange(1, 4),
-                    offer_id=rng.choice([0, 0, rng.randrange(1, 9)]))]
+                    offer_id=rng.choice(
+                        [0, 0, rng.randrange(1, max_offer_id)]))]
             if ops[0].body.value.selling == ops[0].body.value.buying:
                 continue
         elif kind < 0.40:  # path payments
@@ -967,3 +970,528 @@ def test_pipeline_stall_fault_runs_prewarm_inline():
     assert work._pipeline is None             # no worker spawned
     m = app.metrics.to_json().get("catchup.pipeline.stall")
     assert m and m["count"] == 1
+
+
+# ------------------------------------------- the order-book index (ISSUE 32)
+#
+# The engine keeps each book side in price order (native/applyc.c, section
+# "order books") and reads the best offer off the head of that index. The
+# oracle is the Python path, which walks the side; these cases put the
+# index through every way an offer's place in it changes — and assert, as
+# every case of this file does, equal results, meta, delta and header
+# hash on every close.
+
+NATIVE = Asset.native()
+RUNGS, PER_RUNG, LOT = 20, 15, 100
+
+
+def _with_source(acct, op):
+    """`op` as built by a TestAccount, with `acct` as its source: a
+    transaction of ops by several accounts applies them in ITS order."""
+    return TestAccount.op(op.body, source=acct.account_id)
+
+
+def _claims(frame, op_index=0):
+    """(offerID, amountSold) of every ClaimOfferAtom of one manage-offer
+    op result."""
+    succ = frame.result.op_results[op_index].value.value.value
+    return [(a.offerID, a.amountSold) for a in succ.offersClaimed]
+
+
+def _book(h, selling, buying):
+    """The native side's resting offers of one pair, best first:
+    [(offerID, seller key, n, d, amount)]."""
+    return _book_of(h.native, selling, buying)
+
+
+def _book_of(lm, selling, buying):
+    from stellar_core_tpu.xdr import LedgerEntryType
+    out = []
+    for e in lm.root.all_entries():
+        if e.data.disc != LedgerEntryType.OFFER:
+            continue
+        o = e.data.value
+        if o.selling == selling and o.buying == buying:
+            out.append((o.offerID, o.sellerID.key_bytes, o.price.n,
+                        o.price.d, o.amount))
+    out.sort(key=lambda r: (r[2] / r[3], r[0]))
+    assert all(a[2] * b[3] <= b[2] * a[3] for a, b in zip(out, out[1:]))
+    return out
+
+
+class _DeepWorld:
+    """A side of RUNGS x PER_RUNG = 300 offers selling X for native, from
+    five makers, three offers a maker a rung (ties at every rung, within
+    a maker and across makers): rung k asks (20 + k) / 10 native a unit
+    of X, LOT units an offer (orders come in tens of units, so every fill
+    is exact). A second asset Y of the same issuer and a
+    taker with funds in everything."""
+
+    def __init__(self, h, second_side=False):
+        self.h = h
+        root = h.account(root_secret_key())
+        self.root = root
+        self.ix = h.account(SecretKey.from_seed(sha256(b"deep-ix")))
+        self.makers = [h.account(SecretKey.from_seed(sha256(b"deep-m%d" % i)))
+                       for i in range(5)]
+        self.taker = h.account(SecretKey.from_seed(sha256(b"deep-t")))
+        self.other = h.account(SecretKey.from_seed(sha256(b"deep-o")))
+        everyone = self.everyone = self.makers + [self.taker, self.other]
+        h.close([root.tx(
+            [root.op_create_account(a.account_id, 10 ** 11)
+             for a in everyone + [self.ix]])])
+        self.X = Asset.credit("USD", self.ix.account_id)
+        self.Y = Asset.credit("EUR", self.ix.account_id)
+        h.close([a.tx([a.op_change_trust(self.X, 10 ** 15),
+                       a.op_change_trust(self.Y, 10 ** 15)])
+                 for a in everyone])
+        h.close([self.ix.tx(
+            [self.ix.op_payment(a.account_id, 10 ** 10, asset)
+             for a in everyone for asset in (self.X, self.Y)])])
+        h.close([m.tx([m.op_manage_sell_offer(self.X, NATIVE, LOT,
+                                              20 + k, 10)
+                       for k in range(RUNGS) for _ in range(3)])
+                 for m in self.makers])
+        if second_side:
+            # the other side, as deep: bids of native for X from 0.6 X a
+            # native unit upwards (asks start at 2 native: no cross)
+            h.close([m.tx([m.op_manage_sell_offer(NATIVE, self.X, LOT,
+                                                  6 + k, 10)
+                           for k in range(RUNGS) for _ in range(3)])
+                     for m in self.makers])
+        self.asks = _book(h, self.X, NATIVE)
+        assert len(self.asks) == RUNGS * PER_RUNG
+        assert len({(n, d) for _, _, n, d, _ in self.asks}) == RUNGS
+
+    def maker_of(self, offer_id):
+        key = next(r[1] for r in self.asks if r[0] == offer_id)
+        return next(m for m in self.makers
+                    if m.account_id.key_bytes == key)
+
+    def take(self, units, n=10, d=1, acct=None):
+        """An offer by the taker to buy `units` of X at up to n/d native
+        each (a manage_buy_offer selling native)."""
+        a = acct or self.taker
+        return _with_source(a, a.op_manage_buy_offer(NATIVE, self.X, units,
+                                                     n, d))
+
+    def in_order(self, first_ops, then_ops):
+        """Two transactions of the taker's, which apply in sequence
+        order: `first_ops`, then `then_ops`; each signed by the taker and
+        by exactly the other sources of its ops."""
+        seq = self.taker.next_seq()
+        return [_signed_by_sources(self.taker, ops, seq + i, self.everyone)
+                for i, ops in enumerate((first_ops, then_ops))]
+
+
+def _signed_by_sources(acct, ops, seq, accounts):
+    """`acct`'s transaction of `ops`, with the signature of every other
+    account an op names as its source (an unused signature fails a
+    transaction, so no more than those)."""
+    mine = acct.account_id.key_bytes
+    keys = []
+    for op in ops:
+        src = op.sourceAccount
+        key = src.account_id.key_bytes if src is not None else mine
+        if key != mine and key not in keys:
+            keys.append(key)
+    by_key = {a.account_id.key_bytes: a for a in accounts}
+    return acct.tx(ops, seq=seq, extra_signers=[by_key[k].sk for k in keys])
+
+
+def _expect_fills(asks, units):
+    """What a taker of `units` takes off `asks` (best first, whole lots
+    of LOT, the last in part): [(offerID, amountSold)]."""
+    out = []
+    for oid, _, _, _, amount in asks:
+        if units <= 0:
+            break
+        out.append((oid, min(amount, units)))
+        units -= amount
+    return out
+
+
+def test_index_deep_side_crossed_across_rungs():
+    """300 offers on 20 rungs; three takers in one close take 2.4, 1.1
+    and 0.2 rungs' worth: price order across rungs, id order inside a
+    rung, partial fill of the last."""
+    h = DiffHarness()
+    w = _DeepWorld(h)
+    rung = PER_RUNG * LOT
+    sizes = [2 * rung + 640, rung + 130, 270]
+    before = h.closes_native
+    frames = h.close(w.in_order([w.take(sizes[0])],
+                                [w.take(sizes[1]), w.take(sizes[2])]))
+    assert h.closes_native == before + 1
+    assert all(f.result.code == TransactionResultCode.txSUCCESS
+               for f in frames)
+    by_seq = sorted(frames, key=lambda f: f.envelope.value.tx.seqNum)
+    got = _claims(by_seq[0]) + _claims(by_seq[1], 0) + _claims(by_seq[1], 1)
+    # one continuous walk of the side in (price, id) order; an order ends
+    # inside an offer and the next goes on with that offer's rest
+    want, book = [], [list(r) for r in w.asks]
+    for units in sizes:
+        fills = _expect_fills(book, units)
+        want += fills
+        for oid, sold in fills:
+            row = next(r for r in book if r[0] == oid)
+            row[4] -= sold
+        book = [r for r in book if r[4] > 0]
+    assert got == want and len(got) >= 50
+    assert len({(r[2], r[3]) for r in w.asks
+                if r[0] in {o for o, _ in got}}) >= 4   # rungs crossed
+    assert len(_book(h, w.X, NATIVE)) == RUNGS * PER_RUNG - (len(got) - 3)
+
+
+@pytest.mark.parametrize("loaded_first", [False, True],
+                         ids=["side-unloaded", "side-loaded"])
+def test_index_requote_better_and_worse_then_crossed(loaded_first):
+    """A re-quote by offerID that moves an offer to the head of the side,
+    and one that moves the head to the tail, then a taker in the same
+    close: the index follows both — whether the re-quoted offers come
+    from the lookup callback (side not loaded yet) or from the side's
+    own rows (a taker loaded it first)."""
+    h = DiffHarness()
+    w = _DeepWorld(h)
+    first = [w.take(30)] if loaded_first else []
+    head = w.asks[1 if loaded_first else 0][0]
+    mid = w.asks[10 * PER_RUNG + 4][0]    # somewhere on rung 10
+    m_head, m_mid = w.maker_of(head), w.maker_of(mid)
+    frames = h.close(w.in_order(
+        first +
+        [_with_source(m_mid, m_mid.op_manage_sell_offer(
+            w.X, NATIVE, LOT, 150, 100, offer_id=mid)),      # better
+         _with_source(m_head, m_head.op_manage_sell_offer(
+             w.X, NATIVE, LOT, 900, 100, offer_id=head))],   # worse
+        [w.take(3 * LOT)]))
+    by_seq = sorted(frames, key=lambda f: f.envelope.value.tx.seqNum)
+    assert all(f.result.code == TransactionResultCode.txSUCCESS
+               for f in frames)
+    rest = [r for r in w.asks if r[0] not in (head, mid)]
+    if loaded_first:
+        assert _claims(by_seq[0], 0) == [(w.asks[0][0], 30)]
+        assert _claims(by_seq[1]) == [(mid, LOT), (rest[0][0], LOT - 30),
+                                      (rest[1][0], LOT), (rest[2][0], 30)]
+    else:
+        assert _claims(by_seq[1]) == [(mid, LOT), (rest[0][0], LOT),
+                                      (rest[1][0], LOT)]
+    book = _book(h, w.X, NATIVE)
+    assert book[-1][0] == head and (book[-1][2], book[-1][3]) == (900, 100)
+
+
+@pytest.mark.parametrize("loaded_first", [False, True],
+                         ids=["new-pair-unloaded", "new-pair-loaded"])
+def test_index_update_moves_offer_to_another_pair(loaded_first):
+    """An update by offerID that changes the pair: the offer leaves the
+    old pair's side and joins the new pair's, whether or not the engine
+    had loaded the new pair's side before the move."""
+    h = DiffHarness()
+    w = _DeepWorld(h)
+    head = w.asks[0][0]
+    m = w.maker_of(head)
+    t = w.taker
+    first = []
+    if loaded_first:
+        # the taker asks for the best offer of (Y, native) before the
+        # move: nothing there yet, its bid rests
+        first.append(_with_source(t, t.op_manage_buy_offer(
+            NATIVE, w.Y, 10, 1, 100)))
+    first.append(_with_source(m, m.op_manage_sell_offer(
+        w.Y, NATIVE, LOT, 3, 1, offer_id=head)))
+    frames = h.close(w.in_order(
+        first,
+        [w.take(LOT),                                      # the old pair
+         _with_source(t, t.op_manage_buy_offer(NATIVE, w.Y, 40, 3, 1))]))
+    by_seq = sorted(frames, key=lambda f: f.envelope.value.tx.seqNum)
+    assert all(f.result.code == TransactionResultCode.txSUCCESS
+               for f in frames)
+    assert _claims(by_seq[1], 0) == [(w.asks[1][0], LOT)]
+    assert _claims(by_seq[1], 1) == [(head, 40)]
+    assert [r[0] for r in _book(h, w.Y, NATIVE)] == [head]
+    assert head not in {r[0] for r in _book(h, w.X, NATIVE)}
+
+
+@pytest.mark.parametrize("loaded_first", [False, True],
+                         ids=["side-unloaded", "side-loaded"])
+def test_index_offer_created_then_crossed_in_one_close(loaded_first):
+    """An offer created in a close is found by a taker later in the same
+    close, ahead of the 300 the root holds."""
+    h = DiffHarness()
+    w = _DeepWorld(h)
+    o = w.other
+    first = [w.take(30)] if loaded_first else []
+    first.append(_with_source(o, o.op_manage_sell_offer(
+        w.X, NATIVE, 70, 3, 2)))
+    new_id = h.native.root.get_header().idPool + 1
+    frames = h.close(w.in_order(first, [w.take(LOT)]))
+    by_seq = sorted(frames, key=lambda f: f.envelope.value.tx.seqNum)
+    assert all(f.result.code == TransactionResultCode.txSUCCESS
+               for f in frames)
+    rest = LOT - 30 if loaded_first else LOT
+    assert _claims(by_seq[1]) == [(new_id, 70), (w.asks[0][0], min(rest, 30))]
+
+
+@pytest.mark.parametrize("first", ["requote-better", "requote-pair",
+                                   "requote-worse-then-fill",
+                                   "requote-pair-then-fill",
+                                   "fill-part", "fill-whole", "create",
+                                   "delete", "cross-self"])
+def test_index_rollback_restores_the_side(first):
+    """A multi-op transaction whose LAST op fails after an earlier one
+    re-priced, moved, filled, erased or created an offer: the rollback
+    puts pair, price and existence back, and a taker later in the same
+    close meets the side as the root has it."""
+    h = DiffHarness()
+    w = _DeepWorld(h)
+    t, o = w.taker, w.other
+    head, second = w.asks[0][0], w.asks[1][0]
+    deep = w.asks[7 * PER_RUNG][0]
+    m_head, m_deep = w.maker_of(head), w.maker_of(deep)
+    if first == "requote-better":
+        ops = [_with_source(m_deep, m_deep.op_manage_sell_offer(
+            w.X, NATIVE, LOT, 101, 100, offer_id=deep))]
+    elif first == "requote-pair":
+        ops = [_with_source(m_head, m_head.op_manage_sell_offer(
+            w.Y, NATIVE, LOT, 2, 1, offer_id=head))]
+    elif first == "requote-worse-then-fill":
+        # the taker's lookup drops the head's record, dead since the
+        # re-quote, before the rollback brings the head back
+        ops = [_with_source(m_head, m_head.op_manage_sell_offer(
+            w.X, NATIVE, LOT, 7, 1, offer_id=head)), w.take(40)]
+    elif first == "requote-pair-then-fill":
+        ops = [_with_source(m_head, m_head.op_manage_sell_offer(
+            w.Y, NATIVE, LOT, 2, 1, offer_id=head)), w.take(40)]
+    elif first == "fill-part":
+        ops = [w.take(40)]
+    elif first == "fill-whole":
+        ops = [w.take(2 * LOT + 10)]
+    elif first == "create":
+        ops = [_with_source(o, o.op_manage_sell_offer(w.X, NATIVE, 55, 1, 1))]
+    elif first == "delete":
+        ops = [_with_source(m_head, m_head.op_manage_sell_offer(
+            w.X, NATIVE, 0, 2, 1, offer_id=head))]
+    else:
+        # the maker of a rung-0 offer takes its own side: the offers ahead
+        # of its own fill, then the op fails on its own offer and the op's
+        # own rollback (not the transaction's) restores them
+        third = w.asks[2][0]
+        mk = w.maker_of(third)
+        if mk in (w.maker_of(head), w.maker_of(second)):
+            third = next(r[0] for r in w.asks[:PER_RUNG]
+                         if w.maker_of(r[0]) not in
+                         (w.maker_of(head), w.maker_of(second)))
+            mk = w.maker_of(third)
+        ops = [w.take(PER_RUNG * LOT, acct=mk)]
+    if first != "cross-self":
+        # the op that fails: more native than the taker has
+        ops.append(_with_source(t, t.op_payment(o.account_id, 10 ** 13)))
+    frames = h.close(w.in_order(ops, [w.take(2 * LOT + 20)]))
+    by_seq = sorted(frames, key=lambda f: f.envelope.value.tx.seqNum)
+    assert by_seq[0].result.code == TransactionResultCode.txFAILED
+    assert by_seq[1].result.code == TransactionResultCode.txSUCCESS
+    assert _claims(by_seq[1]) == [(head, LOT), (second, LOT),
+                                  (w.asks[2][0], 20)]
+    assert _book(h, w.Y, NATIVE) == []
+
+
+def test_index_revoke_erases_indexed_offers_mid_close():
+    """An allow-trust revoke in the middle of a close erases a maker's
+    offers from a side the engine has indexed, the head among them; the
+    takers before and after it meet what is live."""
+    from stellar_core_tpu.xdr import AccountFlags
+    h = DiffHarness()
+    root = h.account(root_secret_key())
+    ir = h.account(SecretKey.from_seed(sha256(b"rev-ir")))
+    makers = [h.account(SecretKey.from_seed(sha256(b"rev-m%d" % i)))
+              for i in range(3)]
+    t = h.account(SecretKey.from_seed(sha256(b"rev-t")))
+    h.close([root.tx([root.op_create_account(a.account_id, 10 ** 10)
+                      for a in makers + [t, ir]])])
+    h.close([ir.tx([ir.op_set_options(
+        set_flags=AccountFlags.AUTH_REQUIRED_FLAG |
+        AccountFlags.AUTH_REVOCABLE_FLAG)])])
+    R = Asset.credit("RST", ir.account_id)
+    h.close([a.tx([a.op_change_trust(R, 10 ** 12)]) for a in makers + [t]])
+    h.close([ir.tx([ir.op_allow_trust(a.account_id, b"RST\x00")
+                    for a in makers + [t]] +
+                   [ir.op_payment(a.account_id, 10 ** 8, R)
+                    for a in makers + [t]])])
+    # 3 makers x 4 rungs x 5: maker i's offers lead rung i
+    h.close([m.tx([m.op_manage_sell_offer(R, NATIVE, LOT, 2 + k, 1)
+                   for k in range(4) for _ in range(5)])
+             for m in makers])
+    asks = _book(h, R, NATIVE)
+    assert len(asks) == 60
+    gone = next(m for m in makers
+                if m.account_id.key_bytes == asks[0][1])
+    mine = {r[0] for r in asks if r[1] == gone.account_id.key_bytes}
+    left = [r for r in asks if r[0] not in mine]
+
+    def buy(units):
+        return _with_source(t, t.op_manage_buy_offer(NATIVE, R, units,
+                                                     10, 1))
+    seq = ir.next_seq()
+    first = _signed_by_sources(
+        ir, [buy(30),
+             ir.op_allow_trust(gone.account_id, b"RST\x00", authorize=0),
+             buy(LOT + 10)], seq, [t])
+    second = _signed_by_sources(ir, [buy(LOT)], seq + 1, [t])
+    before = h.closes_native
+    frames = h.close([first, second])
+    assert h.closes_native == before + 1
+    by_seq = sorted(frames, key=lambda f: f.envelope.value.tx.seqNum)
+    assert all(f.result.code == TransactionResultCode.txSUCCESS
+               for f in frames)
+    assert _claims(by_seq[0], 0) == [(asks[0][0], 30)]
+    assert _claims(by_seq[0], 2) == [(left[0][0], LOT), (left[1][0], 10)]
+    assert _claims(by_seq[1], 0) == [(left[1][0], LOT - 10),
+                                     (left[2][0], 10)]
+    assert not mine & {r[0] for r in _book(h, R, NATIVE)}
+
+
+def test_index_passive_offer_at_the_head_at_an_equal_price():
+    """A passive offer heads the side. A taker whose limit EQUALS its
+    price does not cross it (and rests); a taker with a better limit
+    crosses it first; the order of the side is unmoved by its flag."""
+    h = DiffHarness()
+    w = _DeepWorld(h)
+    o, t = w.other, w.taker
+    h.close([o.tx([o.op_create_passive_sell_offer(w.X, NATIVE, 80, 3, 2)])])
+    passive = h.native.root.get_header().idPool
+    assert _book(h, w.X, NATIVE)[0][0] == passive
+    frames = h.close(w.in_order(
+        # buying X at exactly 3/2 native: its price as a seller of native
+        # is 2/3 X a unit
+        [_with_source(t, t.op_manage_sell_offer(NATIVE, w.X, 300, 2, 3))],
+        [w.take(LOT, n=2, d=1)]))
+    by_seq = sorted(frames, key=lambda f: f.envelope.value.tx.seqNum)
+    assert all(f.result.code == TransactionResultCode.txSUCCESS
+               for f in frames)
+    assert _claims(by_seq[0]) == []
+    assert _claims(by_seq[1]) == [(passive, 80), (w.asks[0][0], 20)]
+    assert len(_book(h, NATIVE, w.X)) == 1
+
+
+def _deepen_books(h, world, per_side=200):
+    """Rest `per_side` offers on each side of X/native from the fuzz
+    world's two funded users, at the prices the fuzz itself draws from
+    (n, d in 1..3) that cannot cross each other: asks at 2, 3 and 3/2
+    native, bids at 1, 2, 3 and 3/2 X."""
+    root, users, ix, ir, X, R = world
+    u0, u1 = users[0], users[1]
+    h.close([root.tx([root.op_payment(u.account_id, 10 ** 10)
+                      for u in (u0, u1)])])
+    asks = [(2, 1), (3, 1), (3, 2)]
+    bids = [(1, 1), (2, 1), (3, 1), (3, 2)]
+    half = per_side // 2
+    for u in (u0, u1):
+        h.close([u.tx([u.op_manage_sell_offer(X, NATIVE, 6 + 2 * (i % 5),
+                                              *asks[i % len(asks)])
+                       for i in range(half)])])
+        h.close([u.tx([u.op_manage_sell_offer(NATIVE, X, 6 + 6 * (i % 4),
+                                              *bids[i % len(bids)])
+                       for i in range(half)])])
+    assert len(_book(h, X, NATIVE)) >= per_side
+    assert len(_book(h, NATIVE, X)) >= per_side
+
+
+@pytest.mark.parametrize("seed", [0xD0E5, 0xB00C])
+def test_native_apply_randomized_deep_books(seed):
+    """The seeded matrix over all op types (offers with and without an
+    offerID, buy offers, path payments both ways, allow-trust revokes)
+    against books of 200 offers a side."""
+    rng = random.Random(seed)
+    h = DiffHarness()
+    world = _coverage_world(h)
+    _deepen_books(h, world)
+    fresh_counter = [0]
+    native_before = h.closes_native
+    for _ in range(8):
+        frames = _random_full_frames(rng, h, world, fresh_counter,
+                                     max_offer_id=450)
+        if frames:
+            h.close(frames)
+    assert h.closes_native > native_before
+    book = h.native.apply_stats.to_json()["book"]
+    assert book["best_queries"] > 0
+
+
+def _count_world(h, per_side):
+    """`per_side` asks and as many bids on X/native from 25 makers, one
+    offer a maker a rung, posted through the engine alone (no oracle: `h`
+    is one native manager); returns (makers, X, close)."""
+    shim = _Shim(h)
+    root = TestAccount(shim, root_secret_key())
+    ix = TestAccount(shim, SecretKey.from_seed(sha256(b"cnt-ix")))
+    makers = [TestAccount(shim, SecretKey.from_seed(sha256(b"cnt-m%d" % i)))
+              for i in range(25)]
+    rungs = per_side // len(makers)
+
+    def close(frames):
+        header = h.root.get_header()
+        ts = TxSetFrame(TESTING_NETWORK_ID, h.lcl_hash, frames)
+        value = StellarValue(
+            txSetHash=ts.get_contents_hash(),
+            closeTime=header.scpValue.closeTime + 5,
+            upgrades=[], ext=StellarValueExt(0, None))
+        h.close_ledger(LedgerCloseData(header.ledgerSeq + 1, ts, value))
+        assert all(f.result.code == TransactionResultCode.txSUCCESS
+                   for f in frames), [f.result.code for f in frames]
+        assert all(f._native_meta_b is not None for f in frames)
+
+    close([root.tx([root.op_create_account(a.account_id, 10 ** 11)
+                    for a in makers + [ix]])])
+    X = Asset.credit("USD", ix.account_id)
+    close([m.tx([m.op_change_trust(X, 10 ** 15)]) for m in makers])
+    close([ix.tx([ix.op_payment(m.account_id, 10 ** 9, X)
+                  for m in makers])])
+    # asks from 2.00 native a unit of X upwards, bids from 0.51 X a native
+    # unit upwards (2.00 x 0.51 > 1: the sides do not cross)
+    close([m.tx([m.op_manage_sell_offer(X, NATIVE, 100, 200 + i, 100)
+                 for i in range(rungs)]) for m in makers])
+    close([m.tx([m.op_manage_sell_offer(NATIVE, X, 100, 51 + i, 100)
+                 for i in range(rungs)]) for m in makers])
+    return makers, X, close
+
+
+@pytest.mark.parametrize("per_side", [250, 2500])
+def test_best_offer_cost_does_not_grow_with_the_side(per_side):
+    """By count, not by time: over sides of 250 and of 2,500 resting
+    offers the same 50 re-quotes that cross nothing each ask for the
+    best offer of the other side once, and each answer looks at a
+    handful of index records at either size. A walk reads the side:
+    250 or 2,500 records a query."""
+    h = DiffHarness._mk(True)
+    makers, X, close = _count_world(h, per_side)
+    # the posting of the bids asked for the best ask once an offer
+    assert h.apply_stats.to_json()["book"]["rows"] == per_side
+    before = dict(h.apply_stats.to_json()["book"])
+    # each maker re-quotes its best ask and its best bid a tick towards
+    # the other side: neither crosses
+    close([m.tx([m.op_manage_sell_offer(X, NATIVE, 100, 199, 100,
+                                        offer_id=ask),
+                 m.op_manage_sell_offer(NATIVE, X, 100, 505, 1000,
+                                        offer_id=bid)])
+           for m, ask, bid in _own_best(h, makers, X)])
+    after = h.apply_stats.to_json()["book"]
+    queries = after["best_queries"] - before["best_queries"]
+    steps = after["best_steps"] - before["best_steps"]
+    assert after["rows"] - before["rows"] == 2 * per_side
+    # one query a re-quote: cross_offers asks, meets a price past the
+    # limit and stops
+    assert queries == 50
+    assert h.apply_stats.to_json()["last_close"]["book"] == {
+        "best_queries": queries, "best_steps": steps}
+    assert 50 <= steps <= 4 * 50, steps
+
+
+def _own_best(h, makers, X):
+    """(maker, id of its best ask, id of its best bid) for each maker."""
+    asks, bids = _book_of(h, X, NATIVE), _book_of(h, NATIVE, X)
+    out = []
+    for m in makers:
+        key = m.account_id.key_bytes
+        out.append((m, next(r[0] for r in asks if r[1] == key),
+                    next(r[0] for r in bids if r[1] == key)))
+    return out
+

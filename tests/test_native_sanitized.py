@@ -112,6 +112,14 @@ def test_threaded_parallel_close_under_asan_ubsan():
          "tests/test_native_apply.py::"
          "test_native_apply_randomized_full_matrix",
          "tests/test_native_apply.py::test_native_apply_all_op_types",
+         # ISSUE 32: the order-book index (heap growth, records of
+         # offers that died or moved, the refile on rollback) over a
+         # 300-offer side and over 2,500
+         "tests/test_native_apply.py::"
+         "test_index_deep_side_crossed_across_rungs",
+         "tests/test_native_apply.py::test_index_rollback_restores_the_side",
+         "tests/test_native_apply.py::"
+         "test_best_offer_cost_does_not_grow_with_the_side",
          "-q", "-p", "no:cacheprovider"],
         capture_output=True, text=True, cwd=REPO, env=env, timeout=1200)
     tail = (r.stdout or "")[-4000:] + (r.stderr or "")[-4000:]
@@ -219,3 +227,24 @@ def test_threaded_parallel_close_under_tsan():
     assert r.returncode == 0, tail
     assert "WARNING: ThreadSanitizer" not in r.stderr, r.stderr[-6000:]
     assert "3 passed" in r.stdout, tail
+
+
+def test_order_book_index_under_tsan():
+    """ISSUE 32: the price index of a book side takes no lock, because a
+    close with an order-book op applies on one thread with the GIL held.
+    The deep-book differential cases (a 300-offer side crossed over
+    several rungs, every rollback of an indexed offer, a revoke in the
+    middle of a close, the seeded matrix over 200-offer sides) run under
+    ThreadSanitizer beside the engine's worker pool, which the static
+    closes of their set-up ledgers start: zero reports."""
+    env = _tsan_run_env()
+    _tsan_prebuild()
+    r = subprocess.run(
+        [sys.executable, "-m", "pytest", "tests/test_native_apply.py",
+         "-k", "test_index or deep_books",
+         "-q", "-p", "no:cacheprovider"],
+        capture_output=True, text=True, cwd=REPO, env=env, timeout=1800)
+    tail = (r.stdout or "")[-4000:] + (r.stderr or "")[-4000:]
+    assert r.returncode == 0, tail
+    assert "WARNING: ThreadSanitizer" not in r.stderr, r.stderr[-6000:]
+    assert "20 passed" in r.stdout, tail

@@ -128,6 +128,40 @@ def test_update_offer_preserves_passive_flag(market):
     assert e.data.value.amount == 500
 
 
+def test_update_by_id_moves_an_offer_to_another_pair(market):
+    """An update by offerID may name another pair (reference
+    ManageOfferOpFrameBase: the old offer is pulled, the new one built
+    from the op): the offer leaves its old side and is the best of its
+    new one. The native engine's price index is held to this by
+    tests/test_native_apply.py::test_index_update_moves_offer_to_another_pair."""
+    led, root, issuer, usd, a, b, c = market
+    eur = Asset.credit("EUR", issuer.account_id)
+    for acct in (a, b):
+        assert acct.change_trust(eur, 10**12)
+        assert issuer.pay(acct, 10**9, eur)
+    f = a.tx([a.op_manage_sell_offer(usd, XLM, 100, 2, 1)])
+    assert led.apply_frame(f)
+    oid = f.result.op_results[0].value.value.value.offer.value.offerID
+    assert led.apply_frame(
+        c.tx([c.op_manage_sell_offer(usd, XLM, 100, 3, 1)]))
+    assert led.apply_frame(
+        a.tx([a.op_manage_sell_offer(eur, XLM, 100, 2, 1, oid)]))
+    moved = led.root.get_entry(X.LedgerKey.offer(a.account_id, oid))
+    assert moved.data.value.selling == eur
+    # a taker of USD meets c's offer at 3, not a's old one at 2 ...
+    f = b.tx([_op_buy(b, XLM, usd, 10, 5, 1)])
+    assert led.apply_frame(f), f.result
+    succ = f.result.op_results[0].value.value.value
+    assert [atom.sellerID.key_bytes for atom in succ.offersClaimed] == \
+        [c.account_id.key_bytes]
+    # ... and a taker of EUR meets a's
+    f = b.tx([_op_buy(b, XLM, eur, 10, 5, 1)])
+    assert led.apply_frame(f), f.result
+    succ = f.result.op_results[0].value.value.value
+    assert [(atom.offerID, atom.amountSold)
+            for atom in succ.offersClaimed] == [(oid, 10)]
+
+
 # ------------------------------------------------ herder value validation
 
 def test_herder_rejects_bad_close_times():
