@@ -1533,7 +1533,13 @@ def bucketdb_main(argv) -> int:
     within 1.25x from --small to --large accounts, surge prefetch
     hit-rate >= 95%, bloom false positives <= 5%, and ZERO apply-path
     SQL point lookups across every measured close (cockpit-asserted).
-    Records gate against bench/history.jsonl like every other leg."""
+    Records gate against bench/history.jsonl like every other leg.
+
+    Its seeder (`_bucketdb_seed_state`) writes the bucket only: SQL
+    never sees those accounts, so what it installs is no deployment's
+    state. The loader that seeds BOTH stores, the one a node restarts
+    from and a benchmark cell measures, is
+    `benchmark/traffic/state_history.py::bulk_load` (ISSUE 33)."""
     import argparse
     bc = _bench_compare_mod()
     ap = argparse.ArgumentParser(prog="bench.py --bucketdb")

@@ -14,8 +14,8 @@ same byte layout the hash chain commits to.
 
 from __future__ import annotations
 
-import os
-from typing import Iterable, List, Optional, Sequence, Tuple
+import hashlib
+from typing import Iterable, Iterator, List, Optional, Sequence, Tuple
 
 from ..util.xdrstream import XDRInputFileStream, XDROutputFileStream
 from ..xdr import (
@@ -59,56 +59,123 @@ def check_protocol_legality(e: BucketEntry, protocol_version: int) -> None:
 
 class Bucket:
     """An immutable sorted entry run. Empty buckets have the zero hash and
-    no backing file (reference Bucket() default ctor)."""
+    no backing file (reference Bucket() default ctor).
 
-    __slots__ = ("_entries", "_hash", "path")
+    A bucket adopted from its file by name (`from_file`: a restart, a
+    file already in the bucket directory) is not resident: it holds its
+    name and its path, and parses its entries only when something asks
+    for them (a merge, a bucket apply, an index build with no sidecar).
+    Point reads go through the BucketDB index and `pread`, so a
+    restarted node never decodes a deep level it only reads from."""
+
+    __slots__ = ("_entries", "_hash", "path", "_count", "_version")
 
     def __init__(self, entries: Sequence[BucketEntry] = (),
                  hash_: Optional[bytes] = None,
                  path: Optional[str] = None) -> None:
-        self._entries: Tuple[BucketEntry, ...] = tuple(entries)
+        self._entries: Optional[Tuple[BucketEntry, ...]] = tuple(entries)
         if hash_ is None:
             hash_ = _hash_entries(self._entries)
         self._hash = hash_
         self.path = path
+        self._count: Optional[int] = None
+        self._version: Optional[int] = None
+
+    @classmethod
+    def from_file(cls, path: str, expected_hash: bytes
+                  ) -> Optional["Bucket"]:
+        """The bucket in `path`, not resident. Its name is the SHA-256
+        of the file's bytes, streamed here; None where they do not hash
+        to `expected_hash` (a torn or foreign file is a missing
+        bucket)."""
+        h = hashlib.sha256()
+        head = b""
+        with open(path, "rb") as fh:
+            for chunk in iter(lambda: fh.read(1 << 20), b""):
+                head = head or chunk[:12]
+                h.update(chunk)
+        if h.digest() != expected_hash:
+            return None
+        b = cls((), hash_=expected_hash, path=path)
+        b._entries = None
+        # a META record leads: its mark, its discriminant, the version
+        is_meta = len(head) == 12 and \
+            int.from_bytes(head[4:8], "big", signed=True) == _META
+        b._version = int.from_bytes(head[8:12], "big") if is_meta else 0
+        return b
 
     # -- identity ------------------------------------------------------------
     def get_hash(self) -> bytes:
         return self._hash
 
+    @property
+    def resident(self) -> bool:
+        return self._entries is not None
+
     def is_empty(self) -> bool:
-        return not self._entries
+        return self._entries is not None and not self._entries
 
     def __len__(self) -> int:
-        return len(self._entries)
+        if self._entries is not None:
+            return len(self._entries)
+        if self._count is None:
+            self._count = sum(1 for _ in self.record_bodies())
+        return self._count
+
+    def count_hint(self, n: int) -> None:
+        """Entries of a bucket that is not resident, from whoever
+        already knows (its index), so that `len()` never scans the
+        file."""
+        if self._entries is None:
+            self._count = n
 
     @property
     def entries(self) -> Tuple[BucketEntry, ...]:
+        if self._entries is None:
+            with XDRInputFileStream(self.path) as ins:
+                self._entries = tuple(ins.read_all(BucketEntry))
         return self._entries
 
     def __iter__(self):
-        return iter(self._entries)
+        return iter(self.entries)
+
+    def record_bodies(self) -> Iterator[bytes]:
+        """Each entry's XDR body as it sits on disk (the record less its
+        mark), without parsing a bucket that is not resident."""
+        if self._entries is not None:
+            for e in self._entries:
+                yield entry_record(e)[4:]
+            return
+        with open(self.path, "rb", buffering=1 << 20) as fh:
+            while True:
+                mark = fh.read(4)
+                if len(mark) < 4:
+                    return
+                yield fh.read(int.from_bytes(mark, "big") & 0x7FFFFFFF)
 
     # -- metadata ------------------------------------------------------------
     def get_version(self) -> int:
         """Protocol version from the META entry; 0 for empty/pre-11 buckets
         (reference Bucket::getBucketVersion, Bucket.cpp:641-647)."""
+        if self._entries is None:
+            return self._version
         if self._entries and self._entries[0].disc == _META:
             return self._entries[0].value.ledgerVersion
         return 0
 
     def payload_entries(self) -> Tuple[BucketEntry, ...]:
         """Entries excluding the leading META (what input iterators yield)."""
-        if self._entries and self._entries[0].disc == _META:
-            return self._entries[1:]
-        return self._entries
+        entries = self.entries
+        if entries and entries[0].disc == _META:
+            return entries[1:]
+        return entries
 
     # -- persistence ---------------------------------------------------------
     def write_to(self, path: str) -> None:
         # the memoized framed records the hash already serialized —
         # a bucket file write never re-serializes its entries
         with XDROutputFileStream(path) as out:
-            for e in self._entries:
+            for e in self.entries:
                 out.write_record(entry_record(e))
         self.path = path
 

@@ -58,6 +58,7 @@ from ..util.log import get_logger
 from ..util.metrics import MetricsRegistry
 from ..util.threads import TrackedLock
 from ..util.timer import real_monotonic
+from ..util.tracing import tracer_span
 from ..xdr import BucketEntryType, ledger_entry_key
 
 log = get_logger("Bucket")
@@ -501,13 +502,13 @@ class BucketDB:
         self._warned_rebuild = False
 
     # -- index lifecycle -----------------------------------------------------
-    def on_adopt(self, bucket) -> None:
+    def on_adopt(self, bucket) -> Optional[BucketIndex]:
         """Index an adopted bucket (close path for level-0 fresh
         buckets, merge workers for level merges): load the persisted
         sidecar if one matches, else build and persist."""
         if bucket.is_empty() or not self.eager_index:
-            return
-        self.index_for(bucket)
+            return None
+        return self.index_for(bucket)
 
     def index_for(self, bucket) -> BucketIndex:
         h = bucket.get_hash()
@@ -529,10 +530,16 @@ class BucketDB:
         if side is not None and os.path.exists(side):
             t0 = real_monotonic()
             try:
-                if check_faults(self, "bucketdb.index-corrupt"):
-                    raise IndexLoadError("injected index corruption")
-                idx = BucketIndex.load(side, expected_hash=h)
-                self.stats.record_load(real_monotonic() - t0)
+                with tracer_span(self.stats.tracer, "bucketdb.index_load",
+                                 cat="bucket") as sp:
+                    if check_faults(self, "bucketdb.index-corrupt"):
+                        raise IndexLoadError("injected index corruption")
+                    idx = BucketIndex.load(side, expected_hash=h)
+                    seconds = real_monotonic() - t0
+                    if sp.live:
+                        sp.set_tag("keys", len(idx))
+                        sp.set_tag("seconds", round(seconds, 6))
+                self.stats.record_load(seconds)
                 return idx
             except IndexLoadError as e:
                 self.stats.record_load_failure()

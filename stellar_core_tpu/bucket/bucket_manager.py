@@ -110,7 +110,10 @@ class BucketManager:
                 # re-download): serve reads from it
                 b.path = path
             self._shared[h] = b
-        self.bucketdb.on_adopt(b)
+        idx = self.bucketdb.on_adopt(b)
+        if idx is not None:
+            # the index has every entry but the META one
+            b.count_hint(len(idx) + (1 if b.get_version() else 0))
         return b
 
     def get_bucket_by_hash(self, hash_: bytes) -> Optional[Bucket]:
@@ -122,7 +125,13 @@ class BucketManager:
             return b
         path = self.bucket_filename(hash_)
         if path and os.path.exists(path):
-            b = Bucket.read_from(path)
+            # by name, not by content: the file is hashed, not parsed,
+            # and its entries load when a merge or an apply wants them
+            b = Bucket.from_file(path, hash_)
+            if b is None:
+                log.warning("bucket file %s does not hash to its name",
+                            path)
+                return None
             return self.adopt_bucket(b)
         return None
 

@@ -7,6 +7,7 @@ T/X... pre-auth/hash-x signers).
 from __future__ import annotations
 
 import base64
+import binascii
 import struct
 
 
@@ -18,13 +19,9 @@ class StrKeyVersion:
 
 
 def _crc16_xmodem(data: bytes) -> int:
-    crc = 0
-    for byte in data:
-        crc ^= byte << 8
-        for _ in range(8):
-            crc = ((crc << 1) ^ 0x1021) if crc & 0x8000 else (crc << 1)
-            crc &= 0xFFFF
-    return crc
+    # CRC-16/XMODEM (polynomial 0x1021, initial value 0) is binascii's
+    # crc_hqx; every account row key that misses the memo pays this
+    return binascii.crc_hqx(data, 0)
 
 
 def encode(version: int, payload: bytes) -> str:

@@ -457,7 +457,8 @@ class ApplyCheckpointWork(BasicWork):
                 ok = self._load()
             if not ok:
                 return FAILURE
-            self._prewarm()
+            with self.app.ledger_manager.apply_stats.reading("prepare"):
+                self._prewarm()
             self._loaded = True
 
         lm = self.app.ledger_manager
@@ -479,7 +480,8 @@ class ApplyCheckpointWork(BasicWork):
             ts = self._txsets.get(seq)
             txset = (TxSetFrame.from_wire(net, ts) if ts is not None else
                      TxSetFrame(net, entry.header.previousLedgerHash, []))
-        self._prewarm_ledger(txset)
+        with lm.apply_stats.reading("prepare"):
+            self._prewarm_ledger(txset)
         self._await_drain(seq)
         lcd = LedgerCloseData(seq, txset, entry.header.scpValue)
         from ..util.tracing import app_span

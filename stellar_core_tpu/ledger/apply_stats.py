@@ -34,6 +34,7 @@ Cumulative per-op counts and seconds cover both paths identically.
 
 from __future__ import annotations
 
+from contextlib import contextmanager
 from typing import Dict, Optional
 
 from ..util.metrics import MetricsRegistry
@@ -141,8 +142,18 @@ def txset_prefetch_keys(frames) -> list:
     return keys
 
 
+# where a cold root read happens: "prepare" is a catchup's work ahead
+# of the closes (the signer collection of `catchup.sig_prep`, the
+# checkpoint-wide prefetch), "prefetch" the root's bulk-warm inside
+# `close.prefetch`, "apply" any other read (the engine's callback
+# included)
+READ_PHASES = ("prepare", "prefetch", "apply")
+
+
 class ApplyStats:
     """Close-cockpit aggregation; see module docstring."""
+
+    read_phase = "apply"
 
     def __init__(self, metrics=None, tracer=None, now_fn=None) -> None:
         self._now = now_fn or real_monotonic
@@ -173,6 +184,15 @@ class ApplyStats:
         # visible meter, not a mystery miss rate
         self._m_bucket_read = m.new_meter("ledger.apply.state.bucket-read")
         self._m_evict = m.new_meter("ledger.apply.entry-cache.evicted")
+        # cold root reads (a point read or a prefetch load that missed
+        # the entry cache) by where they happen and by what served them
+        self._m_cold: Dict[tuple, object] = {}
+        for phase in READ_PHASES:
+            self._m_cold[phase, None] = m.new_meter(
+                "ledger.root.cold-read.%s" % phase)
+            for source in ("bucket", "sql"):
+                self._m_cold[phase, source] = m.new_meter(
+                    "ledger.root.cold-read.%s.%s" % (phase, source))
         self._m_feebump = m.new_meter("ledger.apply.tx.fee-bump")
         self._m_muxed = m.new_meter("ledger.apply.tx.muxed")
         self._h_merge = m.new_histogram("bucket.merge.seconds")
@@ -225,6 +245,8 @@ class ApplyStats:
                 "bulk_scans": 0, "bulk_scan_rows": 0,
                 "prefetch": {"calls": 0, "requested": 0, "cached": 0,
                              "hits": 0, "misses": 0},
+                "cold": {phase: {"bucket": 0, "sql": 0}
+                         for phase in READ_PHASES},
             }
             self.buckets = {"levels": {}, "merges": 0, "merge_seconds": 0.0}
             self.book = {"loads": 0, "rows": 0,
@@ -497,7 +519,11 @@ class ApplyStats:
                 self._lookup_meter(entry_type).mark()
             elif source == "bucket":
                 self._m_bucket_read.mark()
+            phase = self.read_phase
+            self._m_cold[phase, None].mark()
+            self._m_cold[phase, source].mark()
             with self._lock:
+                self.reads["cold"][phase][source] += 1
                 self.reads["cache_misses"] += 1
                 self.reads["prefetch"]["misses"] += 1
                 if source == "bucket":
@@ -536,7 +562,18 @@ class ApplyStats:
                 self._lookup_meter(entry_type).mark(n)
         if bucket_loads:
             self._m_bucket_read.mark(bucket_loads)
+        # a bulk-warm ahead of the closes is the prepare's, one inside a
+        # close the prefetch's
+        phase = "prepare" if self.read_phase == "prepare" else "prefetch"
+        sql_loads = sum(lookups.values()) if lookups else 0
+        for source, n in (("bucket", bucket_loads), ("sql", sql_loads)):
+            if n:
+                self._m_cold[phase, None].mark(n)
+                self._m_cold[phase, source].mark(n)
         with self._lock:
+            cold = self.reads["cold"][phase]
+            cold["bucket"] += bucket_loads
+            cold["sql"] += sql_loads
             p = self.reads["prefetch"]
             p["calls"] += 1
             p["requested"] += requested
@@ -546,6 +583,16 @@ class ApplyStats:
                 lk = self.reads["lookups"]
                 for entry_type, n in lookups.items():
                     lk[entry_type] = lk.get(entry_type, 0) + n
+
+    @contextmanager
+    def reading(self, phase: str):
+        """Cold root reads inside the block count under `phase` (one of
+        READ_PHASES); the main thread's, as every root read is."""
+        prev, self.read_phase = self.read_phase, phase
+        try:
+            yield
+        finally:
+            self.read_phase = prev
 
     def prefetch_totals(self) -> dict:
         """Cumulative prefetch aggregates (calls/requested/cached/
@@ -615,6 +662,8 @@ class ApplyStats:
                     "bulk_scans": self.reads["bulk_scans"],
                     "bulk_scan_rows": self.reads["bulk_scan_rows"],
                     "prefetch": dict(self.reads["prefetch"]),
+                    "cold_reads": {phase: dict(d) for phase, d in
+                                   self.reads["cold"].items()},
                 },
                 "prefetch_hit_rate": round(self._hit_rate_locked(), 4),
                 "buckets": {

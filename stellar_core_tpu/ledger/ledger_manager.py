@@ -16,6 +16,7 @@ become cache hits.
 from __future__ import annotations
 
 import base64
+import os
 from typing import List, Optional
 
 from ..crypto.hashing import SHA256, sha256
@@ -361,6 +362,10 @@ class LedgerManager:
             with app_span(self.app, "close.prefetch", cat="ledger") as psp:
                 psp.set_tag("cached",
                             self.root.prefetch(txset_prefetch_keys(frames)))
+                if psp.live:
+                    last = self.root.last_prefetch
+                    psp.set_tag("cold", last["cold"])
+                    psp.set_tag("over_budget", last["over_budget"])
 
         bucket_backed = getattr(self.root, "bucket_backed",
                                 lambda: False)()
@@ -665,11 +670,20 @@ class LedgerManager:
         from ..history.archive_state import (
             HistoryArchiveState, has_level_dicts,
         )
+        from ..util.tracing import app_span
         try:
             has = HistoryArchiveState.from_json(s)
             header = self.lcl_header
-            bm.assume_state(has_level_dicts(has),
-                            header.ledgerSeq, header.ledgerVersion)
+            with app_span(self.app, "bucket.assume_state",
+                          cat="bucket") as sp:
+                bm.assume_state(has_level_dicts(has),
+                                header.ledgerSeq, header.ledgerVersion)
+                if sp.live:
+                    paths = [b.path for lev in bm.bucket_list.levels
+                             for b in (lev.curr, lev.snap) if b.path]
+                    sp.set_tag("buckets", len(paths))
+                    sp.set_tag("bytes", sum(os.path.getsize(p)
+                                            for p in paths))
             # the adopted list must hash to what the LCL header committed
             # to — a stale HAS (e.g. written before a bucket-apply catchup
             # fast-forwarded the LCL) silently forks the chain otherwise.

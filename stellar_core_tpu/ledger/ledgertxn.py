@@ -646,6 +646,9 @@ class LedgerTxnRoot(AbstractLedgerTxnParent):
         # bounded-set half-cache budget degraded to silent coverage loss
         # exactly when hot state outgrew it).
         self._prefetched: "OrderedDict[bytes, bool]" = OrderedDict()
+        # the last prefetch() pass: keys it found cold, and of those
+        # the ones the half-cache budget left unloaded
+        self.last_prefetch = {"cold": 0, "over_budget": 0}
 
     def set_header(self, header: LedgerHeader) -> None:
         self._header = header
@@ -854,6 +857,7 @@ class LedgerTxnRoot(AbstractLedgerTxnParent):
         # exactly like the old per-key walk)
         room = max(0, budget - len(self._cache))
         cold: List[Tuple[LedgerKey, bytes]] = []
+        over_budget = 0
         for key in keys:
             requested += 1
             kb = _kb(key)
@@ -863,8 +867,11 @@ class LedgerTxnRoot(AbstractLedgerTxnParent):
                     self._note_prefetched(kb)
                 continue
             if len(cold) >= room:
+                over_budget += 1
                 continue   # over budget: keep counting coverage only
             cold.append((key, kb))
+        self.last_prefetch = {"cold": len(cold) + over_budget,
+                              "over_budget": over_budget}
         # pass 2: resolve every cold key — one batched BucketDB pass per
         # level when attached, per-key SQL otherwise (or on degrade)
         resolved: Dict[bytes, Optional[bytes]] = {}

@@ -240,12 +240,12 @@ class StateCommitmentEngine:
         drain: whole entry-blocks per dispatch through the app's
         BatchHasher (`site="bucket-entries"`), hashlib when no hasher
         is wired."""
-        # entry_record is the memoized framed record the bucket's own
-        # hash serialized; [4:] strips the RFC 5531 mark back to the
-        # XDR body, so leaf hashing never re-serializes an entry
-        from ..bucket.bucket import entry_record
-        msgs = [ENTRY_LEAF_PREFIX + entry_record(e)[4:]
-                for e in bucket.entries]
+        # the XDR bodies as they sit on disk: a resident bucket's
+        # memoized records less their RFC 5531 marks (leaf hashing never
+        # re-serializes an entry), a file-backed bucket's read off its
+        # file (a deep level adopted at a restart is hashed, not parsed)
+        msgs = [ENTRY_LEAF_PREFIX + body
+                for body in bucket.record_bodies()]
         hasher = getattr(self.app, "batch_hasher", None)
         if hasher is not None and msgs:
             return hasher.hash_many(msgs, site="bucket-entries")

@@ -360,7 +360,15 @@ class Application:
                 getattr(self.batch_hasher, "wants_warmup", False):
             self.batch_hasher.warmup(wait=False)
         lm = self.ledger_manager
-        if not lm.load_last_known_ledger():
+        from ..util.tracing import app_span
+        with app_span(self, "node.restore", cat="ledger") as sp:
+            restored = lm.load_last_known_ledger()
+            if sp.live:
+                sp.set_tag("lcl", lm.last_closed_ledger_num()
+                           if restored else 0)
+                sp.set_tag("bucket_backed", bool(
+                    getattr(lm.root, "bucket_backed", bool)()))
+        if not restored:
             lm.start_new_ledger()
         self.herder.restore_scp_state()
         if self.overlay_manager is not None and \
