@@ -33,6 +33,13 @@ _DEAD = BucketEntryType.DEADENTRY
 _INIT = BucketEntryType.INITENTRY
 
 
+def root_sidecar_path(bucket_path: str) -> str:
+    """Where the state commitment keeps a bucket's entry root
+    (ledger/state_commitment.py), beside the file as the BucketDB index
+    is (`bucket_index.sidecar_path`)."""
+    return bucket_path + ".root"
+
+
 def bucket_entry_sort_key(e: BucketEntry):
     """Reference BucketEntryIdCmp (src/bucket/LedgerCmp.h:90-140):
     METAENTRY below everything, others ordered by ledger-entry identity

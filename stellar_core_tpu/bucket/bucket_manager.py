@@ -17,7 +17,7 @@ from typing import Dict, List, Optional, Sequence
 from ..util.log import get_logger
 from ..util.threads import main_thread_only
 from ..xdr import LedgerEntry, LedgerKey
-from .bucket import Bucket
+from .bucket import Bucket, root_sidecar_path
 from .bucket_list import BucketList, K_NUM_LEVELS
 
 log = get_logger("Bucket")
@@ -196,9 +196,17 @@ class BucketManager:
         # satellite): a GC'd bucket's in-memory index, cached fd and
         # persisted sidecar all go with it — a stale sidecar left behind
         # would be adopted verbatim if the same content hash ever
-        # returns, which is exactly why it must match the file's fate
+        # returns, which is exactly why it must match the file's fate.
+        # The commitment's root sidecar goes too: a stale one beside a
+        # returning hash would be right (content-addressed), but the
+        # directory must not grow
         for h, path in victims:
             self.bucketdb.invalidate(h, path)
+            if path:
+                try:
+                    os.remove(root_sidecar_path(path))
+                except OSError:
+                    pass
         return dropped
 
     # -- state restore (catchup / restart) -----------------------------------

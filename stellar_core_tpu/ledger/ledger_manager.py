@@ -546,6 +546,9 @@ class LedgerManager:
                                   self.lcl_hash)
                 if sce.root is not None and msp.live:
                     msp.set_tag("root", sce.root.hex()[:16])
+                    msp.set_tag("roots_loaded", sce.roots_loaded)
+                    msp.set_tag("roots_hashed", sce.roots_hashed)
+                    msp.set_tag("entries_hashed", sce.entries_hashed)
                 if cp is not None:
                     msp.set_tag("checkpoint_seq", cp.ledger_seq)
         with app_span(self.app, "close.sql_commit", cat="ledger"):

@@ -23,6 +23,16 @@ adds the proof-carrying half:
   SHA-256s. A from-scratch oracle (`from_scratch_root`) ignores every
   cache; the differential tests pin incremental == oracle across
   randomized churn and whole replays.
+- **Root sidecars.** The cache has an on-disk form beside the bucket
+  file, as the BucketDB index has: `bucket-<hex>.xdr.root` holds the
+  entry root of the bucket it is named for, so that a restarted node's
+  first close reads 32 bytes where it would hash the bucket again (a
+  deep level: a million entries). It is this node's own result over
+  the same immutable bytes, bound to them by the bucket hash that the
+  restart has just checked the file against; one that is truncated,
+  fails its checksum, names another bucket or another tree definition
+  is refused, the root hashed as before and the sidecar written anew —
+  a corrupt sidecar can degrade startup time, never correctness.
 - **Checkpoints.** Every `STATE_CHECKPOINT_INTERVAL` closes the engine
   emits a `StateCheckpoint` {ledger seq, header hash, Merkle root, node
   signature over the network-id-bound payload}, kept in a bounded ring
@@ -46,9 +56,13 @@ digests.
 
 from __future__ import annotations
 
+import hashlib
+import os
+import struct
 from collections import OrderedDict
 from typing import Dict, List, Optional, Tuple
 
+from ..bucket.bucket import root_sidecar_path
 from ..crypto.hashing import sha256
 from ..util.log import get_logger
 from ..util.timer import real_monotonic
@@ -68,6 +82,61 @@ BUCKET_LEAF_PREFIX = b"\x02"
 _SIGN_DOMAIN = b"sct-state-checkpoint-v1"
 
 ZERO_HASH = b"\x00" * 32
+
+# root sidecar: MAGIC | tree definition | bucket hash | entry count |
+# entry root | SHA256(everything before). The tree definition is what an
+# entry root is a function of besides the bucket's bytes: the three
+# prefixes and the lonely-edge rule (0 = promoted unchanged). A change
+# to either changes these bytes, and every older sidecar is refused.
+_ROOT_MAGIC = b"SCTROOT1"
+_TREE_DEFINITION = (ENTRY_LEAF_PREFIX + NODE_PREFIX + BUCKET_LEAF_PREFIX +
+                    b"\x00")
+_ROOT_BODY = struct.Struct("<8s4s32sQ32s")
+
+# the shallowest level whose buckets' roots are persisted. Level 0's two
+# slots are replaced within two closes and hold one or two closes'
+# entries (a millisecond to hash again at a restart); a sidecar each
+# would add an open, a write and a rename (~0.2 ms where a system call
+# costs 42 us) twice a close to every node with buckets on disk. From
+# level 1 down a new bucket enters ~0.7 times a close.
+PERSIST_FROM_LEVEL = 1
+
+
+class RootSidecarError(Exception):
+    """A root sidecar that is there and not to be trusted."""
+
+
+def root_sidecar_bytes(bucket_hash: bytes, count: int, root: bytes) -> bytes:
+    body = _ROOT_BODY.pack(_ROOT_MAGIC, _TREE_DEFINITION, bucket_hash,
+                           count, root)
+    return body + hashlib.sha256(body).digest()
+
+
+def load_root_sidecar(path: str, bucket_hash: bytes
+                      ) -> Optional[Tuple[bytes, int]]:
+    """(entry root, entry count) from the sidecar at `path`; None where
+    there is none; RootSidecarError where it is not this bucket's root
+    under this tree definition, whole."""
+    size = _ROOT_BODY.size + 32
+    try:
+        with open(path, "rb") as fh:
+            raw = fh.read(size + 1)
+    except FileNotFoundError:
+        return None
+    except OSError as e:
+        raise RootSidecarError("unreadable: %s" % e)
+    if len(raw) != size:
+        raise RootSidecarError("%d bytes, not %d" % (len(raw), size))
+    body, csum = raw[:-32], raw[-32:]
+    if hashlib.sha256(body).digest() != csum:
+        raise RootSidecarError("checksum mismatch")
+    magic, tree, named, count, root = _ROOT_BODY.unpack(body)
+    if (magic, tree) != (_ROOT_MAGIC, _TREE_DEFINITION):
+        raise RootSidecarError("another version or tree definition")
+    if named != bucket_hash:
+        raise RootSidecarError("the root of bucket %s, expected %s"
+                               % (named[:4].hex(), bucket_hash[:4].hex()))
+    return root, count
 
 
 def _node(left: bytes, right: bytes) -> bytes:
@@ -209,11 +278,15 @@ class StateCommitmentEngine:
     def __init__(self, app) -> None:
         self.app = app
         self.metrics = getattr(app, "metrics", None)
-        # bucket-hash -> entry Merkle root; buckets are immutable, so
-        # the cache is sound by construction. Bounded: stale entries
-        # (buckets GC'd by forgetUnreferencedBuckets) age out once the
-        # map exceeds twice the live slot count.
-        self._entry_roots: "OrderedDict[bytes, bytes]" = OrderedDict()
+        # bucket-hash -> (entry Merkle root, entry count); buckets are
+        # immutable, so the cache is sound by construction. Bounded:
+        # stale entries (buckets GC'd by forgetUnreferencedBuckets) age
+        # out once the map exceeds twice the live slot count.
+        self._entry_roots: "OrderedDict[bytes, Tuple[bytes, int]]" = \
+            OrderedDict()
+        # what the last update_root read and hashed, for the
+        # close.commitment span
+        self.roots_loaded = self.roots_hashed = self.entries_hashed = 0
         # leaf slot -> (bucket_hash, leaf_hash): the incremental state
         self._leaves: List[Optional[Tuple[bytes, bytes]]] = []
         self._root: Optional[bytes] = None
@@ -231,8 +304,15 @@ class StateCommitmentEngine:
             m = self.metrics
             self._h_changed = m.new_histogram("commitment.leaves-changed")
             self._h_update = m.new_histogram("commitment.update-ms")
+            self._m_loaded = m.new_meter("commitment.entry-root.loaded")
+            self._m_hashed = m.new_meter("commitment.entry-root.hashed")
+            self._m_persisted = m.new_meter(
+                "commitment.entry-root.persisted")
+            self._m_rejected = m.new_meter("commitment.entry-root.rejected")
         else:
             self._h_changed = self._h_update = None
+            self._m_loaded = self._m_hashed = None
+            self._m_persisted = self._m_rejected = None
 
     # -- entry roots ---------------------------------------------------------
     def _entry_leaves(self, bucket) -> List[bytes]:
@@ -251,20 +331,69 @@ class StateCommitmentEngine:
             return hasher.hash_many(msgs, site="bucket-entries")
         return [sha256(m) for m in msgs]
 
-    def entry_root(self, bucket) -> bytes:
+    def entry_root(self, bucket, persist: bool = False) -> bytes:
         """Merkle root over a bucket's entry leaves, cached by the
-        bucket's identity hash (immutable content)."""
+        bucket's identity hash (immutable content): in memory, and for
+        a bucket with a file in its root sidecar, which is read on any
+        miss and written where `persist` says the bucket will stay."""
         bh = bucket.get_hash()
         got = self._entry_roots.get(bh)
         if got is not None:
             self._entry_roots.move_to_end(bh)
-            return got
-        root = merkle_root(self._entry_leaves(bucket))
-        self._entry_roots[bh] = root
-        limit = max(64, 4 * max(1, len(self._leaves)))
-        while len(self._entry_roots) > limit:
-            self._entry_roots.popitem(last=False)
-        return root
+        else:
+            got = self._load_root(bucket)
+            if got is None:
+                leaves = self._entry_leaves(bucket)
+                got = merkle_root(leaves), len(leaves)
+                self.roots_hashed += 1
+                self.entries_hashed += len(leaves)
+                if self._m_hashed is not None:
+                    self._m_hashed.mark()
+            self._entry_roots[bh] = got
+            limit = max(64, 4 * max(1, len(self._leaves)))
+            while len(self._entry_roots) > limit:
+                self._entry_roots.popitem(last=False)
+        # the file is asked, not a record of what this engine wrote: a
+        # sidecar goes with its bucket (forget_unreferenced_buckets) and
+        # the same content may come back
+        if (persist and bucket.path and
+                not os.path.exists(root_sidecar_path(bucket.path))):
+            self._save_root(bucket, *got)
+        return got[0]
+
+    def _load_root(self, bucket) -> Optional[Tuple[bytes, int]]:
+        if not bucket.path:
+            return None
+        side = root_sidecar_path(bucket.path)
+        try:
+            got = load_root_sidecar(side, bucket.get_hash())
+        except RootSidecarError as e:
+            log.warning("root sidecar %s refused (%s): hashing the bucket",
+                        side, e)
+            if self._m_rejected is not None:
+                self._m_rejected.mark()
+            try:
+                os.remove(side)
+            except OSError:
+                pass
+            return None
+        if got is not None:
+            self.roots_loaded += 1
+            if self._m_loaded is not None:
+                self._m_loaded.mark()
+        return got
+
+    def _save_root(self, bucket, root: bytes, count: int) -> None:
+        side = root_sidecar_path(bucket.path)
+        try:
+            with open(side + ".tmp", "wb") as fh:
+                fh.write(root_sidecar_bytes(bucket.get_hash(), count, root))
+            os.replace(side + ".tmp", side)
+        except OSError as e:
+            log.warning("could not persist entry root %s: %s", side, e)
+            return
+        if self._m_persisted is not None:
+            self._m_persisted.mark()
 
     @staticmethod
     def _slots(bucket_list) -> List:
@@ -276,11 +405,13 @@ class StateCommitmentEngine:
             out.append(lev.snap)
         return out
 
-    def _leaf_hash(self, bucket) -> Tuple[bytes, bytes]:
+    def _leaf_hash(self, bucket, persist: bool = False
+                   ) -> Tuple[bytes, bytes]:
         bh = bucket.get_hash()
         if bh == ZERO_HASH:
             return bh, sha256(BUCKET_LEAF_PREFIX + bh + ZERO_HASH)
-        return bh, sha256(BUCKET_LEAF_PREFIX + bh + self.entry_root(bucket))
+        return bh, sha256(BUCKET_LEAF_PREFIX + bh +
+                          self.entry_root(bucket, persist))
 
     # -- the incremental update ---------------------------------------------
     def update_root(self, bucket_list) -> bytes:
@@ -293,12 +424,15 @@ class StateCommitmentEngine:
         if len(self._leaves) != len(slots):
             self._leaves = [None] * len(slots)
         changed = 0
+        self.roots_loaded = self.roots_hashed = self.entries_hashed = 0
         for i, b in enumerate(slots):
             bh = b.get_hash()
             cached = self._leaves[i]
             if cached is not None and cached[0] == bh:
                 continue
-            self._leaves[i] = self._leaf_hash(b)
+            # slot i is curr or snap of level i // 2
+            self._leaves[i] = self._leaf_hash(
+                b, persist=i // 2 >= PERSIST_FROM_LEVEL)
             changed += 1
         self._root = merkle_root([lf[1] for lf in self._leaves])
         if self._h_changed is not None:
@@ -308,7 +442,8 @@ class StateCommitmentEngine:
 
     def from_scratch_root(self, bucket_list) -> bytes:
         """The differential oracle: the same root computed with every
-        cache bypassed (entry leaves re-hashed via plain hashlib)."""
+        cache bypassed, the root sidecars too (entry leaves re-hashed
+        via plain hashlib)."""
         leaves = []
         for b in self._slots(bucket_list):
             bh = b.get_hash()

@@ -5,6 +5,7 @@ tamper rejection, checkpoint cadence + the sign-fail fault, and the
 admin `checkpoint` endpoint."""
 
 import json
+import os
 from types import SimpleNamespace
 
 import pytest
@@ -235,3 +236,303 @@ def test_checkpoint_admin_endpoint(closing_app):
     p = checkpoint_sign_payload(b"n" * 32, 7, b"h" * 32, b"r" * 32)
     assert p != checkpoint_sign_payload(b"n" * 32, 8, b"h" * 32,
                                         b"r" * 32)
+
+
+# --- root sidecars: the entry-root cache on disk (ISSUE 34) -----------------
+
+CLOSES = 20         # level 0 spills every 2 ledgers, level 1 at 8 and 16
+
+
+def _disk_node(node_dir):
+    """A node whose database and buckets are files under `node_dir`;
+    a second call over the same directory is a restart."""
+    from stellar_core_tpu.crypto.keys import SecretKey
+    from stellar_core_tpu.main.application import Application
+    from stellar_core_tpu.main.config import Config
+    from stellar_core_tpu.util.timer import ClockMode, VirtualClock
+    os.makedirs(node_dir, exist_ok=True)
+    cfg = Config.test_config(93)
+    cfg.NODE_SEED = SecretKey.from_seed(sha256(b"root-sidecar-node"))
+    cfg.QUORUM_SET = cfg.self_qset()
+    cfg.DATABASE = "sqlite3://%s" % (node_dir / "node.db")
+    cfg.STATE_CHECKPOINT_INTERVAL = 2
+    app = Application(VirtualClock(ClockMode.VIRTUAL_TIME), cfg)
+    app.enable_buckets(str(node_dir / "buckets"))
+    app.tracer.enable()
+    app.start()
+    return app
+
+
+def _close_at(app, when, frames=()):
+    for f in frames:
+        assert app.submit_transaction(f) == 0
+    app.clock.set_virtual_time(when)
+    app.manual_close()
+
+
+@pytest.fixture(scope="module")
+def first_node(tmp_path_factory):
+    """A node that has closed past level 1's second spill, a copy of its
+    directory as it stood then, and what it closed next."""
+    import shutil
+    from stellar_core_tpu.testing import AppLedgerAdapter
+    home = tmp_path_factory.mktemp("roots")
+    app = _disk_node(home / "first")
+    root = AppLedgerAdapter(app).root_account()
+    bob = root.create(10 ** 9)          # never touched again: sinks
+    alice = root.create(10 ** 10)
+    t = app.clock.now() + 5
+    while app.ledger_manager.last_closed_ledger_num() < CLOSES:
+        t += 1.0
+        _close_at(app, t, [alice.tx([alice.op_payment(root.account_id, 7)])])
+    shutil.copytree(home / "first", home / "snapshot",
+                    ignore=shutil.ignore_patterns("*.tmp"))
+    nxt = alice.tx([alice.op_payment(root.account_id, 9)])
+    _close_at(app, t + 1.0, [nxt])
+    out = SimpleNamespace(
+        snapshot=home / "snapshot", next_frame=nxt, next_time=t + 1.0,
+        lcl=CLOSES + 1, lcl_hash=app.ledger_manager.lcl_hash,
+        root=app.state_commitment.root, bob=bob.account_id)
+    app.stop()
+    return out
+
+
+def _restart(first_node, tmp_path):
+    import shutil
+    shutil.copytree(first_node.snapshot, tmp_path / "node")
+    return _disk_node(tmp_path / "node")
+
+
+def _slot_buckets(app):
+    """[(level, bucket)] of the live list's non-empty slots."""
+    return [(lev.level, b)
+            for lev in app.bucket_manager.bucket_list.levels
+            for b in (lev.curr, lev.snap) if b.get_hash() != b"\x00" * 32]
+
+
+def _root_files(app):
+    d = app.bucket_manager.bucket_dir
+    return {n for n in os.listdir(d) if n.endswith(".root")}
+
+
+def _meter(app, name):
+    return app.metrics.to_json().get(name, {}).get("count", 0)
+
+
+def _commitment_spans(app):
+    return [s for s in app.tracer.spans()
+            if s.name == "close.commitment" and s.dur is not None]
+
+
+def test_a_restarted_nodes_first_close_reads_its_roots(first_node,
+                                                       tmp_path):
+    """(a) and (d): the first close after a restart hashes only the
+    buckets that have no sidecar, the two of level 0 and what this close
+    merged; the root is the oracle's and the one the node that never
+    stopped had at that ledger."""
+    app = _restart(first_node, tmp_path)
+    try:
+        assert app.ledger_manager.last_closed_ledger_num() == CLOSES
+        assert not _commitment_spans(app)
+        had = _root_files(app)
+        deep = {b.get_hash() for lvl, b in _slot_buckets(app) if lvl >= 1}
+        assert deep and {"bucket-%s.xdr.root" % h.hex() for h in deep} <= had
+        _close_at(app, first_node.next_time, [first_node.next_frame])
+        sce = app.state_commitment
+        bl = app.bucket_manager.bucket_list
+        assert app.ledger_manager.lcl_hash == first_node.lcl_hash
+        assert sce.root == first_node.root == sce.from_scratch_root(bl)
+        by_hash = {b.get_hash(): (lvl, b) for lvl, b in _slot_buckets(app)}
+        loaded = [h for h in by_hash if os.path.basename(
+            by_hash[h][1].path) + ".root" in had]
+        rest = [h for h in by_hash if h not in loaded]
+        span, = _commitment_spans(app)
+        assert span.tags["roots_loaded"] == len(loaded) >= 2
+        assert span.tags["roots_hashed"] == len(rest)
+        assert span.tags["entries_hashed"] == \
+            sum(len(by_hash[h][1]) for h in rest)
+        assert span.tags["entries_hashed"] < \
+            sum(len(b) for _l, b in by_hash.values())
+        assert _meter(app, "commitment.entry-root.loaded") == len(loaded)
+        assert _meter(app, "commitment.entry-root.hashed") == len(rest)
+        assert _meter(app, "commitment.entry-root.rejected") == 0
+        # the rule: a root is written once its bucket sits below level 0
+        now = _root_files(app)
+        for h, (lvl, b) in by_hash.items():
+            name = os.path.basename(b.path) + ".root"
+            if lvl >= 1:
+                assert name in now
+            elif name not in had:
+                assert name not in now, "a level-0 bucket got a sidecar"
+        assert _meter(app, "commitment.entry-root.persisted") == \
+            len(now - had)
+        # the next close finds every unchanged slot in memory
+        _close_at(app, first_node.next_time + 1.0)
+        assert _commitment_spans(app)[-1].tags["roots_loaded"] == 0
+        assert sce.root == sce.from_scratch_root(bl)
+    finally:
+        app.stop()
+
+
+def _truncate(raw, _other):
+    return raw[:-7]
+
+
+def _flip(raw, _other):
+    return raw[:50] + bytes([raw[50] ^ 0x10]) + raw[51:]
+
+
+def _resum(body):
+    return body + sha256(body)
+
+
+def _other_bucket(raw, other):
+    return _resum(raw[:12] + other + raw[44:-32])
+
+
+def _other_version(raw, _other):
+    return _resum(raw[:10] + bytes([raw[10] ^ 0x01]) + raw[11:-32])
+
+
+@pytest.mark.parametrize("damage,rejected", [
+    (_truncate, 1), (_flip, 1), (_other_bucket, 1), (_other_version, 1),
+    (None, 0)], ids=["truncated", "flipped-byte", "another-bucket",
+                     "another-version", "absent"])
+def test_a_sidecar_that_cannot_be_trusted_is_rebuilt(first_node, tmp_path,
+                                                     damage, rejected):
+    """(b): whatever is wrong with a sidecar, the root is the oracle's
+    and a valid sidecar stands there afterwards."""
+    from stellar_core_tpu.bucket.bucket import root_sidecar_path
+    from stellar_core_tpu.ledger.state_commitment import (
+        RootSidecarError, load_root_sidecar,
+    )
+    app = _restart(first_node, tmp_path)
+    try:
+        lvl, victim = _slot_buckets(app)[-1]       # the deepest: it stays
+        assert lvl >= 2
+        side = root_sidecar_path(victim.path)
+        with open(side, "rb") as fh:
+            raw = fh.read()
+        good = load_root_sidecar(side, victim.get_hash())
+        assert good is not None and good[1] == len(victim)
+        os.unlink(side)
+        if damage is not None:
+            with open(side, "wb") as fh:
+                fh.write(damage(raw, sha256(b"another bucket")))
+            with pytest.raises(RootSidecarError):
+                load_root_sidecar(side, victim.get_hash())
+        else:
+            assert load_root_sidecar(side, victim.get_hash()) is None
+        _close_at(app, first_node.next_time, [first_node.next_frame])
+        sce = app.state_commitment
+        assert victim.get_hash() in {
+            b.get_hash() for _l, b in _slot_buckets(app)}
+        assert sce.root == first_node.root == \
+            sce.from_scratch_root(app.bucket_manager.bucket_list)
+        assert _meter(app, "commitment.entry-root.rejected") == rejected
+        span, = _commitment_spans(app)
+        assert span.tags["entries_hashed"] >= len(victim)
+        assert load_root_sidecar(side, victim.get_hash()) == good
+        with open(side, "rb") as fh:
+            assert fh.read() == raw
+        assert not [n for n in os.listdir(os.path.dirname(side))
+                    if n.endswith(".tmp")]
+    finally:
+        app.stop()
+
+
+def test_forgetting_a_bucket_takes_its_root_with_its_index(first_node,
+                                                           tmp_path):
+    """(c): the directory does not grow by sidecars of buckets that
+    have gone."""
+    app = _restart(first_node, tmp_path)
+    try:
+        t = first_node.next_time
+        _close_at(app, t, [first_node.next_frame])
+        for i in range(1, 9):        # level 1 snaps again: buckets go
+            _close_at(app, t + i)
+        d = app.bucket_manager.bucket_dir
+        before = set(os.listdir(d))
+        dropped = app.bucket_manager.forget_unreferenced_buckets()
+        after = set(os.listdir(d))
+        gone = {n for n in before - after if n.endswith(".xdr")}
+        assert dropped >= len(gone) > 0
+        assert {n for n in before - after if n.endswith(".root")}
+        for n in after:
+            stem = n.split(".xdr")[0] + ".xdr"
+            assert stem in after, "%s outlived its bucket" % n
+        for n in gone:
+            assert n + ".root" not in after and n + ".idx" not in after
+        # what is left is what the list holds, roots and all
+        live = {"bucket-%s.xdr.root" % b.get_hash().hex()
+                for lvl, b in _slot_buckets(app) if lvl >= 1}
+        assert live <= after
+        sce = app.state_commitment
+        _close_at(app, t + 9)
+        assert sce.root == \
+            sce.from_scratch_root(app.bucket_manager.bucket_list)
+    finally:
+        app.stop()
+
+
+def test_a_root_whose_sidecar_has_gone_is_persisted_again(first_node,
+                                                          tmp_path):
+    """A sidecar goes with its bucket; where the same content comes back
+    while the engine still holds its root, the root is written again
+    (from memory: nothing is hashed for it)."""
+    from stellar_core_tpu.bucket.bucket import root_sidecar_path
+    from stellar_core_tpu.ledger.state_commitment import load_root_sidecar
+    app = _restart(first_node, tmp_path)
+    try:
+        _close_at(app, first_node.next_time, [first_node.next_frame])
+        sce = app.state_commitment
+        _lvl, victim = _slot_buckets(app)[-1]
+        side = root_sidecar_path(victim.path)
+        good = load_root_sidecar(side, victim.get_hash())
+        assert good is not None
+        os.unlink(side)
+        hashed = _meter(app, "commitment.entry-root.hashed")
+        assert sce.entry_root(victim, persist=True) == good[0]
+        assert load_root_sidecar(side, victim.get_hash()) == good
+        assert _meter(app, "commitment.entry-root.hashed") == hashed
+        os.unlink(side)
+        assert sce.entry_root(victim) == good[0]     # level 0's rule
+        assert not os.path.exists(side)
+    finally:
+        app.stop()
+
+
+def test_a_proof_served_after_a_restart_verifies(first_node, tmp_path):
+    """(e): the entry proven sits in a bucket whose root was read, not
+    hashed; the light client climbs from the entry to the checkpoint's
+    root through it."""
+    app = _restart(first_node, tmp_path)
+    try:
+        sce = app.state_commitment
+        _close_at(app, first_node.next_time, [first_node.next_frame])
+        _close_at(app, first_node.next_time + 1.0)
+        cp = sce.checkpoint()
+        assert cp is not None and cp["ledger_seq"] > CLOSES
+        proof = sce.prove_entry(X.LedgerKey.account(first_node.bob))
+        assert proof is not None and proof["leaf_index"] >= 2
+        assert _meter(app, "commitment.entry-root.hashed") < \
+            len(_slot_buckets(app)) + 2
+        name = "bucket-%s.xdr.root" % proof["bucket_hash"]
+        assert name in os.listdir(first_node.snapshot / "buckets")
+        ok, reason = light_client_verify(proof, cp, app.config.network_id)
+        assert ok, reason
+    finally:
+        app.stop()
+
+
+def test_a_bucket_without_a_file_has_no_sidecar_and_needs_none():
+    """The engine over a plain bucket list (no directory): nothing is
+    read or written, the counts say what was hashed."""
+    bl = BucketList()
+    eng = _engine()
+    for ledger in range(1, 10):
+        bl.add_batch(ledger, PROTO, [acct(ledger)], [], [])
+        bl.resolve_all_futures()
+        eng.update_root(bl)
+        assert eng.roots_loaded == 0 and eng.roots_hashed >= 1
+    assert eng.root == eng.from_scratch_root(bl)

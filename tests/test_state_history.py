@@ -527,25 +527,68 @@ def test_a_bucket_from_its_file_is_hashed_not_parsed(dep, tmp_path):
     assert Bucket.from_file(path, b"\x01" * 32) is None
 
 
-def test_the_commitment_root_of_a_file_backed_bucket_is_the_residents(dep):
+def test_the_commitment_root_of_a_file_backed_bucket_is_the_residents(
+        dep, tmp_path):
     """The state commitment hashes a bucket's entries as they sit on
-    disk: the same root whether the bucket is resident or not."""
-    from stellar_core_tpu.bucket.bucket import Bucket
+    disk: the same root whether the bucket is resident or not, and the
+    one the publisher left in the bucket's root sidecar."""
+    import shutil
+    from stellar_core_tpu.bucket.bucket import Bucket, root_sidecar_path
     from stellar_core_tpu.ledger.state_commitment import (
-        StateCommitmentEngine,
+        StateCommitmentEngine, load_root_sidecar,
     )
     pub = dep.hist.pub
     level = dep.config["state"]["bucket_level"]
     deep = pub.bucket_manager.bucket_list.levels[level].curr
-    resident = Bucket.read_from(deep.path)
-    assert resident.get_hash() == deep.get_hash() and not deep.resident
+    # a copy with no sidecar beside it: both engines hash
+    path = str(tmp_path / os.path.basename(deep.path))
+    shutil.copyfile(deep.path, path)
+    lazy = Bucket.from_file(path, deep.get_hash())
+    resident = Bucket.read_from(path)
+    assert resident.get_hash() == deep.get_hash() and not lazy.resident
 
     class App:
         metrics = None
     a, b = StateCommitmentEngine(App()), StateCommitmentEngine(App())
-    assert a.entry_root(deep) == b.entry_root(resident)
-    assert not deep.resident
+    assert a.entry_root(lazy) == b.entry_root(resident)
+    for eng in (a, b):
+        assert (eng.roots_loaded, eng.roots_hashed, eng.entries_hashed) == \
+            (0, 1, STATE["accounts"] + 1)
+    assert not lazy.resident and not os.path.exists(root_sidecar_path(path))
+    assert load_root_sidecar(root_sidecar_path(deep.path),
+                             deep.get_hash()) == \
+        (a.entry_root(lazy), STATE["accounts"] + 1)
     assert pub.state_commitment.root is not None
+
+
+def test_a_restart_reads_the_seeded_buckets_root_off_its_disk(dep):
+    """ISSUE 34: the publisher wrote the seeded bucket's entry root
+    beside it at its first close over it, the snapshot carries the
+    sidecar as it carries every `bucket-` file, and a restarted node's
+    first close reads it where it hashed the bucket's entries again."""
+    level = dep.config["state"]["bucket_level"]
+    seeded = dep.hist.pub.bucket_manager.bucket_list.levels[level].curr
+    assert "bucket-%s.xdr.root" % seeded.get_hash().hex() in os.listdir(
+        os.path.join(dep.hist.snapshot_dir, "buckets"))
+    seen = []
+    for _ in range(2):
+        app, got = replay(dep, trace=True)
+        assert got["header_mismatches"] == got["state_mismatches"] == 0
+        spans = [s for s in app.tracer.spans()
+                 if s.name == "close.commitment" and s.dur is not None]
+        assert len(spans) == FREQ
+        first = spans[0].tags
+        assert first["seq"] == FREQ and first["roots_loaded"] >= 1
+        assert first["entries_hashed"] < STATE["accounts"]
+        assert all(s.tags["roots_loaded"] == 0 for s in spans[1:])
+        sce = app.state_commitment
+        assert sce.root == dep.hist.pub.state_commitment.root
+        assert count(app, "commitment.entry-root.rejected") == 0
+        seen.append([(s.tags["roots_loaded"], s.tags["roots_hashed"],
+                      s.tags["entries_hashed"]) for s in spans] +
+                    [count(app, "commitment.entry-root." + m)
+                     for m in ("loaded", "hashed", "persisted")])
+    assert seen[0] == seen[1]
 
 
 def test_strkey_checksum_is_crc16_xmodem():
