@@ -15,12 +15,14 @@ batches are for signature verification only.
 
 from __future__ import annotations
 
+import time
 from concurrent.futures import Executor, Future
 from functools import lru_cache
 from typing import Callable, List, Optional, Sequence
 
 from ..crypto.hashing import SHA256
 from ..util.log import get_logger
+from ..util.tracing import tracer_span
 from ..xdr import LedgerEntry, LedgerKey
 from .bucket import Bucket, merge_buckets
 
@@ -152,29 +154,47 @@ class FutureBucket:
         self.input_curr_hash: Optional[bytes] = None
         self.input_snap_hash: Optional[bytes] = None
         self.input_shadow_hashes: List[bytes] = []
+        self._tracer = None     # util/tracing.py: the wait's span
+        self._level = -1
 
     @classmethod
     def start(cls, executor: Optional[Executor], curr: Bucket, snap: Bucket,
               shadows: Sequence[Bucket], keep_dead: bool,
               max_protocol_version: int,
               adopt: Callable[[Bucket], Bucket],
-              on_done: Optional[Callable[[float, int], None]] = None
-              ) -> "FutureBucket":
+              on_done: Optional[Callable[[float, int], None]] = None,
+              tracer=None, level: int = -1) -> "FutureBucket":
         """`on_done(seconds, out_entries)` fires when the merge finishes
         (on the worker thread when an executor runs it) — the close
-        cockpit's bucket-merge duration telemetry."""
+        cockpit's bucket-merge duration telemetry. With `tracer`, the
+        same interval is a `bucket.merge` span on the thread that
+        merges, whose `cause` is the span open here, on the thread that
+        kicks it (the `close.bucket_add` of the close)."""
         fb = cls()
         fb._state = FutureBucket.FB_MERGING
         fb.input_curr_hash = curr.get_hash()
         fb.input_snap_hash = snap.get_hash()
         fb.input_shadow_hashes = [s.get_hash() for s in shadows]
+        fb._tracer, fb._level = tracer, level
+        cause = tracer.current_sid() if tracer is not None else 0
 
         def run() -> Bucket:
             from ..util.timer import real_monotonic
             t0 = real_monotonic()
-            out = adopt(merge_buckets(
-                curr, snap, shadows, keep_dead_entries=keep_dead,
-                max_protocol_version=max_protocol_version))
+            with tracer_span(tracer, "bucket.merge", cat="bucket",
+                             cause=cause, level=level) as sp:
+                cpu0 = time.thread_time() if sp.live else 0.0
+                out = adopt(merge_buckets(
+                    curr, snap, shadows, keep_dead_entries=keep_dead,
+                    max_protocol_version=max_protocol_version))
+                if sp.live:
+                    # wall less cpu: the worker standing in line for the
+                    # interpreter or the disk
+                    sp.set_tag("cpu_ms", round(
+                        (time.thread_time() - cpu0) * 1e3, 3))
+                    sp.set_tag("in_curr", len(curr))
+                    sp.set_tag("in_snap", len(snap))
+                    sp.set_tag("out", len(out))
             if on_done is not None:
                 on_done(real_monotonic() - t0, len(out))
             return out
@@ -211,8 +231,14 @@ class FutureBucket:
         FutureBucket::resolve)."""
         assert self.is_live()
         if self._state == FutureBucket.FB_MERGING:
-            if self._future is not None:
-                self._result = self._future.result()
+            fut = self._future
+            if fut is not None:
+                if fut.done():
+                    self._result = fut.result()
+                else:
+                    with tracer_span(self._tracer, "bucket.merge_wait",
+                                     cat="bucket", level=self._level):
+                        self._result = fut.result()
                 self._future = None
             self._state = FutureBucket.FB_RESOLVED
         assert self._result is not None
@@ -279,7 +305,8 @@ class BucketLevel:
         (BucketList.cpp:127-166). If this level's own curr is one
         prev-level-spill away from snapping, merge against an empty curr
         instead (the pending-snapshot subtlety). `stats` (ApplyStats)
-        records the merge's duration against this level."""
+        records the merge's duration against this level, and its tracer
+        the merge's span."""
         assert not self.next.is_merging(), "double prepare"
         curr = self.curr
         if self.level != 0:
@@ -299,7 +326,8 @@ class BucketLevel:
             executor, curr, snap, use_shadows,
             keep_dead=keep_dead_entries(self.level),
             max_protocol_version=curr_ledger_protocol, adopt=adopt,
-            on_done=on_done)
+            on_done=on_done, tracer=getattr(stats, "tracer", None),
+            level=self.level)
 
 
 class BucketList:
@@ -309,7 +337,9 @@ class BucketList:
         self.levels = [BucketLevel(i) for i in range(K_NUM_LEVELS)]
         self._executor = executor
         self._adopt = adopt or (lambda b: b)
-        self._stats = stats   # ApplyStats: merge durations per level
+        # ApplyStats: merge durations per level; its tracer is the
+        # list's (as BucketDB's comes with its stats)
+        self._stats = stats
 
     def get_level(self, i: int) -> BucketLevel:
         return self.levels[i]
@@ -365,8 +395,12 @@ class BucketList:
                                        curr_ledger_protocol, snap, shadows,
                                        self._adopt, stats=self._stats)
         assert not shadows
-        fresh = self._adopt(Bucket.fresh(curr_ledger_protocol, init_entries,
-                                         live_entries, dead_entries))
+        with tracer_span(getattr(self._stats, "tracer", None),
+                         "bucket.fresh", cat="bucket") as sp:
+            fresh = Bucket.fresh(curr_ledger_protocol, init_entries,
+                                 live_entries, dead_entries)
+            sp.set_tag("entries", len(fresh))
+        fresh = self._adopt(fresh)
         self.levels[0].prepare(self._executor, curr_ledger,
                                curr_ledger_protocol, fresh, [], self._adopt,
                                stats=self._stats)

@@ -12,10 +12,12 @@ from __future__ import annotations
 import os
 import threading
 from concurrent.futures import ThreadPoolExecutor
+from contextlib import ExitStack
 from typing import Dict, List, Optional, Sequence
 
 from ..util.log import get_logger
 from ..util.threads import main_thread_only
+from ..util.tracing import tracer_span
 from ..xdr import LedgerEntry, LedgerKey
 from .bucket import Bucket, root_sidecar_path
 from .bucket_list import BucketList, K_NUM_LEVELS
@@ -70,6 +72,7 @@ class BucketManager:
         # close cockpit (ledger/apply_stats.py): per-level sizes recorded
         # at every snapshot, merge durations from the worker pool
         self._stats = stats
+        self._tracer = getattr(stats, "tracer", None)
         self.bucket_list = BucketList(self._executor, adopt=self.adopt_bucket,
                                       stats=stats)
         # BucketDB (ISSUE 14): bloom-filtered per-bucket indexes over the
@@ -92,28 +95,39 @@ class BucketManager:
         BucketManagerImpl::adoptFileAsBucket). Adoption also indexes the
         bucket for BucketDB (load the persisted sidecar, else build and
         persist one) — OUTSIDE the store lock, so a large merge output's
-        index build never blocks concurrent bucket lookups."""
+        index build never blocks concurrent bucket lookups. The file
+        write and the indexing are the `bucket.adopt` span; a dedup hit
+        has none."""
         h = b.get_hash()
         if h == ZERO_HASH:
             return b
-        with self._lock:
-            existing = self._shared.get(h)
-            if existing is not None:
-                return existing
-            path = self.bucket_filename(h)
-            if path and not os.path.exists(path):
-                b.write_to(path + ".tmp")
-                os.replace(path + ".tmp", path)
-                b.path = path
-            elif path:
-                # bucket file already on disk (restart / catchup
-                # re-download): serve reads from it
-                b.path = path
-            self._shared[h] = b
-        idx = self.bucketdb.on_adopt(b)
-        if idx is not None:
-            # the index has every entry but the META one
-            b.count_hint(len(idx) + (1 if b.get_version() else 0))
+        with ExitStack() as stack:
+            with self._lock:
+                existing = self._shared.get(h)
+                if existing is not None:
+                    return existing
+                # past the dedup hit: the span opens under the store lock
+                # and closes after the indexing, outside it
+                sp = stack.enter_context(tracer_span(
+                    self._tracer, "bucket.adopt", cat="bucket"))
+                path = self.bucket_filename(h)
+                wrote = bool(path) and not os.path.exists(path)
+                if wrote:
+                    b.write_to(path + ".tmp")
+                    os.replace(path + ".tmp", path)
+                if path:
+                    # also where the bucket file was already on disk
+                    # (restart / catchup re-download): serve reads from it
+                    b.path = path
+                self._shared[h] = b
+            idx = self.bucketdb.on_adopt(b)
+            if idx is not None:
+                # the index has every entry but the META one
+                b.count_hint(len(idx) + (1 if b.get_version() else 0))
+            if sp.live:
+                sp.set_tag("wrote", wrote)
+                sp.set_tag("entries", len(b))
+                sp.set_tag("bytes", os.path.getsize(path) if path else 0)
         return b
 
     def get_bucket_by_hash(self, hash_: bytes) -> Optional[Bucket]:
@@ -264,7 +278,8 @@ class BucketManager:
                     self._executor, mc, ms, sh,
                     keep_dead=keep_dead_entries(i),
                     max_protocol_version=max_protocol_version,
-                    adopt=self.adopt_bucket, on_done=on_done)
+                    adopt=self.adopt_bucket, on_done=on_done,
+                    tracer=self._tracer, level=i)
         self.bucket_list.restart_merges(curr_ledger)
 
     def shutdown(self) -> None:

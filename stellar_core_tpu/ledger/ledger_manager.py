@@ -259,13 +259,17 @@ class LedgerManager:
         verifier = getattr(self.app, "sig_verifier", None)
         metrics = getattr(self.app, "metrics", None)
         from ..util.slow_execution import LogSlowExecution
-        from ..util.tracing import app_span
+        from ..util.tracing import GC_HOOK, app_span
         recorder = getattr(self.app, "flight_recorder", None)
+        # what the collector took from this close: the process's pause
+        # total before and after (a pause stops every thread)
+        gc_before = GC_HOOK.pause_total_s
         on_slow = (None if recorder is None else
                    lambda elapsed: recorder.dump(
                        "slow-close",
                        extra={"ledger_seq": lcd.ledger_seq,
-                              "elapsed_s": elapsed}))
+                              "elapsed_s": elapsed,
+                              "gc_s": GC_HOOK.pause_total_s - gc_before}))
         db = getattr(self.app, "database", None)
         ltx = LedgerTxn(self.root)
         try:
@@ -280,8 +284,21 @@ class LedgerManager:
                 with LogSlowExecution("ledger close", on_slow=on_slow), \
                         app_span(self.app, "ledger.close", cat="ledger",
                                  seq=lcd.ledger_seq,
-                                 txs=len(lcd.tx_set.frames)):
+                                 txs=len(lcd.tx_set.frames)) as sp:
+                    if sp.live:
+                        gc_full = GC_HOOK.collections[2]
+                        cpu0 = _time.thread_time()
                     self._close_ledger_in(ltx, lcd, header_prev, verifier)
+                    if sp.live:
+                        # wall - cpu - crypto.device_wait -
+                        # bucket.merge_wait: this thread off the CPU for
+                        # no reason the program chose
+                        sp.set_tag("cpu_ms", round(
+                            (_time.thread_time() - cpu0) * 1e3, 3))
+                        sp.set_tag("gc_ms", round(
+                            (GC_HOOK.pause_total_s - gc_before) * 1e3, 3))
+                        sp.set_tag("gc_full",
+                                   GC_HOOK.collections[2] - gc_full)
             finally:
                 if metrics is not None:
                     elapsed = _time.perf_counter() - t0
@@ -508,7 +525,8 @@ class LedgerManager:
                         live_entries.append(cur)
                 bl.add_batch(header.ledgerSeq, header.ledgerVersion,
                              init_entries, live_entries, dead_keys)
-                bl.snapshot_ledger(header)
+                with app_span(self.app, "bucket.snapshot", cat="bucket"):
+                    bl.snapshot_ledger(header)
             else:
                 h = SHA256()
                 h.add(header_prev.bucketListHash)

@@ -140,6 +140,10 @@ class CommandHandler:
             out["crypto.verify.batch-dispatch"] = {
                 "count": v.inner.batches_dispatched}
             out["crypto.verify.sigs"] = {"count": v.inner.sigs_verified}
+        # the collector's pauses are the process's, kept by the tracer's
+        # hook: timers built from its totals at the scrape
+        from ..util.tracing import GC_HOOK
+        out.update(GC_HOOK.timers())
         if prefix:
             out = {k: v2 for k, v2 in out.items() if k.startswith(prefix)}
         if params.get("format") == "prometheus":

@@ -39,12 +39,14 @@ from typing import Callable, Dict, Optional
 from .metrics import MetricsRegistry
 from .threads import TrackedLock
 from .timer import real_monotonic
+from .tracing import GC_HOOK
 
 
 def process_stats() -> dict:
     """Process-level footprint from /proc (Linux; ru_maxrss fallback):
     resident set in MB, live thread count, open fd count (-1 when
-    /proc/self/fd is unreadable)."""
+    /proc/self/fd is unreadable), and `gc`: the collector's process
+    totals by generation (util/tracing.py::GcHook)."""
     rss_kb = 0
     try:
         with open("/proc/self/status", encoding="ascii") as fh:
@@ -66,7 +68,8 @@ def process_stats() -> dict:
         fds = -1
     return {"rss_mb": round(rss_kb / 1024.0, 3),
             "threads": threading.active_count(),
-            "fds": fds}
+            "fds": fds,
+            "gc": GC_HOOK.stats()}
 
 
 class BoundedStructRegistry:

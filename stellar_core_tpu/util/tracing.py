@@ -36,20 +36,30 @@ where it happened (a queue wait, a consensus slot). While enabled, every
 `TraceAnnotation` of the same name, so program spans sit on the device
 trace's clock — only when `jax` is already loaded: cpu-backend nodes
 never import it because of tracing.
+
+Beneath every span runs the interpreter, and CPython's collector stops
+all of its threads at once. `GcHook` (ONE `gc.callbacks` entry a
+process, installed when the first `Tracer` is built) keeps the process's
+totals always and, while a tracer is enabled, writes each collection as
+a `runtime.gc.young` / `runtime.gc.full` span into it.
 """
 
 from __future__ import annotations
 
+import gc
+import itertools
 import json
 import os
 import sys
 import threading
 import time
 import traceback
+import weakref
 from collections import deque
 from typing import Callable, Dict, List, Optional
 
 from .log import get_logger
+from .metrics import Histogram
 
 log = get_logger("Perf")
 
@@ -177,6 +187,149 @@ def app_tracer(app):
     return enabled_tracer(getattr(app, "tracer", None))
 
 
+class GcHook:
+    """CPython's cyclic collector, as the process and its tracers see it.
+
+    One instance a process (`GC_HOOK`), one `gc.callbacks` entry however
+    many nodes the process builds. A collection stops every thread, so
+    its time is inside whatever span was open, on every thread: the hook
+    is what gives it a name.
+
+    Always on: totals by generation in plain ints and floats (a few item
+    writes a collection), read by `util/footprint.py::process_stats()`
+    (`GET footprint`), by `GET metrics` (`runtime.gc.pause`,
+    `runtime.gc.full.pause`: built from these totals at the scrape, no
+    registry is written at a collection) and by `ledger.close`.
+
+    While a tracer is enabled (a tracer enrols in `enable()` and leaves
+    in `disable()`; held weakly, a dead one drops out): "start" enters
+    the profiler's annotation on the collecting thread, "stop" leaves it
+    and `record()`s one completed span into every enabled tracer. The
+    span's `parent` is 0, so no other span's self time changes; `under`
+    names the span it interrupted.
+
+    The callback takes no lock and calls nothing that does: a collection
+    can start wherever a thread allocates, under any lock of the
+    program's. `_tracers` is a tuple that `enrol` / `leave` replace
+    whole, under a lock of their own."""
+
+    RECENT = 1028       # pauses kept for the timers' quantiles
+
+    def __init__(self) -> None:
+        self.collections = [0, 0, 0]        # by generation
+        self.pause_s = [0.0, 0.0, 0.0]
+        self.max_pause_s = [0.0, 0.0, 0.0]
+        self.collected = [0, 0, 0]
+        self.uncollectable = [0, 0, 0]
+        self.pause_total_s = 0.0            # all generations
+        self._recent: deque = deque(maxlen=self.RECENT)
+        self._recent_full: deque = deque(maxlen=self.RECENT)
+        self._tracers: tuple = ()           # weakrefs to enabled tracers
+        self._lock = threading.Lock()       # writers of `_tracers`
+        self._t0 = 0.0                      # 0.0: no collection running
+        self._note = None
+
+    # -- enrolment -----------------------------------------------------------
+    def install(self) -> None:
+        if self not in gc.callbacks:
+            gc.callbacks.append(self)
+
+    def enrol(self, tracer: "Tracer") -> None:
+        self._replace(tracer, (weakref.ref(tracer),))
+
+    def leave(self, tracer: "Tracer") -> None:
+        self._replace(tracer, ())
+
+    def _replace(self, tracer: "Tracer", refs: tuple) -> None:
+        with self._lock:
+            self._tracers = tuple(
+                r for r in self._tracers
+                if r() is not None and r() is not tracer) + refs
+
+    def enrolled(self) -> List["Tracer"]:
+        return [t for t in (r() for r in self._tracers) if t is not None]
+
+    # -- the callback --------------------------------------------------------
+    def __call__(self, phase: str, info: dict) -> None:
+        if phase == "start":
+            if self._tracers:
+                note = _annotation(_gc_span_name(info["generation"]))
+                if note is not None:
+                    note.__enter__()
+                    self._note = note
+            self._t0 = time.perf_counter()
+            return
+        if not self._t0:        # installed inside a collection: no start
+            return
+        dur = time.perf_counter() - self._t0
+        self._t0 = 0.0
+        if self._note is not None:
+            self._note.__exit__(None, None, None)
+            self._note = None
+        g = info["generation"]
+        self.collections[g] += 1
+        self.pause_s[g] += dur
+        if dur > self.max_pause_s[g]:
+            self.max_pause_s[g] = dur
+        self.collected[g] += info["collected"]
+        self.uncollectable[g] += info["uncollectable"]
+        self.pause_total_s += dur
+        self._recent.append(dur)
+        if g == 2:
+            self._recent_full.append(dur)
+        for ref in self._tracers:
+            tr = ref()
+            if tr is not None and tr.enabled:
+                st = tr._stack()    # the collecting thread's
+                tr.record(_gc_span_name(g), "runtime", tr.now() - dur, dur,
+                          generation=g, collected=info["collected"],
+                          uncollectable=info["uncollectable"],
+                          under=st[-1].name if st else "")
+
+    # -- exports -------------------------------------------------------------
+    def stats(self) -> dict:
+        """The `gc` object of `process_stats()`."""
+        return {"collections": sum(self.collections),
+                "pause_s": round(self.pause_total_s, 6),
+                "generations": [
+                    {"collections": self.collections[g],
+                     "pause_s": round(self.pause_s[g], 6),
+                     "max_pause_s": round(self.max_pause_s[g], 6),
+                     "collected": self.collected[g],
+                     "uncollectable": self.uncollectable[g]}
+                    for g in range(3)]}
+
+    def timers(self) -> dict:
+        """`runtime.gc.pause` / `runtime.gc.full.pause` in the shape a
+        registry timer exports: count, mean and max from the totals,
+        min and the quantiles from the last `RECENT` pauses."""
+        return {
+            "runtime.gc.pause": _timer_json(
+                sum(self.collections), self.pause_total_s,
+                max(self.max_pause_s), list(self._recent)),
+            "runtime.gc.full.pause": _timer_json(
+                self.collections[2], self.pause_s[2],
+                self.max_pause_s[2], list(self._recent_full))}
+
+
+def _gc_span_name(generation: int) -> str:
+    return "runtime.gc.full" if generation == 2 else "runtime.gc.young"
+
+
+def _timer_json(count: int, total: float, mx: float,
+                recent: List[float]) -> dict:
+    s = sorted(recent)
+    pick = Histogram._pick
+    return {"type": "timer", "count": count,
+            "mean": total / count if count else 0.0,
+            "min": s[0] if s else 0.0, "max": mx,
+            "median": pick(s, 0.5), "p75": pick(s, 0.75),
+            "p95": pick(s, 0.95), "p99": pick(s, 0.99)}
+
+
+GC_HOOK = GcHook()
+
+
 class Tracer:
     """Bounded-ring span recorder; see module docstring."""
 
@@ -186,9 +339,11 @@ class Tracer:
         self._now = now_fn
         self._buf: deque = deque(maxlen=capacity)
         self._tls = threading.local()
-        self._next_sid = 0
-        self._sid_lock = threading.Lock()
+        # lock-free (the collector's hook records from wherever a
+        # collection starts, perhaps under a lock of the caller's)
+        self._sids = itertools.count(1)
         self.dropped = 0   # spans evicted from the ring since enable()
+        GC_HOOK.install()
 
     @property
     def capacity(self) -> int:
@@ -200,9 +355,11 @@ class Tracer:
             self._buf = deque(self._buf, maxlen=capacity)
         self.dropped = 0
         self.enabled = True
+        GC_HOOK.enrol(self)
 
     def disable(self) -> None:
         self.enabled = False
+        GC_HOOK.leave(self)
 
     def clear(self) -> None:
         self._buf.clear()
@@ -238,7 +395,8 @@ class Tracer:
         happened (a queue wait, a slot, a timer wait); `t0` is on this
         tracer's clock (`now()`). Its `parent` is 0 whatever is open on
         the thread, so it never changes another span's self time. It is
-        ring-only: the profiler's trace cannot be backdated."""
+        ring-only: the profiler's trace cannot be backdated (the
+        collector's hook enters its annotation itself, in real time)."""
         if not self.enabled:
             return
         s = Span(self, name, cat, tags or None, cause)
@@ -259,9 +417,7 @@ class Tracer:
         self._record(s)
 
     def _new_sid(self) -> int:
-        with self._sid_lock:
-            self._next_sid += 1
-            return self._next_sid
+        return next(self._sids)
 
     def _stack(self) -> List[Span]:
         st = getattr(self._tls, "stack", None)

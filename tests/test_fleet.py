@@ -247,11 +247,18 @@ def test_prometheus_endpoint_serves_whole_registry(fleet_sim):
     knows — registry AND the merged crypto-boundary extras — and
     round-trips through the parser (acceptance)."""
     app = next(iter(fleet_sim.nodes.values())).app
-    st, body = app.command_handler.handle_command(
-        "metrics", {"format": "prometheus"})
-    assert st == 200 and isinstance(body, str)
+    import gc
+    was = gc.isenabled()
+    gc.disable()    # `runtime.gc.*` count the process's collections
+    try:
+        st, body = app.command_handler.handle_command(
+            "metrics", {"format": "prometheus"})
+        st2, js = app.command_handler.handle_command("metrics", {})
+    finally:
+        if was:
+            gc.enable()
+    assert st == 200 and st2 == 200 and isinstance(body, str)
     samples, types = parse_exposition(body)
-    st, js = app.command_handler.handle_command("metrics", {})
     for name, m in js.items():
         base = prometheus_name(name)
         if m.get("type") == "meter":

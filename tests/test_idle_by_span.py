@@ -67,3 +67,55 @@ def test_a_device_run_is_matched_to_the_wait_around_it():
                                  (-0.1 * MS, 1.4 * MS, 2.2 * MS)])
     # a wait that straddles the slice's end is left out
     assert len(I.wait_offsets(notes, modules, 0.0, 22 * MS)) == 1
+
+
+# a restart, a close with a full pass of the collector inside its apply
+# and a merge on a worker beside it (ISSUE 35): every name below lacked
+# its prefix before, and its gap fell through to the benchmark's wrapper
+RUNTIME_NOTES = [
+    ("bench.trace_slice", 0.0, 100 * MS),
+    ("bench.catchup.new_node", 0.0, 20 * MS),
+    ("node.restore", 2 * MS, 18 * MS),
+    ("bucketdb.index_load", 4 * MS, 12 * MS),
+    ("bench.catchup.crank", 20 * MS, 100 * MS),
+    ("ledger.close", 30 * MS, 90 * MS),
+    ("close.apply", 32 * MS, 70 * MS),
+    ("runtime.gc.full", 40 * MS, 60 * MS),
+    ("close.bucket_add", 70 * MS, 88 * MS),
+    ("bucket.merge_wait", 72 * MS, 80 * MS),
+    ("bucket.merge", 73 * MS, 79 * MS),     # the worker's, beside it
+]
+
+
+def test_a_gap_under_a_full_pass_is_the_collectors_and_a_restarts_its_own():
+    idle = I.charge([(0.0, 100 * MS)], RUNTIME_NOTES, split=True)
+    assert sum(idle.values()) == pytest.approx(100 * MS)
+    assert idle == pytest.approx({
+        "bench.catchup.new_node": 2 * MS + 2 * MS,      # 0–2, 18–20
+        "node.restore": 2 * MS + 6 * MS,                # 2–4, 12–18
+        "bucketdb.index_load": 8 * MS,                  # 4–12
+        "bench.catchup.crank": 10 * MS + 10 * MS,       # 20–30, 90–100
+        "ledger.close": 2 * MS + 2 * MS,                # 30–32, 88–90
+        "close.apply": 8 * MS + 10 * MS,                # 32–40, 60–70
+        "runtime.gc.full": 20 * MS,                     # 40–60
+        "close.bucket_add": 2 * MS + 8 * MS,            # 70–72, 80–88
+        "bucket.merge_wait": 1 * MS + 1 * MS,           # 72–73, 79–80
+        "bucket.merge": 6 * MS})                        # 73–79: the shortest
+    for name in ("runtime.gc.full", "node.restore", "bucket.merge",
+                 "bucketdb.index_load", "overlay.recv_tx", "scp.slot"):
+        assert name.startswith(I.PROGRAM_PREFIXES)
+
+
+def test_a_workers_merge_is_measured_against_the_other_threads_closes():
+    threads = {
+        "main": [("ledger.close", 30 * MS, 90 * MS),
+                 ("close.bucket_add", 70 * MS, 88 * MS)],
+        "bucket-merge_0": [("bucket.merge", 71 * MS, 79 * MS),
+                           ("bucket.merge", 85 * MS, 95 * MS)],
+    }
+    rows = I.beside_closes(threads)
+    n, ns, under = rows["bucket-merge_0"]["bucket.merge"]
+    assert (n, ns, under) == (2, pytest.approx(18 * MS),
+                              pytest.approx(8 * MS + 5 * MS))
+    # the closing thread's own spans are under nobody else's close
+    assert rows["main"]["ledger.close"] == (1, pytest.approx(60 * MS), 0.0)

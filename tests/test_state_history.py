@@ -353,7 +353,11 @@ def test_restore_spans_nest_and_say_what_was_restored(dep):
     loads = [s for s in spans.values() if s.name == "bucketdb.index_load"]
     assert restore.tags == {"lcl": FREQ - 1, "bucket_backed": True}
     assert assume.parent == restore.sid
-    assert loads and all(s.parent == assume.sid for s in loads)
+    # each sidecar loads under its bucket's adoption (PR 35)
+    adopts = {s.sid for s in spans.values()
+              if s.name == "bucket.adopt" and s.parent == assume.sid}
+    assert loads and all(s.parent in adopts for s in loads)
+    assert not any(spans[a].tags["wrote"] for a in adopts)
     assert assume.tags["buckets"] == len(loads)
     assert assume.tags["bytes"] > 100 * STATE["accounts"]
     assert max(s.tags["keys"] for s in loads) == STATE["accounts"]

@@ -16,6 +16,11 @@ uses: the table's total is the run line's `window_s - busy_s`. With
 --split a gap is first cut at every span boundary inside it, so a gap of
 a second between two drains is shared out among the spans it crosses.
 
+With --threads it prints the program's spans of the slice by host
+thread, and how much of each thread's span time lies under a `close.*`
+/ `ledger.close` span of ANOTHER thread (a `bucket.merge` on a worker
+beside the closes it shares the interpreter with).
+
 It also prints, for the verify executable, how far each device run sits
 inside its `crypto.device_wait`: launch latency (`crypto.launch` start →
 module start on the device) and readback latency (module end →
@@ -42,9 +47,12 @@ if ROOT not in sys.path:
 from benchmark.harness import trace_reduce as T   # noqa: E402
 
 # every span name the program opens with `with` (record()ed spans and
-# instants are ring-only and never reach the profiler's trace)
-PROGRAM_PREFIXES = ("catchup.", "close.", "crypto.", "herder.", "ledger.",
-                    "tx.", "txqueue.")
+# instants are ring-only and never reach the profiler's trace, but for
+# the collector's `runtime.gc.*`, whose hook enters its annotation in
+# real time)
+PROGRAM_PREFIXES = ("bucket.", "bucketdb.", "catchup.", "close.", "crypto.",
+                    "herder.", "ledger.", "node.", "overlay.", "runtime.",
+                    "scp.", "tx.", "txqueue.")
 VERIFY_MODULE = "jit_verify_batch"
 
 Note = Tuple[str, float, float]     # (name, start_ns, end_ns)
@@ -113,6 +121,44 @@ def charge(gaps: List[T.Interval], notes: List[Note],
     return idle
 
 
+def by_thread(pd, lo: float, hi: float) -> Dict[str, List[Note]]:
+    """{host thread: its program spans, the part inside the slice}. A
+    thread is a line of the host plane; Python's all carry the process's
+    name, so the key is the name and the line's place in the plane."""
+    out: Dict[str, List[Note]] = {}
+    for plane in pd.planes:
+        if plane.name != T.HOST_PLANE:
+            continue
+        for i, line in enumerate(plane.lines):
+            mine = [(e.name, max(e.start_ns, lo),
+                     min(e.start_ns + e.duration_ns, hi))
+                    for e in line.events
+                    if e.name.startswith(PROGRAM_PREFIXES)
+                    and e.start_ns < hi and e.start_ns + e.duration_ns > lo]
+            if mine:
+                out["%s#%d" % (line.name, i)] = mine
+    return out
+
+
+def beside_closes(threads: Dict[str, List[Note]]
+                  ) -> Dict[str, Dict[str, Tuple[int, float, float]]]:
+    """{thread: {span name: (spans, their ns, the ns of them under a
+    close.* / ledger.close span of another thread)}}."""
+    out = {}
+    for thread, notes in threads.items():
+        cover = T.union([(a, b) for other, theirs in threads.items()
+                         if other != thread for name, a, b in theirs
+                         if name.startswith("close.")
+                         or name == "ledger.close"])
+        rows: Dict[str, Tuple[int, float, float]] = {}
+        for name, a, b in notes:
+            n, ns, under = rows.get(name, (0, 0.0, 0.0))
+            rows[name] = (n + 1, ns + (b - a),
+                          under + T.total(T.clip(cover, a, b)))
+        out[thread] = rows
+    return out
+
+
 def wait_offsets(notes: List[Note], modules: List[Note], lo: float,
                  hi: float) -> List[Tuple[float, float, float]]:
     """[(launch latency, device run, readback latency)] in ns: each
@@ -149,6 +195,8 @@ def main(argv=None) -> int:
                     help="also print the N longest device ops")
     ap.add_argument("--split", action="store_true",
                     help="cut each gap at the span boundaries inside it")
+    ap.add_argument("--threads", action="store_true",
+                    help="also print the program's spans by host thread")
     args = ap.parse_args(argv)
     pd = T.load(T.find_xplane(args.trace_dir))
     lo, hi, busy, rec = slice_and_busy(pd, args.platform)
@@ -172,6 +220,14 @@ def main(argv=None) -> int:
               "median launch latency %.3f ms, device run %.3f ms, "
               "readback latency %.3f ms"
               % (VERIFY_MODULE, len(off), med[0], med[1], med[2]))
+    if args.threads:
+        for thread, rows in sorted(beside_closes(
+                by_thread(pd, lo, hi)).items()):
+            print("thread %s" % thread)
+            for name, (n, ns, under) in sorted(rows.items(),
+                                               key=lambda kv: -kv[1][1]):
+                print("  %-26s %6d spans %10.6f s, %10.6f s under another "
+                      "thread's close" % (name, n, ns / 1e9, under / 1e9))
     if args.ops:
         print_ops(pd, args.platform, lo, hi, args.ops)
     return 0
