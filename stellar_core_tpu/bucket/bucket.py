@@ -363,11 +363,11 @@ def entry_record(e: BucketEntry) -> bytes:
     its file write, AND every later merge that re-hashes the same
     entry objects into a new bucket — the `bucket add` close-phase
     win the BENCH_r11 leg gates."""
-    rec = e.__dict__.get("_sct_rec")
+    rec = getattr(e, "_sct_rec", None)
     if rec is None:
         from ..util.xdrstream import frame_record
         rec = frame_record(e.to_xdr())
-        e.__dict__["_sct_rec"] = rec
+        e._sct_rec = rec
     return rec
 
 

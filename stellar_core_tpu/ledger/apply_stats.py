@@ -107,7 +107,7 @@ def txset_prefetch_keys(frames) -> list:
         kb = key.to_xdr()
         if kb not in seen:
             seen.add(kb)
-            key.__dict__["_kb"] = kb   # the ledgertxn map key, pre-memoized
+            key._kb = kb   # the ledgertxn map key, pre-memoized
             keys.append(key)
 
     def add_acc(pk) -> None:

@@ -30,10 +30,10 @@ def _kb(key: LedgerKey) -> bytes:
     """LedgerKey → canonical bytes (the txn tree's map key), memoized on
     the instance — keys are treated as immutable once built, and the same
     key object flows through load/commit/delta several times per access."""
-    kb = key.__dict__.get("_kb")
+    kb = getattr(key, "_kb", None)
     if kb is None:
         kb = key.to_xdr()
-        key.__dict__["_kb"] = kb
+        key._kb = kb
     return kb
 
 

@@ -135,6 +135,7 @@ static void capsule_destructor(PyObject *cap)
 /* ----------------------------------------------------------------- pack */
 
 static PyObject *str_disc, *str_value; /* interned at module init */
+static PyObject *empty_args;            /* () for tp_new, at module init */
 
 #define SCT_MAX_DEPTH 200 /* real wire types nest < 20 deep; adversarial
                              * self-nesting must raise, not smash the
@@ -409,6 +410,17 @@ static int rd_pad(Rdr *r, Py_ssize_t n)
 static PyObject *unpack_node(const Prog *p, long long idx, Rdr *r,
                              int depth);
 
+/* A struct or union value is made by its type's tp_new, not tp_alloc:
+   object_new sets up CPython's inline attribute values, so the
+   PyObject_SetAttr calls that fill the value materialize no instance
+   __dict__, a second GC-tracked container per value (docs/observability.md,
+   "XDR values are one object to the collector"). */
+static PyObject *new_value(PyObject *cls)
+{
+    PyTypeObject *tp = (PyTypeObject *)cls;
+    return tp->tp_new(tp, empty_args, NULL);
+}
+
 static PyObject *unpack_union(const Prog *p, const Node *nd, Rdr *r,
                               int depth)
 {
@@ -447,7 +459,7 @@ static PyObject *unpack_union(const Prog *p, const Node *nd, Rdr *r,
             return NULL;
         }
     }
-    obj = ((PyTypeObject *)nd->cls)->tp_alloc((PyTypeObject *)nd->cls, 0);
+    obj = new_value(nd->cls);
     if (!obj) {
         Py_DECREF(dnum);
         Py_DECREF(val);
@@ -633,8 +645,7 @@ static PyObject *unpack_node(const Prog *p, long long idx, Rdr *r,
         return NULL;
     }
     case 9: { /* struct */
-        PyObject *obj =
-            ((PyTypeObject *)nd->cls)->tp_alloc((PyTypeObject *)nd->cls, 0);
+        PyObject *obj = new_value(nd->cls);
         Py_ssize_t i;
         if (!obj)
             return NULL;
@@ -907,7 +918,8 @@ PyMODINIT_FUNC PyInit__sctxdr(void)
     PyObject *m, *codec;
     str_disc = PyUnicode_InternFromString("disc");
     str_value = PyUnicode_InternFromString("value");
-    if (!str_disc || !str_value)
+    empty_args = PyTuple_New(0);
+    if (!str_disc || !str_value || !empty_args)
         return NULL;
     codec = PyImport_ImportModule("stellar_core_tpu.xdr.codec");
     if (!codec)

@@ -32,6 +32,7 @@ Consumers:
 
 from __future__ import annotations
 
+import gc
 import os
 import threading
 from typing import Callable, Dict, Optional
@@ -46,7 +47,7 @@ def process_stats() -> dict:
     """Process-level footprint from /proc (Linux; ru_maxrss fallback):
     resident set in MB, live thread count, open fd count (-1 when
     /proc/self/fd is unreadable), and `gc`: the collector's process
-    totals by generation (util/tracing.py::GcHook)."""
+    totals by generation (util/tracing.py::GcHook) with `tracked`."""
     rss_kb = 0
     try:
         with open("/proc/self/status", encoding="ascii") as fh:
@@ -69,7 +70,14 @@ def process_stats() -> dict:
     return {"rss_mb": round(rss_kb / 1024.0, 3),
             "threads": threading.active_count(),
             "fds": fds,
-            "gc": GC_HOOK.stats()}
+            "gc": dict(GC_HOOK.stats(), tracked=tracked_objects())}
+
+
+def tracked_objects() -> list:
+    """The objects the collector tracks, by generation: what a pass of
+    that generation walks (a full pass walks all three). Counted on
+    request, by listing each generation, so it stays off every close."""
+    return [len(gc.get_objects(generation=g)) for g in range(3)]
 
 
 class BoundedStructRegistry:

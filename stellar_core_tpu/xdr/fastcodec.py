@@ -37,6 +37,36 @@ def _pad(n: int) -> int:
     return (4 - n % 4) % 4
 
 
+def _generate(cls: type, slot: str, verb: str, source) -> Callable:
+    """A struct's decoder or copier as straight-line code generated once
+    per class: `obj.key, pos = f0(buf, pos)`, one line a field. An
+    attribute set this way stays in the instance's inline values; a write
+    through `obj.__dict__` makes CPython materialize a dict, a second
+    GC-tracked container per value that every collector pass walks
+    (docs/observability.md, "XDR values are one object to the collector").
+
+    `source(fields, env)` returns the parameter list and the body's
+    lines, and puts the field functions it names into `env`. The fields
+    are read here, at the first compile: a class whose `xdr_fields` is
+    patched after creation (scp.py, transaction.py) is patched at import,
+    before any compile. While they compile, `slot` on the class holds a
+    forwarder, so a type that contains itself (SCPQuorumSet.innerSets)
+    reaches the finished function."""
+    setattr(cls, slot, lambda *a: cls.__dict__[slot](*a))
+    try:
+        env = {"new": cls.__new__, "cls": cls}
+        params, lines = source(cls.xdr_fields, env)
+    except BaseException:
+        delattr(cls, slot)
+        raise
+    name = "%s_%s" % (verb, cls.__name__)
+    src = "def %s(%s, %s):\n    %s\n" % (
+        name, params, ", ".join("%s=%s" % (k, k) for k in env),
+        "\n    ".join(lines))
+    exec(compile(src, "<xdr %s>" % name, "exec"), env)
+    return env[name]
+
+
 # --------------------------------------------------------------- compilers
 
 def compile_pack(t: Any) -> PackFn:
@@ -339,18 +369,14 @@ def _build_unpack(t: Any) -> UnpackFn:
         return f
 
     if isinstance(t, type) and issubclass(t, C.XdrStruct):
-        cell: list = []
-
-        def f(buf, pos, cls=t, cell=cell):
-            if not cell:
-                cell.append(tuple((n, compile_unpack(ft))
-                                  for n, ft in cls.xdr_fields))
-            obj = cls.__new__(cls)
-            d = obj.__dict__
-            for n, fu in cell[0]:
-                d[n], pos = fu(buf, pos)
-            return obj, pos
-        return f
+        def source(fields, env):
+            lines = ["obj = new(cls)"]
+            for i, (n, ft) in enumerate(fields):
+                env["f%d" % i] = compile_unpack(ft)
+                lines.append("obj.%s, pos = f%d(buf, pos)" % (n, i))
+            lines.append("return obj, pos")
+            return "buf, pos", lines
+        return _generate(t, "_fast_unpack", "unpack", source)
 
     if isinstance(t, type) and issubclass(t, C.XdrUnion):
         cell: list = []
@@ -441,20 +467,18 @@ def _build_copy(t: Any):
         return lambda v, elem=elem: None if v is None else elem(v)
 
     if isinstance(t, type) and issubclass(t, C.XdrStruct):
-        cell: list = []   # lazy: xdr_fields may be patched post-creation
-
-        def f(v, cls=t, cell=cell):
-            if not cell:
-                cell.append(tuple((n, _copy_of(ft))
-                                  for n, ft in cls.xdr_fields))
-            obj = cls.__new__(cls)
-            d = obj.__dict__
-            s = v.__dict__
-            for n, fc in cell[0]:
-                x = s[n]
-                d[n] = x if fc is None else fc(x)
-            return obj
-        return f
+        def source(fields, env):
+            lines = ["obj = new(cls)"]
+            for i, (n, ft) in enumerate(fields):
+                fc = _copy_of(ft)
+                if fc is None:
+                    lines.append("obj.%s = v.%s" % (n, n))
+                else:
+                    env["f%d" % i] = fc
+                    lines.append("obj.%s = f%d(v.%s)" % (n, i, n))
+            lines.append("return obj")
+            return "v", lines
+        return _generate(t, "_fast_copy", "copy", source)
 
     if isinstance(t, type) and issubclass(t, C.XdrUnion):
         cell: list = []
