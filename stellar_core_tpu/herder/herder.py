@@ -38,6 +38,13 @@ from .upgrades import Upgrades
 
 log = get_logger("Herder")
 
+# who asks Herder.check_txset to walk a transaction set: this node's
+# trigger (which trims what it nominates), nomination's federated accept
+# and its pick of a round leader's value (SCPDriver.validation_site), any
+# other caller of validate_value. Each is the `site` tag of the
+# `txset.check_valid` span and a meter `herder.txset.checks.<site>`.
+TXSET_CHECK_SITES = ("trigger", "nominate_accept", "leader_pick", "other")
+
 
 class HerderState:
     HERDER_SYNCING_STATE = 0
@@ -120,9 +127,8 @@ class HerderSCPDriver(SCPDriver):
         if nomination:
             if txset.previous_ledger_hash != lm.lcl_hash:
                 return ValidationLevel.INVALID
-            ltx_root = lm.ltx_root()
-            ok, _removed = txset.check_or_trim(
-                ltx_root, self.herder.verifier, trim=False)
+            ok, _removed = self.herder.check_txset(
+                txset, self.validation_site)
             if not ok:
                 return ValidationLevel.INVALID
         if not self._upgrades_valid(sv, nomination):
@@ -1048,7 +1054,9 @@ class Herder:
             tl.record(self.current_slot(), "txset.fetched", dedupe=True,
                       dedupe_key=h.hex(),
                       hash=h.hex()[:8], txs=len(txset.frames))
-        self.pending.add_tx_set(h, txset)
+        # kept through the open slot at least; an envelope that names it
+        # keeps it through the envelope's
+        self.pending.add_tx_set(h, txset, self.current_slot())
         return True
 
     def recv_scp_quorum_set(self, h: bytes, qset: SCPQuorumSet) -> bool:
@@ -1105,14 +1113,14 @@ class Herder:
                 # the queue so this trigger's txset sees parked txs
                 self.ingress.pump()
             txset = self.tx_queue.to_txset(lm.lcl_hash, cfg.network_id)
-            removed = txset.trim_invalid(lm.ltx_root(), self.verifier)
+            _, removed = self.check_txset(txset, "trigger", trim=True)
             if removed:
                 self.tx_queue.ban([f.full_hash() for f in removed])
             txset.surge_pricing_filter(lcl)
             tsp.set_tag("txs", len(txset.frames))
             h = txset.get_contents_hash(
                 hasher=getattr(self.app, "batch_hasher", None))
-            self.pending.add_tx_set(h, txset)
+            self.pending.add_tx_set(h, txset, slot)
             # lifecycle stamp: txset inclusion at nomination (the slot's
             # externalized set may differ; missed stages backfill)
             self.tx_lifecycle.included(
@@ -1139,6 +1147,26 @@ class Herder:
             tl.record(slot, "nominate.trigger", dedupe=True,
                       txs=len(txset.frames))
         self.scp.nominate(slot, value.to_xdr(), prev)
+
+    def check_txset(self, txset: TxSetFrame, site: str,
+                    trim: bool = False) -> Tuple[bool, list]:
+        """`TxSetFrame.check_or_trim` against the LCL, as one
+        `txset.check_valid` span and one mark of `herder.txset.checks` and
+        of `herder.txset.checks.<site>`; `site` is one of
+        TXSET_CHECK_SITES. What the walk does is check_or_trim's own."""
+        m = self._metrics()
+        if m is not None:
+            m.new_meter("herder.txset.checks").mark()
+            m.new_meter("herder.txset.checks." + site).mark()
+        with app_span(self.app, "txset.check_valid", cat="herder",
+                      site=site, txs=len(txset.frames)) as sp:
+            if sp.live:
+                sp.set_tag("ops", txset.size_ops())
+            ok, removed = txset.check_or_trim(
+                self.app.ledger_manager.ltx_root(), self.verifier,
+                trim=trim)
+            sp.set_tag("removed", len(removed))
+        return ok, removed
 
     def _arm_trigger_timer(self) -> None:
         cfg = self.app.config

@@ -70,6 +70,8 @@ class PendingEnvelopes:
         self.fetch_txset_fn = fetch_txset
         self.fetch_qset_fn = fetch_qset
         self.txsets: Dict[bytes, object] = {}
+        # txset hash -> the last slot that named it (0: not known, kept)
+        self.txset_slots: Dict[bytes, int] = {}
         self.qsets: Dict[bytes, SCPQuorumSet] = {}
         # slot -> list of envelopes waiting on deps
         self.fetching: Dict[int, List[SCPEnvelope]] = {}
@@ -85,9 +87,18 @@ class PendingEnvelopes:
         self.fetch_qset_fn = fetch_qset
 
     # -- caches -------------------------------------------------------------
-    def add_tx_set(self, h: bytes, txset) -> None:
+    def add_tx_set(self, h: bytes, txset, slot: int = 0) -> None:
+        """Keep `txset` through `slot`, the last slot known to name it
+        (reference addTxSet's lastSeenSlotIndex): `erase_below` drops it
+        once that slot is purged. Slot 0 is not known, and kept."""
         self.txsets[h] = txset
+        self.txset_slots.setdefault(h, 0)
+        self._keep_through(h, slot)
         self._retry_fetching()
+
+    def _keep_through(self, h: bytes, slot: int) -> None:
+        if slot > self.txset_slots[h]:
+            self.txset_slots[h] = slot
 
     def add_quorum_set(self, h: bytes, qset: SCPQuorumSet) -> None:
         self.qsets[h] = qset
@@ -101,13 +112,17 @@ class PendingEnvelopes:
 
     # -- intake -------------------------------------------------------------
     def _missing_deps(self, env: SCPEnvelope) -> List[tuple]:
+        """What `env` still lacks. A txset it names that is here is kept
+        through the envelope's slot."""
         missing = []
         st = env.statement
         qh = statement_qset_hash(st)
         if qh not in self.qsets:
             missing.append(("qset", qh))
         for th in statement_txset_hashes(st):
-            if th not in self.txsets:
+            if th in self.txsets:
+                self._keep_through(th, st.slotIndex)
+            else:
                 missing.append(("txset", th))
         return missing
 
@@ -189,3 +204,9 @@ class PendingEnvelopes:
                   self.verifying):
             for s in [s for s in d if s < slot]:
                 del d[s]
+        # a txset last named below `slot` goes with the slots (reference
+        # eraseBelow); without this a node keeps every set it ever saw,
+        # and the collector walks them all
+        for h in [h for h, s in self.txset_slots.items() if 0 < s < slot]:
+            del self.txsets[h]
+            del self.txset_slots[h]

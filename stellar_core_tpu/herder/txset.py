@@ -291,15 +291,6 @@ class TxSetFrame:
         if triples:
             verifier.prewarm_many(triples)
 
-    def trim_invalid(self, ltx_parent, verifier=None) -> List[AnyFrame]:
-        _, removed = self.check_or_trim(ltx_parent, verifier, trim=True)
-        return removed
-
-    def check_valid(self, ltx_parent, verifier=None) -> bool:
-        lcl_hash = getattr(ltx_parent, "lcl_hash", None)
-        ok, _ = self.check_or_trim(ltx_parent, verifier, trim=False)
-        return ok
-
     # -- hashing ------------------------------------------------------------
     def get_contents_hash(self, hasher=None) -> bytes:
         """SHA256(previousLedgerHash ‖ sorted envelopes), streamed as

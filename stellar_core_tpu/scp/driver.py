@@ -47,6 +47,10 @@ class SCPDriver:
     # per-slot phase/round/envelope attribution. None keeps
     # standalone/test drivers cockpit-free.
     scp_stats = None
+    # which step of nomination is asking `validate_value` (set around the
+    # call by scp/nomination.py: "leader_pick", "nominate_accept"; else
+    # "other"). Herder tags its txset validation with it.
+    validation_site = "other"
 
     def _trace_instant(self, name: str, slot_index: int, **tags) -> None:
         from ..util.tracing import tracer_instant

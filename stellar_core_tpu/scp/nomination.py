@@ -110,7 +110,6 @@ class NominationProtocol:
         self.latest_nominations[nb] = envelope
         if not self.nomination_started:
             return BallotProtocol.EnvelopeState.VALID
-        from .driver import ValidationLevel
         modified = False
         new_candidates = False
         # echo a round leader's BEST new value (reference
@@ -128,14 +127,9 @@ class NominationProtocol:
             if v in self.accepted:
                 continue
             if self._federated_accept_value(v):
-                lvl = self._driver().validate_value(
-                    self.slot.slot_index, v, True)
-                if lvl != ValidationLevel.FULLY_VALIDATED:
-                    alt = self._driver().extract_valid_value(
-                        self.slot.slot_index, v)
-                    if alt is None:
-                        continue
-                    v = alt
+                v = self._validated(v, "nominate_accept")
+                if v is None:
+                    continue
                 self.accepted.add(v)
                 self.votes.add(v)
                 self._journal("nominate.accept",
@@ -233,18 +227,12 @@ class NominationProtocol:
     def _pick_leader_value(self, env: SCPEnvelope) -> Optional[bytes]:
         """Highest value-hash among the leader's votes (reference
         getNewValueFromNomination)."""
-        from .driver import ValidationLevel
         nom = env.statement.pledges.value
         best, best_h = None, -1
         for v in list(nom.votes) + list(nom.accepted):
-            lvl = self._driver().validate_value(self.slot.slot_index, v,
-                                                True)
-            if lvl != ValidationLevel.FULLY_VALIDATED:
-                v2 = self._driver().extract_valid_value(
-                    self.slot.slot_index, v)
-                if v2 is None:
-                    continue
-                v = v2
+            v = self._validated(v, "leader_pick")
+            if v is None:
+                continue
             if v in self.votes:
                 continue  # only NEW values (reference :472-491)
             h = self._driver().compute_value_hash(
@@ -253,6 +241,22 @@ class NominationProtocol:
             if h >= best_h:  # ties: later (higher) value wins (reference)
                 best, best_h = v, h
         return best
+
+    def _validated(self, v: bytes, site: str) -> Optional[bytes]:
+        """`v` where the driver fully validates it, else the valid value
+        it extracts from `v` (None: there is none). `site` names the step
+        that asks, for the driver's `validation_site`."""
+        from .driver import ValidationLevel
+        d = self._driver()
+        prev = getattr(d, "validation_site", "other")
+        d.validation_site = site
+        try:
+            if d.validate_value(self.slot.slot_index, v, True) == \
+                    ValidationLevel.FULLY_VALIDATED:
+                return v
+            return d.extract_valid_value(self.slot.slot_index, v)
+        finally:
+            d.validation_site = prev
 
     def stop_nomination(self) -> None:
         self.nomination_started = False
