@@ -45,6 +45,11 @@ log = get_logger("Herder")
 # `txset.check_valid` span and a meter `herder.txset.checks.<site>`.
 TXSET_CHECK_SITES = ("trigger", "nominate_accept", "leader_pick", "other")
 
+# most verdicts Herder.check_txset keeps for one LCL: a slot sees a few
+# distinct sets (its own, one or two leaders'), so this bounds only a
+# flood of distinct sets
+TXSET_VALID_CACHE_SIZE = 64
+
 
 class HerderState:
     HERDER_SYNCING_STATE = 0
@@ -128,7 +133,7 @@ class HerderSCPDriver(SCPDriver):
             if txset.previous_ledger_hash != lm.lcl_hash:
                 return ValidationLevel.INVALID
             ok, _removed = self.herder.check_txset(
-                txset, self.validation_site)
+                txset, self.validation_site, txset_hash=sv.txSetHash)
             if not ok:
                 return ValidationLevel.INVALID
         if not self._upgrades_valid(sv, nomination):
@@ -337,6 +342,9 @@ class Herder:
         # arrival order: full hash -> (frame, fresh, on_verdict)
         self._parked: Dict[bytes, tuple] = {}
         self._drain_posted = False
+        # check_txset's verdicts, (LCL hash, txset contents hash) -> ok,
+        # all of one LCL: this node's own, never shared with another node
+        self._txset_valid: Dict[Tuple[bytes, bytes], bool] = {}
         self.upgrades = Upgrades()
         self.state = HerderState.HERDER_SYNCING_STATE
         self.tracking_slot: Optional[int] = None
@@ -1148,13 +1156,36 @@ class Herder:
                       txs=len(txset.frames))
         self.scp.nominate(slot, value.to_xdr(), prev)
 
-    def check_txset(self, txset: TxSetFrame, site: str,
-                    trim: bool = False) -> Tuple[bool, list]:
+    def check_txset(self, txset: TxSetFrame, site: str, trim: bool = False,
+                    txset_hash: Optional[bytes] = None) -> Tuple[bool, list]:
         """`TxSetFrame.check_or_trim` against the LCL, as one
         `txset.check_valid` span and one mark of `herder.txset.checks` and
         of `herder.txset.checks.<site>`; `site` is one of
-        TXSET_CHECK_SITES. What the walk does is check_or_trim's own."""
+        TXSET_CHECK_SITES. What the walk does is check_or_trim's own.
+
+        Without `trim` the set is walked once per LCL: a set is named by
+        its contents and what the walk reads (the LCL's state and header,
+        signature verdicts) is fixed until the LCL moves, so a later ask
+        answers from this node's verdict cache (reference
+        HerderSCPDriver's txset validity cache), marks
+        `herder.txset.valid_cache.hits[.<site>]` and returns no removed
+        transactions. `txset_hash` is the set's contents hash where the
+        caller has it. A trimming walk changes the set: never cached."""
         m = self._metrics()
+        cache, key = self._txset_valid, None
+        if not trim:
+            key = (self.app.ledger_manager.lcl_hash,
+                   txset_hash or txset.get_contents_hash(
+                       hasher=getattr(self.app, "batch_hasher", None)))
+            if cache and next(iter(cache))[0] != key[0]:
+                cache.clear()             # the LCL moved
+            ok = cache.get(key)
+            if ok is not None:
+                if m is not None:
+                    m.new_meter("herder.txset.valid_cache.hits").mark()
+                    m.new_meter("herder.txset.valid_cache.hits." +
+                                site).mark()
+                return ok, []
         if m is not None:
             m.new_meter("herder.txset.checks").mark()
             m.new_meter("herder.txset.checks." + site).mark()
@@ -1166,6 +1197,10 @@ class Herder:
                 self.app.ledger_manager.ltx_root(), self.verifier,
                 trim=trim)
             sp.set_tag("removed", len(removed))
+        if key is not None:
+            if len(cache) >= TXSET_VALID_CACHE_SIZE:
+                del cache[next(iter(cache))]     # the oldest
+            cache[key] = ok
         return ok, removed
 
     def _arm_trigger_timer(self) -> None:

@@ -8,8 +8,10 @@ offerID, takers cross the books with offers and path payments, payers
 pay; every transaction is submitted to node 0, floods to the others,
 is nominated, validated and closed by consensus. The model, which
 orders each ledger itself from hashlib, has to equal every node; the
-txset validation each slot repeats has to show as `txset.check_valid`
-spans and `herder.txset.checks` meters, one each a walk.
+txset validation each slot asks for has to show as `txset.check_valid`
+spans and `herder.txset.checks` meters, one each a walk, or as a mark
+of `herder.txset.valid_cache.hits` where the node answers from its
+verdicts at the LCL.
 """
 
 import collections
@@ -26,7 +28,10 @@ if ROOT not in sys.path:
 
 from benchmark.deployments import validator_dex  # noqa: E402
 from benchmark.traffic.dex_live import MAKER_MARGIN  # noqa: E402
-from stellar_core_tpu.herder.herder import TXSET_CHECK_SITES  # noqa: E402
+from stellar_core_tpu.herder.herder import (  # noqa: E402
+    TXSET_CHECK_SITES, Herder,
+)
+from stellar_core_tpu.herder.txset import TxSetFrame  # noqa: E402
 
 STATE = {"pairs": 2, "offers_per_side": 50, "levels": 20, "makers": 4,
          "takers": 4, "payers": 4}
@@ -51,15 +56,29 @@ def small():
 @pytest.fixture(scope="module")
 def dep(tmp_path_factory):
     config, workload = small()
+    # every ask of Herder.check_txset, by node and site: each is a walk
+    # or a hit of the node's verdict cache
+    asks = collections.Counter()
+    check = Herder.check_txset
+
+    def counted(self, txset, site, *args, **kwargs):
+        asks[self, site] += 1
+        return check(self, txset, site, *args, **kwargs)
+    mp = pytest.MonkeyPatch()
+    mp.setattr(Herder, "check_txset", counted)
     d = validator_dex.Deployment(config, workload, 37,
                                  str(tmp_path_factory.mktemp("dexlive")),
                                  True)
-    d.setup()
-    d.window(WINDOW_S, lambda now: False)
-    d.drain()
-    d.compared = {k: c["value"] for k, c in d.compare().items()}
-    yield d
-    d.release()
+    d.asks = asks
+    try:
+        d.setup()
+        d.window(WINDOW_S, lambda now: False)
+        d.drain()
+        d.compared = {k: c["value"] for k, c in d.compare().items()}
+        yield d
+    finally:
+        mp.undo()
+        d.release()
 
 
 def test_live_traffic_leaves_every_node_equal_to_the_model(dep):
@@ -113,10 +132,14 @@ def test_an_offer_altered_on_one_node_is_a_book_mismatch(dep):
     assert dep.compare()["book_mismatches"]["value"] == 0
 
 
-def _site_counts(app):
+def _site_counts(app, prefix="herder.txset.checks"):
     spans = [s for s in app.tracer.spans() if s.name == "txset.check_valid"]
-    meters = app.metrics.to_json(prefix="herder.txset.checks")
+    meters = app.metrics.to_json(prefix=prefix)
     return spans, {k: v["count"] for k, v in meters.items()}
+
+
+def _hit_counts(app):
+    return _site_counts(app, "herder.txset.valid_cache.hits")[1]
 
 
 def test_every_txset_walk_is_one_span_and_one_mark_of_its_site(dep):
@@ -128,9 +151,25 @@ def test_every_txset_walk_is_one_span_and_one_mark_of_its_site(dep):
         by_site.values())
     for site, n in by_site.items():
         assert meters["herder.txset.checks." + site] == n
-    # a validator's slot walks its own trimmed set, the leaders' values
-    # it picks and the values its quorum accepts
-    assert {"trigger", "leader_pick", "nominate_accept"} <= set(by_site)
+    # a validator's slot walks its own trimmed set and the leaders'
+    # values it picks first; the values its quorum accepts it has picked
+    # or walked at the LCL already
+    assert {"trigger", "leader_pick"} <= set(by_site)
+    # every ask, site by site, is a walk or a hit of the node's cache
+    for a in dep.apps:
+        _, walks = _site_counts(a)
+        hits = _hit_counts(a)
+        for site in TXSET_CHECK_SITES:
+            assert dep.asks[a.herder, site] == walks.get(
+                "herder.txset.checks." + site, 0) + hits.get(
+                "herder.txset.valid_cache.hits." + site, 0), site
+        assert sum(dep.asks[a.herder, site] for site in
+                   TXSET_CHECK_SITES) == walks["herder.txset.checks"] + \
+            hits["herder.txset.valid_cache.hits"]
+        assert {"trigger", "leader_pick", "nominate_accept"} <= {
+            site for site in TXSET_CHECK_SITES if dep.asks[a.herder, site]}
+        # a set asked about again at one LCL is answered, not walked
+        assert hits["herder.txset.valid_cache.hits"] > 0
     for s in spans:
         assert set(s.tags) == {"site", "txs", "ops", "removed"}
         assert s.tags["site"] in TXSET_CHECK_SITES and s.tags["removed"] == 0
@@ -148,7 +187,12 @@ def test_a_validation_outside_nomination_is_site_other(dep):
     from stellar_core_tpu.xdr import StellarValue, StellarValueExt
     app = dep.node0
     h, lm = app.herder, app.ledger_manager
-    txset = h.tx_queue.to_txset(lm.lcl_hash, app.config.network_id)
+    g = dep.gen
+    payer = g.payers[0]
+    # a set no node has seen at this LCL: a payment nobody submitted
+    fresh = payer.tx([payer.op_payment(g.payers[1].account_id, 1)],
+                     seq=g.model[payer.account_id.key_bytes]["seq"] + 1)
+    txset = TxSetFrame(app.config.network_id, lm.lcl_hash, [fresh])
     key = txset.get_contents_hash()
     h.pending.add_tx_set(key, txset)
     sv = StellarValue(txSetHash=key,
@@ -156,14 +200,19 @@ def test_a_validation_outside_nomination_is_site_other(dep):
                       upgrades=[], ext=StellarValueExt(0, None))
     h.sign_stellar_value(sv)
     spans0, meters0 = _site_counts(app)
-    assert h.scp_driver.validate_value(lm.lcl_header.ledgerSeq + 1,
-                                       sv.to_xdr(), True) == \
-        ValidationLevel.FULLY_VALIDATED
+    hits0 = _hit_counts(app)
+    for _ in range(2):
+        assert h.scp_driver.validate_value(lm.lcl_header.ledgerSeq + 1,
+                                           sv.to_xdr(), True) == \
+            ValidationLevel.FULLY_VALIDATED
     spans1, meters1 = _site_counts(app)
     new, = spans1[len(spans0):]
-    assert new.tags["site"] == "other" and new.tags["txs"] == 0
+    assert new.tags["site"] == "other" and new.tags["txs"] == 1
     assert meters1["herder.txset.checks.other"] == \
         meters0.get("herder.txset.checks.other", 0) + 1
+    # the second ask is the node's cached verdict, marked at its site
+    assert _hit_counts(app)["herder.txset.valid_cache.hits.other"] == \
+        hits0.get("herder.txset.valid_cache.hits.other", 0) + 1
 
 
 def test_a_trimming_walk_tags_what_it_removed(dep):
