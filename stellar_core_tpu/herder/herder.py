@@ -697,13 +697,18 @@ class Herder:
         `recv_flood_transaction`, which comes here only for what the
         queue answers by hash)."""
         with app_span(self.app, "herder.admit", cat="herder",
-                      origin=origin) as sp:
+                      origin=origin) as sp, self._reading_admit():
             h = frame.full_hash()
             status, fresh = self._gate(frame, h)
             if status is None:
                 status = self._admit_gated(frame, h, fresh, origin)
             sp.set_tag("status", status)
             return status
+
+    def _reading_admit(self):
+        """Cold root reads inside the block count under the `admit`
+        phase of `ledger.root.cold-read.*` (apply_stats.READ_PHASES)."""
+        return self.app.ledger_manager.apply_stats.reading("admit")
 
     def _mark_received(self) -> None:
         m = self._metrics()
@@ -806,7 +811,7 @@ class Herder:
         if not parked:
             return
         with app_span(self.app, "herder.admit_batch", cat="herder",
-                      n=len(parked)) as sp:
+                      n=len(parked)) as sp, self._reading_admit():
             triples = dispatched = 0
             try:
                 triples, dispatched = self.tx_queue.prewarm_frames(
@@ -1190,7 +1195,8 @@ class Herder:
             m.new_meter("herder.txset.checks").mark()
             m.new_meter("herder.txset.checks." + site).mark()
         with app_span(self.app, "txset.check_valid", cat="herder",
-                      site=site, txs=len(txset.frames)) as sp:
+                      site=site, txs=len(txset.frames)) as sp, \
+                self._reading_admit():
             if sp.live:
                 sp.set_tag("ops", txset.size_ops())
             ok, removed = txset.check_or_trim(

@@ -95,6 +95,11 @@ class HistoryArchive:
         return r.returncode == 0
 
     def put_file_sync(self, local: str, remote: str) -> bool:
+        return self.put_file(local, remote) == 0
+
+    def put_file(self, local: str, remote: str) -> int:
+        """mkdir (where the archive has the command), then put; the put
+        command's exit code."""
         if self.mkdir_tmpl:
             d = os.path.dirname(remote)
             if d:
@@ -102,7 +107,7 @@ class HistoryArchive:
                                capture_output=True)
         r = subprocess.run(self.put_cmd(local, remote), shell=True,
                            capture_output=True)
-        return r.returncode == 0
+        return r.returncode
 
 
 class _ArchiveHealth:

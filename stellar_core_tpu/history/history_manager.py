@@ -15,6 +15,7 @@ from typing import Dict, List, Optional
 
 from ..util.log import get_logger
 from ..util.tmpdir import TmpDir
+from ..util.tracing import app_span
 from .archive import (ArchivePool, HistoryArchive, WELL_KNOWN, bucket_path,
                       category_path)
 from .archive_state import HistoryArchiveState
@@ -22,6 +23,11 @@ from .checkpoints import is_last_in_checkpoint
 from .snapshot import StateSnapshot, gzip_file
 
 log = get_logger("History")
+
+# what a publish counts beside history.publish.success: the files put to
+# an archive, their bytes as put, and the bucket files among them
+PUBLISH_METERS = ("history.publish.files", "history.publish.bytes",
+                  "history.publish.buckets")
 
 
 class HistoryManager:
@@ -153,38 +159,79 @@ class HistoryManager:
         has = self._queued_has(checkpoint)
         if has is None:
             return True
-        staging = os.path.join(self.publish_queue_dir.path,
-                               "%08x" % checkpoint)
-        snap = StateSnapshot(self.app, checkpoint, has, staging)
-        files = snap.write_all()
-        ok_all = True
-        for archive in self.writable_archives():
-            ok = self._put_snapshot(archive, checkpoint, has, files)
-            ok_all = ok_all and ok
-            if ok:
-                m = getattr(self.app, "metrics", None)
-                if m is not None:
-                    m.new_meter("history.publish.success").mark()
-                log.info("published checkpoint %d to %s", checkpoint,
-                         archive.name)
+        with app_span(self.app, "history.publish", cat="history",
+                      checkpoint=checkpoint) as sp:
+            staging = os.path.join(self.publish_queue_dir.path,
+                                   "%08x" % checkpoint)
+            snap = StateSnapshot(self.app, checkpoint, has, staging)
+            files = snap.write_all()
+            ok_all = True
+            tally = dict.fromkeys(("files", "bytes_in", "bytes_out",
+                                   "buckets"), 0)
+            for archive in self.writable_archives():
+                ok = self._put_snapshot(archive, checkpoint, has, files,
+                                        tally)
+                ok_all = ok_all and ok
+                if ok:
+                    m = getattr(self.app, "metrics", None)
+                    if m is not None:
+                        m.new_meter("history.publish.success").mark()
+                    log.info("published checkpoint %d to %s", checkpoint,
+                             archive.name)
+            for k, v in tally.items():
+                sp.set_tag(k, v)
         return ok_all
 
     def _put_snapshot(self, archive: HistoryArchive, checkpoint: int,
-                      has: HistoryArchiveState, files: dict) -> bool:
+                      has: HistoryArchiveState, files: dict,
+                      tally: dict) -> bool:
         for category in ("ledger", "transactions", "results", "scp"):
             src = files[category]
             if not os.path.exists(src):
                 continue
-            gz = gzip_file(src)
-            if not archive.put_file_sync(
-                    gz, category_path(category, checkpoint, ".xdr.gz")):
+            gz = self._gzip(src, category, tally)
+            if not self._put(archive, gz, category_path(
+                    category, checkpoint, ".xdr.gz"), category, tally):
                 return False
         for bpath in files["buckets"]:
             hh = os.path.basename(bpath).split("-")[1].split(".")[0]
-            gz = gzip_file(bpath)
-            if not archive.put_file_sync(gz, bucket_path(hh)):
+            gz = self._gzip(bpath, "bucket", tally)
+            if not self._put(archive, gz, bucket_path(hh), "bucket", tally):
                 return False
-        if not archive.put_file_sync(
-                files["has"], category_path("history", checkpoint, ".json")):
+        if not self._put(archive, files["has"], category_path(
+                "history", checkpoint, ".json"), "history", tally):
             return False
-        return archive.put_file_sync(files["has"], WELL_KNOWN)
+        return self._put(archive, files["has"], WELL_KNOWN, "well-known",
+                         tally)
+
+    def _gzip(self, src: str, kind: str, tally: dict) -> str:
+        with app_span(self.app, "history.gzip", cat="history",
+                      kind=kind) as sp:
+            gz = gzip_file(src)
+            n_in, n_out = os.path.getsize(src), os.path.getsize(gz)
+            sp.set_tag("bytes_in", n_in)
+            sp.set_tag("bytes_out", n_out)
+        tally["bytes_in"] += n_in
+        tally["bytes_out"] += n_out
+        return gz
+
+    def _put(self, archive: HistoryArchive, local: str, remote: str,
+             kind: str, tally: dict) -> bool:
+        """One file to one archive (mkdir included); a file put is
+        counted by the publish meters (PUBLISH_METERS)."""
+        size = os.path.getsize(local)
+        with app_span(self.app, "history.put", cat="history", kind=kind,
+                      bytes=size) as sp:
+            rc = archive.put_file(local, remote)
+            sp.set_tag("rc", rc)
+        if rc != 0:
+            return False
+        tally["files"] += 1
+        tally["buckets"] += kind == "bucket"
+        m = getattr(self.app, "metrics", None)
+        if m is not None:
+            m.new_meter("history.publish.files").mark()
+            m.new_meter("history.publish.bytes").mark(size)
+            if kind == "bucket":
+                m.new_meter("history.publish.buckets").mark()
+        return True

@@ -14,6 +14,7 @@ from typing import List, Optional
 
 from ..crypto.hashing import sha256
 from ..util.log import get_logger
+from ..util.tracing import app_span
 from ..util.xdrstream import XDROutputFileStream
 from ..xdr import (
     LedgerHeader, LedgerHeaderHistoryEntry, LedgerSCPMessages, SCPEnvelope,
@@ -181,11 +182,13 @@ class StateSnapshot:
         return out
 
     def write_all(self) -> dict:
-        return {
-            "ledger": self.write_ledger_headers(),
-            "transactions": self.write_transactions(),
-            "results": self.write_results(),
-            "scp": self.write_scp_messages(),
-            "has": self.write_has(),
-            "buckets": self.bucket_files(),
-        }
+        with app_span(self.app, "history.snapshot", cat="history",
+                      checkpoint=self.checkpoint):
+            return {
+                "ledger": self.write_ledger_headers(),
+                "transactions": self.write_transactions(),
+                "results": self.write_results(),
+                "scp": self.write_scp_messages(),
+                "has": self.write_has(),
+                "buckets": self.bucket_files(),
+            }

@@ -145,9 +145,11 @@ def txset_prefetch_keys(frames) -> list:
 # where a cold root read happens: "prepare" is a catchup's work ahead
 # of the closes (the signer collection of `catchup.sig_prep`, the
 # checkpoint-wide prefetch), "prefetch" the root's bulk-warm inside
-# `close.prefetch`, "apply" any other read (the engine's callback
-# included)
-READ_PHASES = ("prepare", "prefetch", "apply")
+# `close.prefetch`, "admit" the herder's admission of a transaction and
+# its walks of a transaction set (Herder.recv_transaction, the drain of
+# flooded ones, Herder.check_txset), "apply" any other read (the
+# engine's callback included)
+READ_PHASES = ("prepare", "prefetch", "apply", "admit")
 
 
 class ApplyStats:
